@@ -485,9 +485,7 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
                 )
                 with span("Time/train_time", SumMetric(sync_on_compute=cfg.metric.sync_on_compute), phase="train"):
                     # the whole burst (n_samples gradient steps) is ONE
-                    # scanned dispatch (sheeprl_tpu/train): per-call overhead
-                    # on a remote-attached device would otherwise repeat per
-                    # gradient step
+                    # scanned dispatch (sheeprl_tpu/train)
                     root_key, train_key = jax.random.split(root_key)
                     agent_state, metrics, _ = run_train_burst(
                         train_fn,

@@ -73,7 +73,6 @@ from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
 from sheeprl_tpu.utils.optim import clip_norm_of, set_lr
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.utils.utils import fetch_losses_if_observed, gae, normalize_tensor, polynomial_decay, save_configs
-from sheeprl_tpu.utils.jax_compat import shard_map
 
 
 def build_update_fn(
@@ -177,7 +176,7 @@ def build_update_fn(
         return params, opt_state, metrics
 
     data_spec = P() if share else P(axis)
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         local_update,
         mesh=fabric.mesh,
         in_specs=(P(), P(), data_spec, P(), P(), P()),
@@ -307,16 +306,14 @@ def main(fabric, cfg: Dict[str, Any]):
 
     # The player runs on the CPU host with a mirrored parameter snapshot
     # (one pytree transfer per update) instead of dispatching one device
-    # program per env step: env interaction is latency-bound, and over a
-    # remote-attached TPU every dispatch is a network round trip
-    # (SURVEY §5.8 — players pinned to CPU hosts feeding the trainer mesh).
+    # program per env step (SURVEY §5.8 — players pinned to CPU hosts
+    # feeding the trainer mesh; algo.player_on_host=False acts on the mesh).
     to_host = HostParamMirror.from_cfg(params, fabric, cfg)
 
     def _act_fn(params, obs, key):
         # the key advances INSIDE the jitted burst: the rollout costs one
         # dispatch per env.act_burst env steps (a host-side jax.random.split
-        # per step would be a second one — over a remote TPU, a second round
-        # trip); the body is the old per-step policy_step_fn verbatim, so
+        # per step would be a second one); the body is the old per-step policy_step_fn verbatim, so
         # act_burst=1 reproduces the per-step path bitwise
         key, sub = jax.random.split(key)
         norm = normalize_obs(obs, cnn_keys, obs_keys)

@@ -58,7 +58,6 @@ from sheeprl_tpu.obs import (
 from sheeprl_tpu.obs.dist import pmean
 from sheeprl_tpu.utils.optim import clip_norm_of, set_lr
 from sheeprl_tpu.utils.utils import fetch_losses_if_observed, gae, normalize_tensor, polynomial_decay, save_configs
-from sheeprl_tpu.utils.jax_compat import shard_map
 
 
 def build_update_fn(
@@ -152,7 +151,7 @@ def build_update_fn(
             return params, opt_state, metrics, probes
         return params, opt_state, metrics
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         local_update,
         mesh=fabric.mesh,
         in_specs=(P(), P(), P(None, axis), P(axis), P(), P(), P()),

@@ -82,7 +82,6 @@ from sheeprl_tpu.obs.dist import pmean
 from sheeprl_tpu.utils.optim import clip_norm_of, get_lr, set_lr
 from sheeprl_tpu.parallel.shard import measured_bytes_per_device
 from sheeprl_tpu.utils.utils import fetch_losses_if_observed, save_configs
-from sheeprl_tpu.utils.jax_compat import shard_map
 
 
 def build_train_fn(
@@ -281,7 +280,7 @@ def build_train_fn(
     # staged batch itself
     td_specs = (P(None, data_axis),) if emit_td else ()
     if state_plan is None:
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             local_train,
             mesh=fabric.mesh,
             in_specs=(P(), P(), P(None, data_axis), P(), P()),

@@ -53,7 +53,6 @@ from sheeprl_tpu.obs import (
 from sheeprl_tpu.obs.dist import pmean
 from sheeprl_tpu.utils.optim import clip_norm_of
 from sheeprl_tpu.utils.utils import fetch_losses_if_observed, save_configs
-from sheeprl_tpu.utils.jax_compat import shard_map
 
 sg = jax.lax.stop_gradient
 
@@ -279,7 +278,7 @@ def build_train_fn(
             return state, opts, metrics, probes
         return state, opts, metrics
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         local_train,
         mesh=fabric.mesh,
         in_specs=(P(), P(), P(None, axis), P(), P()),

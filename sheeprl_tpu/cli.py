@@ -219,13 +219,12 @@ def check_configs(cfg) -> None:
         )
 
     # fused recurrent-core kernels (algo.fused_kernels, sheeprl_tpu/kernels):
-    # a `pallas` request on a non-TPU backend is not an error — the registry
-    # degrades it to the padded-XLA tier at agent-build time — but say so
-    # here, up front, instead of only counting it in telemetry
+    # the knob is read by the dreamer-v1/v2 families only. An explicit
+    # `pallas` on a backend that cannot compile it is an error raised by
+    # kernels.resolve_tier at agent-build time, never a quiet `xla`.
     from sheeprl_tpu.kernels import normalize_tier
 
-    fused_req = normalize_tier(cfg.algo.get("fused_kernels", "off"))
-    if fused_req != "off" and algo_name not in (
+    if normalize_tier(cfg.algo.get("fused_kernels", "off")) != "off" and algo_name not in (
         "dreamer_v1",
         "dreamer_v2",
         "p2e_dv1_exploration",
@@ -239,17 +238,6 @@ def check_configs(cfg) -> None:
             f"'{algo_name}' ignores it (howto/kernels.md)",
             UserWarning,
         )
-    elif fused_req == "pallas":
-        import jax
-
-        if jax.default_backend() != "tpu":
-            warnings.warn(
-                f"algo.fused_kernels=pallas on backend={jax.default_backend()}: "
-                "the Pallas kernels target TPU — the run will auto-degrade to "
-                "the padded-XLA tier (counted as kernel_tier_degraded in "
-                "telemetry; howto/kernels.md)",
-                UserWarning,
-            )
 
     # the actor–learner plane (plane.*, sheeprl_tpu/plane) is consumed by the
     # decoupled entrypoints only; validate its knobs here so a multi-process
@@ -396,7 +384,7 @@ def run_algorithm(cfg) -> None:
     # exits (and before telemetry finalizes, so its counters are complete).
     from sheeprl_tpu.ckpt import setup_checkpoint, teardown_checkpoint
 
-    setup_telemetry(cfg)
+    setup_telemetry(cfg, devices=list(fabric.mesh.devices.flat))
     setup_checkpoint(cfg)
     try:
         # jax.profiler trace capture around the whole run (SURVEY §5.1 — the
@@ -418,6 +406,11 @@ def run_algorithm(cfg) -> None:
 
         fabric.launch(entrypoint, cfg, **kwargs)
     finally:
+        # the TensorBoard writer flushes on a timer: close it so what the run
+        # logged is on disk when run() returns (in-process callers read it)
+        logger = getattr(fabric, "logger", None)
+        if logger is not None:
+            logger.close()
         teardown_checkpoint()
         # inside a finally, exc_info() sees the in-flight exception (if any):
         # a crashed run's telemetry.json records `"crashed": true` plus the

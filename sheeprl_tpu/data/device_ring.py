@@ -4,10 +4,8 @@ TPU-native replacement for the reference's host-only replay staging
 (``sheeprl/data/buffers.py:528-690`` + per-gradient-step host→device batch
 copies): every transition crosses the host→HBM link **once**, when it is
 collected, and gradient-step batches are *gathered on device* from a
-resident uint8 ring. On a remote-attached chip (or any bandwidth-limited
-host link) this turns the train round from transfer-bound into
-compute-bound — a [64, 16] pixel batch that costs a 12.6 MB upload per
-gradient step becomes an 8 KB index upload.
+resident uint8 ring — a [64, 16] pixel batch that costs a 12.6 MB upload
+per gradient step becomes an 8 KB index upload.
 
 Design:
 

@@ -1,8 +1,7 @@
 """Burst acting for Python envs (rollout tier b).
 
 The per-step acting path pays one policy dispatch per env step:
-``policy_fn(...)`` → ``np.asarray(actions)`` → ``envs.step(...)`` — and on
-a remote-attached accelerator each dispatch is a network round trip.
+``policy_fn(...)`` → ``np.asarray(actions)`` → ``envs.step(...)``.
 :class:`BurstActor` compiles K acting steps into ONE dispatched program: a
 ``lax.while_loop`` whose body runs the policy on device and hands the
 actions to the host through an ordered

@@ -100,6 +100,10 @@ def _worker(
     shows learner + players + workers on one Perfetto timeline."""
     import signal
 
+    from sheeprl_tpu.utils.utils import pin_process_to_cpu
+
+    pin_process_to_cpu()  # an env that touches jax must not claim the parent's chip
+
     # the parent owns shutdown: a preemption SIGTERM/SIGINT fans out to the
     # process group, and a worker that died mid-drain would turn a clean
     # checkpoint-and-exit into a crashed run

@@ -58,12 +58,11 @@ def child_main(spec: Dict[str, Any]) -> None:
             os.nice(19)
         except OSError:
             pass
-    # before ANY jax import: the eval child lives on the host CPU
-    os.environ["JAX_PLATFORMS"] = "cpu"
-
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    from sheeprl_tpu.utils.utils import pin_process_to_cpu
+
+    pin_process_to_cpu()  # the eval child lives on the host CPU
     if spec.get("prng_impl"):
         jax.config.update("jax_default_prng_impl", str(spec["prng_impl"]))
 

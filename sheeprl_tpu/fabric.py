@@ -43,19 +43,27 @@ def _select_devices(devices: Any, accelerator: str) -> List[jax.Device]:
     """Resolve the device list from the fabric config.
 
     ``devices`` may be "auto" (all), an int (first N), or a list of indices.
-    ``accelerator`` ∈ {auto, cpu, gpu, cuda, tpu} picks the jax platform; on a
-    machine without that platform we fall back to the default platform with a
-    warning (the reference warns similarly for cpu/ddp mismatches).
+    ``accelerator`` ∈ {auto, cpu, gpu, cuda, tpu}: ``auto`` is whatever
+    platform jax chose; a named platform that this process does not have
+    raises — a run asked onto a TPU never lands on the CPU instead.
     """
-    platform = None
     accelerator = (accelerator or "auto").lower()
-    if accelerator in ("tpu", "gpu", "cuda", "cpu"):
-        platform = {"cuda": "gpu"}.get(accelerator, accelerator)
-    try:
-        all_devices = jax.devices(platform) if platform else jax.devices()
-    except RuntimeError:
-        warnings.warn(f"No '{platform}' platform available; using the default jax platform")
+    if accelerator == "auto":
         all_devices = jax.devices()
+    elif accelerator in ("tpu", "gpu", "cuda", "cpu"):
+        platform = {"cuda": "gpu"}.get(accelerator, accelerator)
+        try:
+            all_devices = jax.devices(platform)
+        except RuntimeError as exc:
+            raise RuntimeError(
+                f"fabric.accelerator={accelerator} but this process has no '{platform}' "
+                f"platform (jax default backend: {jax.default_backend()}). Use "
+                "fabric.accelerator=auto to run on whatever jax finds."
+            ) from exc
+    else:
+        raise ValueError(
+            f"Unknown fabric.accelerator {accelerator!r}; expected auto, cpu, gpu, cuda or tpu"
+        )
     if devices in (None, "auto", -1, "-1"):
         return list(all_devices)
     if isinstance(devices, (list, tuple)):
@@ -159,22 +167,6 @@ class Fabric:
                     f"Unknown fabric.prng_impl {prng_impl!r}; expected one of "
                     "'rbg', 'threefry' (threefry2x32), 'unsafe_rbg'"
                 )
-            if prng_impl != "threefry2x32" and not hasattr(jax, "shard_map"):
-                # pre-graduation jax ships an XLA whose SPMD partitioner hard
-                # CHECK-fails (`!IsManual()`) on the RngBitGenerator op that
-                # rbg keys lower to inside shard_map's manual regions; on such
-                # versions every multi-device train step would abort the
-                # process. Counter-based threefry partitions fine everywhere.
-                import warnings
-
-                warnings.warn(
-                    f"fabric.prng_impl={prng_impl!r} is not usable inside "
-                    "shard_map on this jax version (XLA SPMD partitioner "
-                    "crashes on manual RngBitGenerator); falling back to "
-                    "'threefry2x32'",
-                    UserWarning,
-                )
-                prng_impl = "threefry2x32"
             jax.config.update("jax_default_prng_impl", prng_impl)
         self.strategy = strategy or "auto"
         self.accelerator = accelerator or "auto"
@@ -298,14 +290,6 @@ class Fabric:
         ``world_size`` unless ``model_axis`` carves devices out of it."""
         return int(self.mesh.shape[self.data_axis])
 
-    @property
-    def auto_axes(self):
-        """Mesh axes left to the GSPMD partitioner inside ``shard_map``
-        bodies (empty ⇒ the fully-manual 1-D data-parallel path)."""
-        if self.model_axis > 1:
-            return frozenset({_mesh.MODEL_AXIS})
-        return frozenset()
-
     def shard_plan(self, tree: Any) -> Optional["_shard.ShardingPlan"]:
         """Spec-assign ``tree``'s leaves over the ``'model'`` axis.
 
@@ -340,16 +324,14 @@ class Fabric:
                 "running single-host (call sheeprl_tpu.fabric.init_distributed() before "
                 "creating Fabric, or launch via the CLI which does)"
             )
-        # Eager host-side work in the entrypoint (flax param init, PRNG key
-        # math, staging) defaults to the local CPU: every op traced eagerly
-        # on an accelerator is its own XLA program — over a remote-attached
-        # TPU that is a compile + round trip *per op*. Mesh computation is
-        # unaffected: the train programs carry explicit shardings/meshes and
-        # their inputs are committed with device_put.
-        try:
-            jax.config.update("jax_default_device", jax.devices("cpu")[0])
-        except RuntimeError:  # pragma: no cover - no cpu backend
-            pass
+        # Uncommitted eager work in the entrypoint (flax param init, PRNG key
+        # math, optax state init) runs on the host CPU, one small XLA:CPU
+        # program per op. The mesh programs are unaffected: they carry
+        # explicit shardings and their inputs are committed with device_put
+        # (agent state -> fabric.replicated, replay bursts -> the batch
+        # sharding, acting params -> wherever algo.player_on_host says), so
+        # nothing the config places on the mesh can land here instead.
+        jax.config.update("jax_default_device", jax.devices("cpu")[0])
         return fn(self, *args, **kwargs)
 
     def setup_module(self, module: Any) -> Any:

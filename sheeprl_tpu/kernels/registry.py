@@ -8,11 +8,17 @@ One knob — ``algo.fused_kernels`` — resolved ONCE at agent-build time by
 - ``xla``    — padded + fused pure-XLA cells (``kernels/xla.py``); runs
   everywhere, ``pad_to`` defaults to the 128-lane tile on TPU and 1 (no
   padding, bitwise reference) elsewhere.
-- ``pallas`` — the Pallas TPU kernels (``kernels/pallas_tpu.py``). On a
-  non-TPU backend this request auto-degrades to ``xla`` with a logged
-  notice and a ``kernel_tier_degraded`` telemetry count (tests exercise
-  the Pallas tier on CPU explicitly via ``interpret=True``).
-- ``auto``   — ``pallas`` on TPU, ``xla`` elsewhere.
+- ``pallas`` — the Pallas TPU kernels (``kernels/pallas_tpu.py``). An
+  explicit request on a non-TPU backend raises: nothing reachable from
+  config runs the kernels through the interpreter (``interpret=True`` is an
+  argument of the dispatchers for the CPU parity tests only). A family
+  with no Pallas kernel yet degrades to ``xla`` with a logged notice and a
+  ``kernel_tier_degraded`` telemetry count. Programs of a TPU run that are
+  lowered for the host CPU (the ``algo.player_on_host`` acting mirror)
+  take the kernel's padded-XLA twin — chosen per lowering platform by
+  ``jax.lax.platform_dependent``, never by a Python branch.
+- ``auto``   — the best tier available: ``pallas`` on TPU where the family
+  has one, ``xla`` elsewhere.
 
 The registry also owns two cross-cutting facilities:
 
@@ -100,22 +106,14 @@ def resolve_tier(requested: Any, *, family: str = "hafner_ln_gru") -> str:
     """Resolve the ``algo.fused_kernels`` knob to a concrete tier for one
     kernel family on the current backend (called at agent-build time)."""
     tier = normalize_tier(requested)
+    has_pallas = "pallas" in KERNELS[family]["tiers"]
     if tier == "auto":
-        tier = "pallas" if jax.default_backend() == "tpu" else "xla"
+        tier = "pallas" if has_pallas and jax.default_backend() == "tpu" else "xla"
     if tier not in TIERS:
         raise ValueError(
             f"algo.fused_kernels={requested!r}: expected one of {TIERS + ('auto',)}"
         )
-    if tier == "pallas" and jax.default_backend() != "tpu":
-        _LOGGER.warning(
-            "fused_kernels=pallas requested on backend=%s: degrading to the "
-            "padded-XLA tier (the Pallas kernels target TPU; CPU parity runs "
-            "use interpret mode in the test suite)",
-            jax.default_backend(),
-        )
-        _count_degrade()
-        tier = "xla"
-    if tier == "pallas" and "pallas" not in KERNELS[family]["tiers"]:
+    if tier == "pallas" and not has_pallas:
         _LOGGER.warning(
             "fused_kernels=pallas: kernel family %r has no Pallas tier yet — "
             "degrading to xla",
@@ -123,6 +121,12 @@ def resolve_tier(requested: Any, *, family: str = "hafner_ln_gru") -> str:
         )
         _count_degrade()
         tier = "xla"
+    if tier == "pallas" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"algo.fused_kernels=pallas on backend={jax.default_backend()}: the "
+            "Pallas kernels compile for TPU only. Use algo.fused_kernels=auto for "
+            "the best tier this backend has (xla here), or xla/off explicitly."
+        )
     if tier != "off":
         _ACTIVE_FUSED.add(tier)
     return tier
@@ -151,6 +155,33 @@ def default_pad_to(tier: str) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _pallas_or_twin(name, twin, interpret, *operands, hidden_size, eps):
+    """Tier ``pallas``: the Mosaic kernel where the program is lowered for
+    TPU, the kernel's padded-XLA twin (the program its custom-VJP already
+    differentiates) where a TPU run lowers it for the host CPU — the
+    ``algo.player_on_host`` acting mirror cannot run Mosaic code.
+    ``interpret=True`` (CPU parity tests) runs the kernel body itself."""
+    from sheeprl_tpu.kernels import pallas_tpu
+
+    kernel_fn = getattr(pallas_tpu, name)
+    layer_norm = operands[4] is not None
+
+    def run_kernel(*ops):
+        return kernel_fn(
+            *ops, hidden_size=hidden_size, eps=eps, layer_norm=layer_norm, interpret=interpret
+        )
+
+    if interpret:
+        return run_kernel(*operands)
+    return jax.lax.platform_dependent(
+        *operands,
+        tpu=run_kernel,
+        default=lambda *ops: twin(
+            *ops, hidden_size=hidden_size, eps=eps, pad_to=pallas_tpu.LANE
+        ),
+    )
+
+
 def hafner_gru_cell(
     h: jnp.ndarray,
     x: jnp.ndarray,
@@ -175,12 +206,9 @@ def hafner_gru_cell(
             pad_to=default_pad_to(tier) if pad_to is None else pad_to,
         )
     if tier == "pallas":
-        from sheeprl_tpu.kernels import pallas_tpu
-
-        return pallas_tpu.hafner_cell(
-            h, x, kernel, bias, ln_scale, ln_bias,
-            hidden_size=hidden_size, eps=eps,
-            layer_norm=ln_scale is not None, interpret=interpret,
+        return _pallas_or_twin(
+            "hafner_cell", xla.hafner_cell_fused, interpret,
+            h, x, kernel, bias, ln_scale, ln_bias, hidden_size=hidden_size, eps=eps,
         )
     raise ValueError(f"unknown kernel tier {tier!r}")
 
@@ -216,12 +244,9 @@ def hafner_gru_sequence(
             pad_to=default_pad_to(tier) if pad_to is None else pad_to,
         )
     if tier == "pallas":
-        from sheeprl_tpu.kernels import pallas_tpu
-
-        return pallas_tpu.hafner_sequence(
-            h0, xs, kernel, bias, ln_scale, ln_bias,
-            hidden_size=hidden_size, eps=eps,
-            layer_norm=ln_scale is not None, interpret=interpret,
+        return _pallas_or_twin(
+            "hafner_sequence", xla.hafner_sequence_fused, interpret,
+            h0, xs, kernel, bias, ln_scale, ln_bias, hidden_size=hidden_size, eps=eps,
         )
     raise ValueError(f"unknown kernel tier {tier!r}")
 

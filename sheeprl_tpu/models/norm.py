@@ -1,6 +1,6 @@
 """LayerNorm with a hand-derived one-pass backward (``jax.custom_vjp``).
 
-Why this exists (round-4, VERDICT item #4): the DV3 S-preset profile puts
+Why this exists (round 4): the DV3 S-preset profile puts
 ~2.3 ms of the 14.03 ms device step in LayerNorm *backward* lane reductions
 across the conv stacks — XLA autodiffs flax's ``nn.LayerNorm`` into a chain
 that re-derives the variance path and schedules several cross-lane

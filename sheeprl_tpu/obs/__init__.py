@@ -73,7 +73,6 @@ from sheeprl_tpu.obs.live import (
     prometheus_text,
 )
 from sheeprl_tpu.obs.perf import (
-    PEAK_TFLOPS_BF16,
     LoopProbe,
     cost_flops,
     log_sps_metrics,
@@ -99,7 +98,6 @@ __all__ = [
     "LiveExporter",
     "LoopProbe",
     "NonFiniteGuard",
-    "PEAK_TFLOPS_BF16",
     "PromServer",
     "StalenessTracker",
     "StallWatchdog",

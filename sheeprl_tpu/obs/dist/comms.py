@@ -103,8 +103,8 @@ def collective_span(kind: str, payload_bytes: int = 0, world: Optional[int] = No
 
 
 def link_peak_gbps() -> Optional[float]:
-    """This host's device-link peak GB/s (ICI for TPUs, estimated loopback
-    for CPU test meshes) from the roofline registry, or None."""
+    """This host's device-link peak GB/s (ICI for TPUs) from the roofline
+    registry, or None for a device the registry does not list."""
     from sheeprl_tpu.obs.prof.roofline import detect_link_peaks
 
     return detect_link_peaks().get("link_gbps")

@@ -18,7 +18,6 @@ from typing import Any, Dict, Optional
 
 __all__ = [
     "LoopProbe",
-    "PEAK_TFLOPS_BF16",
     "cost_flops",
     "log_sps_metrics",
     "mfu_pct",
@@ -26,20 +25,13 @@ __all__ = [
     "shape_specs",
 ]
 
-#: TPU v5e single-chip bf16 peak — the default MFU denominator
-#: (``metric.telemetry.peak_tflops`` overrides; 32-true programs are measured
-#: against the same bf16 peak so numbers stay comparable across precisions).
-PEAK_TFLOPS_BF16 = 197.0
-
-
 class LoopProbe:
     """Env-gated per-phase wall-time probe for latency-dominated hot loops.
 
     ``SHEEPRL_LOOP_TRACE=1`` prints the mean per-iteration wall time of each
-    ``lap``-delimited slice every ``every`` iterations — the remote-attached
-    device loop is latency-dominated and the TB timers can't see through
-    async dispatch, so this is the ground truth for where a slow loop spends
-    its time. The algorithms use this instead of hand-rolled
+    ``lap``-delimited slice every ``every`` iterations — the TB timers
+    can't see through async dispatch, so this is the ground truth for where
+    a slow loop spends its host time. The algorithms use this instead of hand-rolled
     ``time.perf_counter()`` deltas (``tools/lint_telemetry.py`` rejects those
     in ``sheeprl_tpu/algos/`` so loop accounting stays in one place); when
     the env var is unset every call is a single attribute check.
@@ -183,10 +175,12 @@ def mfu_pct(
     flops_per_step: Optional[float],
     steps: float,
     seconds: Optional[float],
-    peak_tflops: float = PEAK_TFLOPS_BF16,
+    peak_tflops: Optional[float],
 ) -> Optional[float]:
-    """Model FLOPs utilization in percent, or None when unmeasurable."""
-    if not flops_per_step or not seconds or seconds <= 0 or steps <= 0 or peak_tflops <= 0:
+    """Model FLOPs utilization in percent, or None when unmeasurable — which
+    includes a device with no entry in ``obs.prof.roofline.DEVICE_PEAKS``
+    (``peak_tflops`` None): no peak, no MFU."""
+    if not flops_per_step or not seconds or seconds <= 0 or steps <= 0 or not peak_tflops:
         return None
     return round(flops_per_step * steps / seconds / (peak_tflops * 1e12) * 100.0, 3)
 

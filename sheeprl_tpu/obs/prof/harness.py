@@ -138,8 +138,8 @@ def build_harness(
         raise ValueError(f"unknown family {family!r}; choose from {sorted(FAMILIES)}")
     import jax
 
-    # eager init work stays on the host (bench_dreamer's rationale: on a
-    # remote-attached device every eager op is a dispatch round trip)
+    # same pin as Fabric.launch: uncommitted eager init work runs on the
+    # host CPU; the step's inputs are committed to the mesh
     jax.config.update("jax_default_device", jax.devices("cpu")[0])
     ovr = list(tiny_overrides(family) if tiny else ()) + list(overrides)
     if family in ("sac", "ppo"):

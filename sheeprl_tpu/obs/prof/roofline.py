@@ -10,16 +10,16 @@ the ceiling), **HBM bandwidth** (achieved GB/s is the ceiling), or by
 work will help until the dispatch path does). ROADMAP item 4 needs exactly
 this verdict per Dreamer family before choosing a Pallas target.
 
-Peak numbers come from a small device registry keyed on
-``jax.devices()[0].device_kind`` with a CPU fallback estimated from the core
-count — estimated peaks are flagged ``estimated: True`` and make the
-*relative* verdicts meaningful on hosts without an accelerator (tests, the
-CI dry-run), while absolute MFU on CPU is read as indicative only.
+Peak numbers come from the one ``DEVICE_PEAKS`` table, keyed on
+``device_kind``. A device that is not in the table (the host CPU included)
+has no peak: its MFU, bandwidth utilization and ridge point are ``None`` —
+not measured — never an estimate and never another device's number. An
+explicit override (``metric.telemetry.peak_tflops`` /
+``metric.telemetry.profile.peak_*``) always wins.
 """
 
 from __future__ import annotations
 
-import os
 import re
 from typing import Any, Dict, Optional
 
@@ -34,8 +34,9 @@ __all__ = [
 ]
 
 #: device_kind pattern -> (peak TFLOP/s in bf16, peak HBM GB/s). Single-chip
-#: numbers from the vendor datasheets; the MFU denominator stays the chip's
-#: bf16 peak for 32-true programs too (same convention as obs/perf.py).
+#: numbers from the vendor datasheets (v5e: Google Cloud "TPU v5e" page —
+#: 197 TFLOP/s bf16, 819 GB/s HBM); the MFU denominator stays the chip's
+#: bf16 peak for 32-true programs too.
 DEVICE_PEAKS = (
     (r"TPU v6|Trillium", {"label": "TPU v6e", "peak_tflops": 918.0, "peak_gbps": 1640.0}),
     (r"TPU v5p", {"label": "TPU v5p", "peak_tflops": 459.0, "peak_gbps": 2765.0}),
@@ -51,8 +52,7 @@ DEVICE_PEAKS = (
 
 
 #: device_kind pattern -> inter-chip link peak, GB/s per link per direction
-#: (ICI for TPUs from the public specs — the same ballpark
-#: tools/bench_scaling.py projects with; NVLink-generation numbers for the
+#: (ICI for TPUs from the public specs; NVLink-generation numbers for the
 #: GPUs). The comms instrumentation (obs/dist/comms.py) reports achieved
 #: wire GB/s against this as `link_util_pct`.
 LINK_PEAKS = (
@@ -68,85 +68,57 @@ LINK_PEAKS = (
 )
 
 
-def detect_link_peaks(link_gbps: Optional[float] = None) -> Dict[str, Any]:
-    """Inter-chip link peak for this host's first jax device.
+def _first_device(device: Any = None) -> Any:
+    if device is not None:
+        return device
+    import jax
 
-    Returns ``{label, device_kind, link_gbps, estimated}``. On CPU test
-    meshes the "link" is the host's own memory system (gloo over loopback
-    for multi-process runs) — estimated from the DDR figure so the relative
-    utilization numbers stay meaningful; an explicit ``link_gbps`` override
-    always wins."""
-    kind = "unknown"
-    try:
-        import jax
+    return jax.devices()[0]
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", dev.platform)
-    except Exception:
-        pass
-    out: Dict[str, Any] = {"device_kind": kind, "estimated": False}
+
+def detect_link_peaks(link_gbps: Optional[float] = None, device: Any = None) -> Dict[str, Any]:
+    """Inter-chip link peak of ``device`` (default: the first jax device).
+
+    Returns ``{label, device_kind, link_gbps}``; ``link_gbps`` is ``None``
+    for a device that is not in ``LINK_PEAKS`` (a CPU mesh has no link to
+    rate against). An explicit ``link_gbps`` override always wins."""
+    kind = _first_device(device).device_kind
+    out: Dict[str, Any] = {"device_kind": kind, "label": kind, "link_gbps": None}
     for pattern, gbps in LINK_PEAKS:
         if re.search(pattern, kind, re.I):
-            out.update({"label": kind, "link_gbps": gbps})
+            out["link_gbps"] = gbps
             break
-    else:
-        # CPU / unknown device: loopback collectives bottleneck on memcpy
-        # bandwidth — reuse the estimated DDR figure, flagged estimated
-        out.update({"label": f"{kind} (estimated link)", "link_gbps": _cpu_peaks()["peak_gbps"], "estimated": True})
     if link_gbps:
         out["link_gbps"] = float(link_gbps)
-        out["estimated"] = False
     return out
 
 
-def _cpu_peaks() -> Dict[str, Any]:
-    """Order-of-magnitude CPU peaks so the roofline runs everywhere: AVX-512
-    FMA at 32 FLOPs/cycle/core × a nominal 3 GHz, and a nominal dual-channel
-    DDR bandwidth. Flagged estimated — the verdicts stay comparative."""
-    cores = os.cpu_count() or 1
-    return {
-        "label": f"CPU ({cores} cores, estimated)",
-        "peak_tflops": round(cores * 3.0e9 * 32 / 1e12, 2),
-        "peak_gbps": 64.0,
-        "estimated": True,
-    }
-
-
 def detect_peaks(
-    peak_tflops: Optional[float] = None, peak_gbps: Optional[float] = None
+    peak_tflops: Optional[float] = None,
+    peak_gbps: Optional[float] = None,
+    device: Any = None,
 ) -> Dict[str, Any]:
-    """Peak numbers for this host's first jax device (overridable).
+    """Peak numbers of ``device`` (default: the first jax device).
 
-    Returns ``{label, platform, device_kind, peak_tflops, peak_gbps,
-    estimated}``; explicit overrides win over the registry."""
-    platform = kind = "unknown"
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        platform, kind = dev.platform, getattr(dev, "device_kind", dev.platform)
-    except Exception:
-        pass
-    peaks: Dict[str, Any] = {"estimated": False}
-    if platform == "cpu" or kind == "unknown":
-        peaks.update(_cpu_peaks())
-    else:
-        for pattern, entry in DEVICE_PEAKS:
-            if re.search(pattern, kind, re.I):
-                peaks.update(entry)
-                break
-        else:
-            peaks.update({"label": kind, "peak_tflops": None, "peak_gbps": None})
-    peaks["platform"] = platform
-    peaks["device_kind"] = kind
+    Returns ``{label, platform, device_kind, peak_tflops, peak_gbps}``. Both
+    peaks are ``None`` for a device that is not in ``DEVICE_PEAKS``;
+    explicit overrides win over the table."""
+    dev = _first_device(device)
+    peaks: Dict[str, Any] = {
+        "label": dev.device_kind,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "peak_tflops": None,
+        "peak_gbps": None,
+    }
+    for pattern, entry in DEVICE_PEAKS:
+        if re.search(pattern, dev.device_kind, re.I):
+            peaks.update(entry)
+            break
     if peak_tflops:
         peaks["peak_tflops"] = float(peak_tflops)
     if peak_gbps:
         peaks["peak_gbps"] = float(peak_gbps)
-    if peak_tflops and peak_gbps:
-        # only a FULL override clears the flag — with one axis still guessed
-        # the verdict is still derived from an estimated peak
-        peaks["estimated"] = False
     return peaks
 
 

@@ -21,8 +21,15 @@ key                       meaning
 ``sps``                   policy_steps / run_wall_s (whole-run average)
 ``sps_env``               policy_steps / timed env-interaction seconds
 ``sps_train``             train_steps / timed train seconds
-``mfu``                   % of ``peak_tflops`` sustained during timed train
-                          seconds (null until an algo registers step FLOPs)
+``mfu``                   % of ``mfu_peak_tflops`` sustained during timed
+                          train seconds (null until an algo registers step
+                          FLOPs, and for a device with no known peak)
+``mfu_peak_tflops``       the MFU denominator: ``metric.telemetry.peak_tflops``
+                          when set, else the ``DEVICE_PEAKS`` entry of the
+                          mesh's ``device_kind`` (null when it has none)
+``platform``              ``platform`` / ``device_kind`` / ``device_count`` of
+``device_kind``           the devices the run's Fabric mesh spans — every
+``device_count``          number in this file was measured on these
 ``bytes_staged_h2d``      bytes shipped host→device through the staging paths
 ``h2d_transfers``         number of staged transfers
 ``recompiles``            XLA backend compiles observed (jax.monitoring)
@@ -82,7 +89,7 @@ from sheeprl_tpu.obs import counters as _counters
 from sheeprl_tpu.obs import hist as _hist
 from sheeprl_tpu.obs.health import NonFiniteGuard, StallWatchdog
 from sheeprl_tpu.obs.live import FlightRecorder, LiveExporter, PromServer, atomic_write_json
-from sheeprl_tpu.obs.perf import PEAK_TFLOPS_BF16, mfu_pct
+from sheeprl_tpu.obs.perf import mfu_pct
 from sheeprl_tpu.obs.spans import TraceWriter, set_tracer
 
 __all__ = ["Telemetry", "setup_telemetry", "get_telemetry", "finalize_telemetry"]
@@ -105,9 +112,17 @@ def _process_index() -> int:
 
 
 class Telemetry:
-    def __init__(self, tcfg: Optional[Dict[str, Any]] = None):
+    def __init__(self, tcfg: Optional[Dict[str, Any]] = None, devices: Optional[list] = None):
+        """``devices`` are the devices the run computes on (the Fabric mesh;
+        default: every jax device) — named in the summary, polled for HBM
+        use, and the key into the peak table."""
         tcfg = dict(tcfg or {})
         self.cfg = tcfg
+        if devices is None:
+            import jax
+
+            devices = jax.devices()
+        self.devices = list(devices)
         self.trace_enabled = bool(tcfg.get("trace", True))
         self.trace_file: Optional[str] = tcfg.get("trace_file") or None
         self.xla_annotations = bool(tcfg.get("xla_annotations", True))
@@ -115,7 +130,12 @@ class Telemetry:
         self.stall_timeout_s = float(tcfg.get("stall_timeout_s", 120.0) or 0.0)
         self.summary_enabled = bool(tcfg.get("summary", True))
         self.summary_path: Optional[str] = tcfg.get("summary_path") or None
-        self.peak_tflops = float(tcfg.get("peak_tflops", PEAK_TFLOPS_BF16))
+        from sheeprl_tpu.obs.prof.roofline import detect_peaks
+
+        #: MFU denominator, or None: a device outside DEVICE_PEAKS has no MFU
+        self.peak_tflops: Optional[float] = detect_peaks(
+            tcfg.get("peak_tflops"), device=self.devices[0]
+        )["peak_tflops"]
         # live plane (obs/live.py)
         self.live_interval_s = float(tcfg.get("live_interval_s", 30.0) or 0.0)
         self.live_window_s = float(tcfg.get("live_window_s", 60.0) or 60.0)
@@ -185,7 +205,10 @@ class Telemetry:
             self.staleness = _staleness.StalenessTracker()
             _staleness.install(self.staleness)
         if self.poll_interval_s > 0:
-            self.poller = _counters.DevicePoller(self.poll_interval_s)
+            self.poller = _counters.DevicePoller(
+                self.poll_interval_s,
+                devices=[d for d in self.devices if d.process_index == _process_index()],
+            )
             self.poller.start()
         fcfg = self._flight_cfg
         if bool(fcfg.get("enabled", True)):
@@ -467,6 +490,9 @@ class Telemetry:
                 self.peak_tflops,
             ),
             "mfu_peak_tflops": self.peak_tflops,
+            "platform": self.devices[0].platform,
+            "device_kind": self.devices[0].device_kind,
+            "device_count": len(self.devices),
             "flops_per_train_step": self.flops_per_train_step,
             "bytes_per_train_step": self.bytes_per_train_step,
             "env_seconds": round(self.env_seconds, 3),
@@ -664,6 +690,7 @@ class Telemetry:
         lines = [
             "── run telemetry "
             + "─" * 46,
+            f"  device {s['platform']} · {s['device_kind']} × {s['device_count']}",
             f"  wall {s['run_wall_s']:.1f}s · " + steps,
             f"  staged h2d {fmt_bytes(s['bytes_staged_h2d'])} over "
             f"{s['h2d_transfers']} transfers · recompiles {s['recompiles']} "
@@ -760,9 +787,10 @@ class Telemetry:
         print("\n".join(lines), flush=True)
 
 
-def setup_telemetry(cfg) -> Optional[Telemetry]:
+def setup_telemetry(cfg, devices: Optional[list] = None) -> Optional[Telemetry]:
     """Build and activate telemetry from a composed run config (or return
-    None when ``metric.telemetry.enabled`` is off/absent)."""
+    None when ``metric.telemetry.enabled`` is off/absent). ``devices``: the
+    Fabric mesh's devices (see :class:`Telemetry`)."""
     global _ACTIVE
     tcfg = {}
     try:
@@ -772,7 +800,7 @@ def setup_telemetry(cfg) -> Optional[Telemetry]:
     if not tcfg.get("enabled", False):
         _ACTIVE = None
         return None
-    telemetry = Telemetry(tcfg)
+    telemetry = Telemetry(tcfg, devices=devices)
     telemetry.start()
     _ACTIVE = telemetry
     return telemetry

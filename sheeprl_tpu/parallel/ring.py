@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sheeprl_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-from sheeprl_tpu.utils.jax_compat import axis_size, shard_map
 
 _NEG_INF = -1e30
 
@@ -95,7 +94,7 @@ def ring_attention(
     this; at the ring sizes the framework targets (≤ one pod slice) the
     imbalance is bounded by 2× on the attention FLOPs only.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = float(q.shape[-1]) ** -0.5 if scale is None else scale
     b, tq, h, d = q.shape
@@ -156,7 +155,7 @@ def ulysses_attention(
     head sharding (full T on every device), local attention runs, a second
     all-to-all restores sequence sharding.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if q.shape[2] % p != 0:
         raise ValueError(f"ulysses needs heads ({q.shape[2]}) divisible by axis size ({p})")
 
@@ -195,4 +194,4 @@ def ring_self_attention(
     ba = batch_axis if batch_axis in mesh.shape else None
     spec = P(ba, seq_axis)
     local = functools.partial(fn, axis_name=seq_axis, causal=causal)
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)

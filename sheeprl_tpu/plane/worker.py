@@ -263,16 +263,13 @@ def child_main(spec: Dict[str, Any]) -> None:
     # preemption signals go to the learner; players drain via the stop event
     signal.signal(signal.SIGTERM, signal.SIG_IGN)
     signal.signal(signal.SIGINT, signal.SIG_IGN)
-    # before ANY jax import: players live on the host CPU, never the mesh
-    os.environ["JAX_PLATFORMS"] = "cpu"
-
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    from sheeprl_tpu.utils.utils import enable_persistent_compilation_cache, pin_process_to_cpu
+
+    pin_process_to_cpu()  # players live on the host CPU, never the mesh
     if spec.get("prng_impl"):
         jax.config.update("jax_default_prng_impl", str(spec["prng_impl"]))
-    from sheeprl_tpu.utils.utils import enable_persistent_compilation_cache
-
     enable_persistent_compilation_cache()
 
     idx = int(spec["player_idx"])

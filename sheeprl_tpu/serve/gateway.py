@@ -315,8 +315,8 @@ class ServeContext:
     (the :class:`~sheeprl_tpu.plane.worker.PlayerContext` shape, collapsed to
     the client side): the ring, the client's slot, and a ``module:function``
     entry point called as ``entry(client, spec)``. ``child_main`` pins the
-    child to the CPU jax backend before any jax import — serve clients never
-    touch the device."""
+    child to the CPU jax backend first — serve clients never touch the
+    device."""
 
     def __init__(self, ring, slot: int, entry: str, spec: Optional[Dict[str, Any]] = None):
         self.ring = ring
@@ -327,10 +327,12 @@ class ServeContext:
 
 def child_main(ctx: ServeContext) -> None:
     """Client-process entry point (spawned, never forked)."""
-    os.environ["JAX_PLATFORMS"] = "cpu"  # before ANY jax import
     import importlib
 
     from sheeprl_tpu.serve.client import RingServeClient
+    from sheeprl_tpu.utils.utils import pin_process_to_cpu
+
+    pin_process_to_cpu()  # serve clients never touch the device
 
     module_name, _, fn_name = ctx.entry.partition(":")
     fn = getattr(importlib.import_module(module_name), fn_name)

@@ -185,7 +185,7 @@ def test_resume_accounts_for_every_typed_override(tmp_path):
     """Silently-skipped override classes (group selections, dict-valued keys,
     ~deletions, bare flags) must be reported in the re-apply warning with a
     reason, so every typed token is accounted for as re-applied, rejected, or
-    ignored-with-reason (round-5 ADVICE)."""
+    ignored-with-reason."""
     import warnings as _warnings
 
     import yaml

@@ -1,4 +1,4 @@
-"""Edge-path buffer tests (round-1 VERDICT #9): wrap-around × memmap
+"""Edge-path buffer tests: wrap-around × memmap
 interplay, trailing-window overwrites, `prioritize_ends` edges, episode
 chunking across `add` calls, eviction file cleanup, and state-dict round
 trips — the hairy paths the reference pins with ~75 property-style tests

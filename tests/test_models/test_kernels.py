@@ -11,7 +11,8 @@ Tier contract (ISSUE 13 / howto/kernels.md):
   within float tolerance, and the ``custom_vjp`` backward must match
   reference autodiff (it IS the padded-XLA autodiff by construction —
   these tests pin that the padded program's gradient matches the
-  real-width reference gradient).
+  real-width reference gradient). Whether Mosaic compiles the kernels is
+  ``chip_smoke.py``'s check, on the TPU.
 
 Width sweep includes the DV2 production shape (600, straddling the
 128-lane tile), a prime just under it (599), an exact tile (128), and the
@@ -333,20 +334,55 @@ def test_normalize_tier_yaml_spellings():
     assert normalize_tier(" pallas ") == "pallas"
 
 
-def test_resolve_tier_degrades_pallas_off_tpu_and_counts():
-    if jax.default_backend() == "tpu":
-        pytest.skip("degrade path is the non-TPU behavior")
+def test_resolve_tier_explicit_pallas_off_tpu_raises_auto_resolves_xla():
+    """An explicit pallas request on a backend that cannot compile it is an
+    error, never a quiet xla; `auto` is the way to ask for the best tier."""
     from sheeprl_tpu.obs import counters as obs_counters
 
     c = obs_counters.Counters()
     obs_counters.install(c)
     try:
-        assert resolve_tier("pallas", family="hafner_ln_gru") == "xla"
-        # DV1's family has no pallas tier at all — also a degrade
+        with pytest.raises(ValueError, match="fused_kernels=pallas on backend=cpu"):
+            resolve_tier("pallas", family="hafner_ln_gru")
+        assert resolve_tier("auto", family="hafner_ln_gru") == "xla"
+        assert resolve_tier("auto", family="flax_gru") == "xla"
+        assert c.kernel_tier_degraded == 0
+        # DV1's family has no pallas kernel at all: the one remaining degrade
         assert resolve_tier("pallas", family="flax_gru") == "xla"
-        assert c.kernel_tier_degraded == 2
+        assert c.kernel_tier_degraded == 1
     finally:
         obs_counters.install(None)
+
+
+def test_pallas_compiler_params_construct_on_installed_jax():
+    """Interpret mode never builds the Mosaic parameters, so a renamed
+    pltpu class (TPUCompilerParams -> CompilerParams) would only surface on
+    the chip: build them here."""
+    from sheeprl_tpu.kernels import pallas_tpu
+
+    params = pallas_tpu._compiler_params("arbitrary", 16, 640, 512)
+    assert tuple(params.dimension_semantics) == ("arbitrary",)
+    # the double-buffered [Hp+Xp, 3*Hp] f32 weight alone is 17.7 MB
+    assert 2 * 4 * 1152 * 1920 < params.vmem_limit_bytes < (100 << 20)
+
+
+def test_pallas_tier_lowers_mosaic_for_tpu_and_xla_twin_for_cpu():
+    """Tier `pallas` picks per LOWERING platform: the Mosaic kernel in a TPU
+    program, the padded-XLA twin in a host-CPU program of the same run (the
+    algo.player_on_host acting mirror) — and no path takes the interpreter."""
+    H, X = 600, 400
+    h, x, kernel, bias, scale, ln_bias = _hafner_operands(H, X)
+
+    def cell(*operands):
+        return registry.hafner_gru_cell(*operands, hidden_size=H, eps=1e-5, tier="pallas")
+
+    traced = jax.jit(cell).trace(h, x, kernel, bias, scale, ln_bias)
+    assert "tpu_custom_call" in traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()
+    # on this CPU backend the call itself runs the twin
+    got = cell(h, x, kernel, bias, scale, ln_bias)
+    want = reference.hafner_cell(h, x, kernel, bias, scale, ln_bias, eps=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
 def test_resolve_tier_rejects_unknown():

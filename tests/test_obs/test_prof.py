@@ -85,7 +85,7 @@ def test_phase_mapping_heuristics():
 
 # -- roofline verdicts --------------------------------------------------------
 
-PEAKS = {"label": "test", "peak_tflops": 100.0, "peak_gbps": 1000.0, "estimated": False}
+PEAKS = {"label": "test", "peak_tflops": 100.0, "peak_gbps": 1000.0}
 
 
 def test_verdict_unmeasured_without_device_time():
@@ -124,19 +124,33 @@ def test_verdict_unknown_without_cost_analysis():
     assert out["verdict"] == "unknown"
 
 
-def test_detect_peaks_cpu_fallback_is_estimated():
-    peaks = detect_peaks()
-    # tier-1 runs pin JAX_PLATFORMS=cpu: the registry must fall back to an
-    # estimated entry rather than failing or claiming TPU numbers
-    assert peaks["peak_tflops"] and peaks["peak_gbps"]
-    if peaks["platform"] == "cpu":
-        assert peaks["estimated"] is True
+def test_detect_peaks_unlisted_device_has_no_peak_and_no_mfu():
+    """A device outside DEVICE_PEAKS (the CPU these tests run on, or any
+    unknown kind) gets no peak — so no MFU and no roofline share — never an
+    estimate and never the v5e number by default."""
+    from types import SimpleNamespace
+
+    from sheeprl_tpu.obs.perf import mfu_pct
+    from sheeprl_tpu.obs.prof.roofline import detect_link_peaks
+
+    for peaks in (
+        detect_peaks(),
+        detect_peaks(device=SimpleNamespace(platform="tpu", device_kind="TPU v99x")),
+    ):
+        assert peaks["peak_tflops"] is None and peaks["peak_gbps"] is None
+        assert "estimated" not in peaks
+        assert mfu_pct(1e12, 10, 1.0, peaks["peak_tflops"]) is None
+        out = roofline_analyze(1e9, 1e6, 2.0, busy_frac=0.9, peaks=peaks)
+        assert out["mfu_pct"] is None and out["bandwidth_util_pct"] is None
+        assert out["ridge_intensity"] is None and out["verdict"] == "unknown"
+    assert detect_link_peaks()["link_gbps"] is None
+    # the one table, keyed by device_kind: the attached chip reports itself
+    # as "TPU v5 lite"
+    v5e = detect_peaks(device=SimpleNamespace(platform="tpu", device_kind="TPU v5 lite"))
+    assert (v5e["peak_tflops"], v5e["peak_gbps"]) == (197.0, 819.0)
+    # an explicit override keeps working, on any device
     assert detect_peaks(peak_tflops=197.0)["peak_tflops"] == 197.0
-    if peaks.get("estimated"):
-        # a partial override must NOT clear the estimated flag — the other
-        # axis (and so the verdict) is still derived from a guess
-        assert detect_peaks(peak_tflops=197.0)["estimated"] is True
-        assert detect_peaks(peak_tflops=197.0, peak_gbps=819.0)["estimated"] is False
+    assert mfu_pct(1e12, 10, 1.0, 197.0) == pytest.approx(5.076, abs=1e-3)
 
 
 # -- cost-analysis fallbacks --------------------------------------------------

@@ -33,6 +33,14 @@ def test_fabric_too_many_devices():
         Fabric(devices=1024, accelerator="cpu")
 
 
+def test_fabric_named_accelerator_without_its_platform_raises():
+    # the suite runs on a CPU-only backend: asking for a TPU must fail, not
+    # warn and train on the CPU under the TPU's name
+    with pytest.raises(RuntimeError, match="fabric.accelerator=tpu"):
+        Fabric(devices=1, accelerator="tpu")
+    assert Fabric(devices=1, accelerator="auto").device.platform == jax.default_backend()
+
+
 def test_fabric_shard_data_places_on_mesh():
     fabric = Fabric(devices=8, accelerator="cpu")
     x = np.arange(32, dtype=np.float32).reshape(8, 4)

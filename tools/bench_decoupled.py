@@ -1,6 +1,6 @@
 """Coupled vs decoupled PPO/SAC throughput on the virtual CPU mesh.
 
-Measures the player-thread/double-buffering win (round-1 VERDICT #10): the
+Measures the player-thread/double-buffering win (round 1): the
 decoupled runner overlaps env stepping with the update program, so at
 identical configs its wall-clock should beat the strictly-alternating
 coupled loop whenever env interaction is a non-trivial fraction of the

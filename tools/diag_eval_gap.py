@@ -10,7 +10,7 @@ checkpoint; the variants that run are (in order):
   B. train-style vector acting with template-less-restored params
   A. eval-style single env (skipped with SHEEPRL_DIAG_ONLY_E=1)
 
-Outcome of the round-5 investigation (BENCH_WALKER.md): with the DMC
+Outcome of the round-5 investigation (record deleted in PR 21): with the DMC
 seeding fix and the train key-chain, E reproduces the CLI training loop's
 no-learning episodes BIT-EXACTLY — the historical gap came from the CLI
 dropping resume overrides (so "no-learn" probes actually trained).

@@ -5,7 +5,7 @@ merges the `Rewards/rew_avg` scalars by policy step, and prints:
 
 - the merged curve (step -> mean episode reward, downsampled),
 - sustained-performance stats (best, last-10k-step mean),
-- the success verdict against the VERDICT bar (sustained >= 5x random).
+- the success verdict against the round-5 bar (sustained >= 5x random).
 
 Usage: python tools/walker_report.py [run_glob]
 """
