@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    summary = run.device_summary()
+    if summary is None:
+        return None
+    return 100.0 * (1.0 - summary["busy_s"] / summary["window_s"])
