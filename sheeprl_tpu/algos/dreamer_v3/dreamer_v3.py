@@ -66,7 +66,6 @@ from sheeprl_tpu.utils.logger import create_tensorboard_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
 from sheeprl_tpu.utils.registry import register_algorithm
 from sheeprl_tpu.obs import (
-    LoopProbe,
     learn_probes,
     log_sps_metrics,
     probes_enabled,
@@ -171,22 +170,24 @@ def build_train_fn(
 
     def wm_loss_fn(wm_params, data, key):
         T, B = data["rewards"].shape[:2]
-        batch_obs = {k: data[k] / 255.0 for k in cnn_keys}
-        batch_obs.update({k: data[k] for k in mlp_keys})
-        is_first = data["is_first"].at[0].set(1.0)
-        # shift: the action column becomes "action that led here"
-        batch_actions = jnp.concatenate(
-            [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
-        )
-        embedded = wm_apply(wm_params, WorldModel.encode, batch_obs)
-        # hoist the embed half of the posterior trunk out of the time scan:
-        # one [T*B, E]×[E, H] matmul here instead of T sequential [B, E]×[E, H]
-        embed_proj = wm_apply(wm_params, WorldModel.project_embed, embedded)
+        with jax.named_scope("dv3/encoder"):
+            batch_obs = {k: data[k] / 255.0 for k in cnn_keys}
+            batch_obs.update({k: data[k] for k in mlp_keys})
+            is_first = data["is_first"].at[0].set(1.0)
+            # shift: the action column becomes "action that led here"
+            batch_actions = jnp.concatenate(
+                [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
+            )
+            embedded = wm_apply(wm_params, WorldModel.encode, batch_obs)
+            # hoist the embed half of the posterior trunk out of the time scan:
+            # one [T*B, E]×[E, H] matmul here instead of T sequential [B, E]×[E, H]
+            embed_proj = wm_apply(wm_params, WorldModel.project_embed, embedded)
         # the is_first reset posterior is the prior mode at a zeroed recurrent
         # state — a constant, computed once (broadcast over B inside the scan)
-        init_post = wm_apply(
-            wm_params, WorldModel.initial_posterior, jnp.zeros((1, rec_size))
-        )
+        with jax.named_scope("dv3/rssm"):
+            init_post = wm_apply(
+                wm_params, WorldModel.initial_posterior, jnp.zeros((1, rec_size))
+            )
 
         def step(carry, inp):
             posterior, recurrent = carry
@@ -207,40 +208,42 @@ def build_train_fn(
 
         # pre-draw the posterior sampling noise for the whole sequence in one
         # vectorized call; the scan body is left with add+argmax only
-        gumbels = jax.random.gumbel(key, (T, B, S, D))
-        (_, _), (recurrents, posteriors, post_logits) = jax.lax.scan(
-            step,
-            (jnp.zeros((B, stoch_flat)), jnp.zeros((B, rec_size))),
-            (batch_actions, embed_proj, is_first, gumbels),
-        )
-        # prior (transition) logits never feed back into the loop: batch them
-        # over the whole [T, B] recurrent-state sequence after the scan
-        prior_logits = wm_apply(wm_params, WorldModel.prior_logits, recurrents)
-        latents = jnp.concatenate([posteriors, recurrents], -1)
-        recon = wm_apply(wm_params, WorldModel.decode, latents)
-        po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec_keys}
-        po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec_keys})
-        pr = TwoHotEncodingDistribution(
-            wm_apply(wm_params, WorldModel.reward_logits, latents), dims=1
-        )
-        pc = continue_distribution(
-            wm_apply(wm_params, WorldModel.continue_logits, latents)
-        )
-        loss, metrics = reconstruction_loss(
-            po,
-            batch_obs,
-            pr,
-            data["rewards"],
-            prior_logits.reshape(T, B, S, D),
-            post_logits.reshape(T, B, S, D),
-            kl_dynamic,
-            kl_representation,
-            kl_free_nats,
-            kl_regularizer,
-            pc,
-            1.0 - data["dones"],
-            continue_scale,
-        )
+        with jax.named_scope("dv3/rssm"):
+            gumbels = jax.random.gumbel(key, (T, B, S, D))
+            (_, _), (recurrents, posteriors, post_logits) = jax.lax.scan(
+                step,
+                (jnp.zeros((B, stoch_flat)), jnp.zeros((B, rec_size))),
+                (batch_actions, embed_proj, is_first, gumbels),
+            )
+        with jax.named_scope("dv3/heads"):
+            # prior (transition) logits never feed back into the loop: batch
+            # them over the whole [T, B] recurrent-state sequence after the scan
+            prior_logits = wm_apply(wm_params, WorldModel.prior_logits, recurrents)
+            latents = jnp.concatenate([posteriors, recurrents], -1)
+            recon = wm_apply(wm_params, WorldModel.decode, latents)
+            po = {k: MSEDistribution(recon[k], dims=3) for k in cnn_dec_keys}
+            po.update({k: SymlogDistribution(recon[k], dims=1) for k in mlp_dec_keys})
+            pr = TwoHotEncodingDistribution(
+                wm_apply(wm_params, WorldModel.reward_logits, latents), dims=1
+            )
+            pc = continue_distribution(
+                wm_apply(wm_params, WorldModel.continue_logits, latents)
+            )
+            loss, metrics = reconstruction_loss(
+                po,
+                batch_obs,
+                pr,
+                data["rewards"],
+                prior_logits.reshape(T, B, S, D),
+                post_logits.reshape(T, B, S, D),
+                kl_dynamic,
+                kl_representation,
+                kl_free_nats,
+                kl_regularizer,
+                pc,
+                1.0 - data["dones"],
+                continue_scale,
+            )
         return loss, (metrics, sg(posteriors), sg(recurrents))
 
     # ------------------------------------------------------------------
@@ -306,58 +309,60 @@ def build_train_fn(
 
     def actor_loss_fn(actor_params, wm_params, critic_params, posteriors, recurrents,
                       true_continue, moments_state, key):
-        traj, imagined_actions = imagination_rollout(
-            wm_params, actor_params, posteriors, recurrents, key
-        )
-        predicted_values = TwoHotEncodingDistribution(
-            critic.apply({"params": critic_params}, traj), dims=1
-        ).mean
-        predicted_rewards = TwoHotEncodingDistribution(
-            wm_apply(wm_params, WorldModel.reward_logits, traj), dims=1
-        ).mean
-        continues = continue_distribution(
-            wm_apply(wm_params, WorldModel.continue_logits, traj)
-        ).base.mode
-        continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
+        with jax.named_scope("dv3/imagination"):
+            traj, imagined_actions = imagination_rollout(
+                wm_params, actor_params, posteriors, recurrents, key
+            )
+        with jax.named_scope("dv3/behavior"):
+            predicted_values = TwoHotEncodingDistribution(
+                critic.apply({"params": critic_params}, traj), dims=1
+            ).mean
+            predicted_rewards = TwoHotEncodingDistribution(
+                wm_apply(wm_params, WorldModel.reward_logits, traj), dims=1
+            ).mean
+            continues = continue_distribution(
+                wm_apply(wm_params, WorldModel.continue_logits, traj)
+            ).base.mode
+            continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
 
-        lambda_values = compute_lambda_values(
-            predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
-        )
-        discount = sg(jnp.cumprod(continues * gamma, axis=0) / gamma)
+            lambda_values = compute_lambda_values(
+                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
+            )
+            discount = sg(jnp.cumprod(continues * gamma, axis=0) / gamma)
 
-        pre = actor.apply({"params": actor_params}, sg(traj))
-        policies = build_actor_dists(
-            pre, is_continuous, distribution, init_std, min_std, unimix
-        )
+            pre = actor.apply({"params": actor_params}, sg(traj))
+            policies = build_actor_dists(
+                pre, is_continuous, distribution, init_std, min_std, unimix
+            )
 
-        baseline = predicted_values[:-1]
-        new_moments, offset, invscale = update_moments(
-            moments_state, lambda_values, m_decay, m_max, m_low, m_high, axis_name=axis
-        )
-        advantage = (lambda_values - offset) / invscale - (baseline - offset) / invscale
+            baseline = predicted_values[:-1]
+            new_moments, offset, invscale = update_moments(
+                moments_state, lambda_values, m_decay, m_max, m_low, m_high, axis_name=axis
+            )
+            advantage = (lambda_values - offset) / invscale - (baseline - offset) / invscale
 
-        if is_continuous:
-            objective = advantage
-        else:
-            per_head = [
-                p.log_prob(sg(a))[..., None][:-1]
-                for p, a in zip(policies, jnp.split(imagined_actions, splits, axis=-1))
-            ]
-            objective = sum(per_head) * sg(advantage)
-        entropy = ent_coef * actor_entropy(policies, distribution)
-        policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[..., None][:-1]))
-        aux = {
-            "trajectories": sg(traj),
-            "lambda_values": sg(lambda_values),
-            "discount": discount,
-            "moments": new_moments,
-            "Loss/policy_loss": policy_loss,
-            "User/LambdaValues": jnp.mean(sg(lambda_values)),
-            "User/Advantages": jnp.mean(sg(advantage)),
-            "User/Entropy": jnp.mean(sg(entropy)),
-            "User/PredictedRewards": jnp.mean(sg(predicted_rewards)),
-            "User/PredictedValues": jnp.mean(sg(predicted_values)),
-        }
+            if is_continuous:
+                objective = advantage
+            else:
+                per_head = [
+                    p.log_prob(sg(a))[..., None][:-1]
+                    for p, a in zip(policies, jnp.split(imagined_actions, splits, axis=-1))
+                ]
+                objective = sum(per_head) * sg(advantage)
+            entropy = ent_coef * actor_entropy(policies, distribution)
+            policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[..., None][:-1]))
+            aux = {
+                "trajectories": sg(traj),
+                "lambda_values": sg(lambda_values),
+                "discount": discount,
+                "moments": new_moments,
+                "Loss/policy_loss": policy_loss,
+                "User/LambdaValues": jnp.mean(sg(lambda_values)),
+                "User/Advantages": jnp.mean(sg(advantage)),
+                "User/Entropy": jnp.mean(sg(entropy)),
+                "User/PredictedRewards": jnp.mean(sg(predicted_rewards)),
+                "User/PredictedValues": jnp.mean(sg(predicted_values)),
+            }
         return policy_loss, aux
 
     # ------------------------------------------------------------------
@@ -365,14 +370,15 @@ def build_train_fn(
     # ------------------------------------------------------------------
 
     def critic_loss_fn(critic_params, target_params, traj, lambda_values, discount):
-        qv = TwoHotEncodingDistribution(
-            critic.apply({"params": critic_params}, traj[:-1]), dims=1
-        )
-        target_values = TwoHotEncodingDistribution(
-            critic.apply({"params": target_params}, traj[:-1]), dims=1
-        ).mean
-        value_loss = -qv.log_prob(lambda_values) - qv.log_prob(sg(target_values))
-        return jnp.mean(value_loss * discount[:-1, ..., 0])
+        with jax.named_scope("dv3/behavior"):
+            qv = TwoHotEncodingDistribution(
+                critic.apply({"params": critic_params}, traj[:-1]), dims=1
+            )
+            target_values = TwoHotEncodingDistribution(
+                critic.apply({"params": target_params}, traj[:-1]), dims=1
+            ).mean
+            value_loss = -qv.log_prob(lambda_values) - qv.log_prob(sg(target_values))
+            return jnp.mean(value_loss * discount[:-1, ..., 0])
 
     # ------------------------------------------------------------------
     # the fused step
@@ -389,11 +395,12 @@ def build_train_fn(
         opt = agent_state["opt"]
 
         # target critic EMA, dynamic cadence (reference main :731-735)
-        target = jax.tree_util.tree_map(
-            lambda c, t: tau * c + (1.0 - tau) * t,
-            params["critic"],
-            params["target_critic"],
-        )
+        with jax.named_scope("dv3/optimizer"):
+            target = jax.tree_util.tree_map(
+                lambda c, t: tau * c + (1.0 - tau) * t,
+                params["critic"],
+                params["target_critic"],
+            )
 
         k_wm, k_img = jax.random.split(key)
 
@@ -401,9 +408,10 @@ def build_train_fn(
         (wm_loss, (wm_metrics, posteriors, recurrents)), wm_grads = jax.value_and_grad(
             wm_loss_fn, has_aux=True
         )(params["world_model"], data, k_wm)
-        wm_grads = pmean(wm_grads, axis)
-        wm_updates, wm_opt = world_tx.update(wm_grads, opt["world_model"], params["world_model"])
-        wm_params = optax.apply_updates(params["world_model"], wm_updates)
+        with jax.named_scope("dv3/optimizer"):
+            wm_grads = pmean(wm_grads, axis)
+            wm_updates, wm_opt = world_tx.update(wm_grads, opt["world_model"], params["world_model"])
+            wm_params = optax.apply_updates(params["world_model"], wm_updates)
 
         # -- actor update (imagination from the *updated* world model, as the
         # reference's in-place optimizer.step implies)
@@ -418,9 +426,10 @@ def build_train_fn(
             agent_state["moments"],
             k_img,
         )
-        actor_grads = pmean(actor_grads, axis)
-        actor_updates, actor_opt = actor_tx.update(actor_grads, opt["actor"], params["actor"])
-        actor_params = optax.apply_updates(params["actor"], actor_updates)
+        with jax.named_scope("dv3/optimizer"):
+            actor_grads = pmean(actor_grads, axis)
+            actor_updates, actor_opt = actor_tx.update(actor_grads, opt["actor"], params["actor"])
+            actor_params = optax.apply_updates(params["actor"], actor_updates)
 
         # -- critic update
         critic_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(
@@ -430,9 +439,10 @@ def build_train_fn(
             aux["lambda_values"],
             aux["discount"],
         )
-        critic_grads = pmean(critic_grads, axis)
-        critic_updates, critic_opt = critic_tx.update(critic_grads, opt["critic"], params["critic"])
-        critic_params = optax.apply_updates(params["critic"], critic_updates)
+        with jax.named_scope("dv3/optimizer"):
+            critic_grads = pmean(critic_grads, axis)
+            critic_updates, critic_opt = critic_tx.update(critic_grads, opt["critic"], params["critic"])
+            critic_params = optax.apply_updates(params["critic"], critic_updates)
 
         metrics = dict(wm_metrics)
         metrics.update(
@@ -443,10 +453,11 @@ def build_train_fn(
             }
         )
         metrics["Loss/value_loss"] = critic_loss
-        metrics["Grads/world_model"] = optax.global_norm(wm_grads)
-        metrics["Grads/actor"] = optax.global_norm(actor_grads)
-        metrics["Grads/critic"] = optax.global_norm(critic_grads)
-        metrics = pmean(metrics, axis)
+        with jax.named_scope("dv3/optimizer"):
+            metrics["Grads/world_model"] = optax.global_norm(wm_grads)
+            metrics["Grads/actor"] = optax.global_norm(actor_grads)
+            metrics["Grads/critic"] = optax.global_norm(critic_grads)
+            metrics = pmean(metrics, axis)
         if learn_on:
             # grads are already pmean'd above, so every shard computes the
             # same probe scalars — no extra collectives for the learn plane
@@ -787,11 +798,6 @@ def main(fabric, cfg: Dict[str, Any]):
     step_data["is_first"] = np.ones((1, n_envs, 1), np.float32)
     player_state = player_fns["init_states"](play_wm, n_envs)
 
-    # SHEEPRL_LOOP_TRACE=1: per-phase wall-time means printed every 50
-    # updates — the TB timers can't see through async dispatch, so this is
-    # the ground truth for where a slow loop spends its host time.
-    probe = LoopProbe(every=50)
-
     # SHEEPRL_GC_TUNE=1: move everything built so far out of GC's reach and
     # relax collection thresholds — the hot loop allocates heavily (numpy
     # views, jax array wrappers) and full collections otherwise scan a
@@ -854,16 +860,14 @@ def main(fabric, cfg: Dict[str, Any]):
         cur_update = state_box["update"]
         state_box["update"] += 1
         state_box["policy_step"] += n_envs
-        probe.lap("act")
         step_data["actions"] = actions.reshape(1, n_envs, -1).astype(np.float32)
-        rb.add(step_data)
-        probe.lap("rb_add")
+        with span("Time/replay_add_time", phase="store"):
+            rb.add(step_data)
         with span("Time/env_interaction_time", SumMetric(sync_on_compute=False), phase="env"):
             o, rewards, terminated, truncated, infos = envs.step(
                 real_actions.reshape(envs.action_space.shape)
             )
         dones = np.logical_or(terminated, truncated).astype(np.float32)
-        probe.lap("env_step")
 
         step_data["is_first"] = np.zeros_like(step_data["dones"])
         if "restart_on_exception" in infos:
@@ -975,7 +979,6 @@ def main(fabric, cfg: Dict[str, Any]):
         if is_minedojo:
             carry["masks"] = {k: np.asarray(o[k]) for k in mask_keys}
         state_box["carry"] = carry
-        probe.lap("bookkeeping")
         return carry
 
     def _host_env_step(*args):
@@ -1047,7 +1050,6 @@ def main(fabric, cfg: Dict[str, Any]):
             updates_before_training,
             resuming=cfg.checkpoint.resume_from is not None,
         )
-        probe.mark()
         if random_phase:
             real_actions = actions = np.array(envs.action_space.sample())
             if not is_continuous:
@@ -1095,7 +1097,6 @@ def main(fabric, cfg: Dict[str, Any]):
                     sequence_length=cfg.per_rank_sequence_length,
                     n_samples=n_samples,
                 )
-                probe.lap("sample")
                 fetch_metrics = metric_fetch_gate(
                     cfg,
                     aggregator,
@@ -1116,11 +1117,6 @@ def main(fabric, cfg: Dict[str, Any]):
                     tau=cfg.algo.critic.tau,
                     first_hard=True,
                 )
-                # NOTE: when the metric fetch is skipped, nothing in this block
-                # waits on the device — the burst dispatch is async, so the
-                # timer records dispatch time and the device compute overlaps
-                # the next acting phase. Time/sps_train is only device-accurate on
-                # bursts that fetch.
                 with span("Time/train_time", SumMetric(sync_on_compute=cfg.metric.sync_on_compute), phase="train"):
                     root_key, train_key = jax.random.split(root_key)
                     agent_state, metrics, extras = run_train_burst(
@@ -1130,7 +1126,6 @@ def main(fabric, cfg: Dict[str, Any]):
                         (jax.random.split(train_key, n_samples), jnp.asarray(taus)),
                         world_size=world_size,
                         fetch_metrics=fetch_metrics,
-                        probe=probe,
                     )
                     per_rank_gradient_steps += n_samples
                     if use_packed_player:
@@ -1189,8 +1184,6 @@ def main(fabric, cfg: Dict[str, Any]):
             profile_tick(policy_step=policy_step, world_size=world_size)
             last_log = policy_step
             last_train = train_step
-
-        probe.tick(last)
 
         # Checkpoint (reference main :803-830)
         if should_checkpoint(cfg, policy_step, last_checkpoint, last, num_updates):

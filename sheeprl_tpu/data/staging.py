@@ -52,6 +52,7 @@ from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, EpisodeBuffer
 from sheeprl_tpu.data.device_ring import DeviceRingReplay, DeviceRingTransitions
 from sheeprl_tpu.obs.counters import add_prefetch, add_ring_gather, count_h2d
 from sheeprl_tpu.obs.dist.staleness import note_queue_depth
+from sheeprl_tpu.obs.spans import span
 
 __all__ = ["HostStaging", "ReplayStaging", "RingStaging", "make_replay_staging"]
 
@@ -214,8 +215,6 @@ class HostStaging(ReplayStaging):
     def _produce(self, spec: _Spec, clone: bool) -> Dict[str, Any]:
         import jax
 
-        from sheeprl_tpu.obs.spans import span
-
         batch_size, seq_len, n_samples, sample_next_obs = spec
         with self._lock:
             if self._seq:
@@ -261,6 +260,13 @@ class HostStaging(ReplayStaging):
             int(n_samples),
             bool(sample_next_obs),
         )
+        # what the train loop waits for its batch: host sampling and the H2D
+        # put on the synchronous path or a prefetch miss (Time/stage_h2d_time
+        # is then this span's child), the wait for the worker on a hit
+        with span("Time/replay_sample_time", phase="sample"):
+            return self._sample_device(spec)
+
+    def _sample_device(self, spec: _Spec) -> Dict[str, Any]:
         if self._pool is None:
             return self._produce(spec, clone=self._concurrent)
         batch: Optional[Dict[str, Any]] = None

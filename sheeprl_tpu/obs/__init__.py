@@ -73,7 +73,6 @@ from sheeprl_tpu.obs.live import (
     prometheus_text,
 )
 from sheeprl_tpu.obs.perf import (
-    LoopProbe,
     cost_flops,
     log_sps_metrics,
     mfu_pct,
@@ -96,7 +95,6 @@ __all__ = [
     "HistogramSet",
     "LearnSentinel",
     "LiveExporter",
-    "LoopProbe",
     "NonFiniteGuard",
     "PromServer",
     "StalenessTracker",

@@ -132,6 +132,11 @@ class Counters:
         self.train_bursts = 0
         self.train_dispatches = 0
         self.train_burst_steps = 0
+        # publication (utils/host.py::HostParamMirror): refreshes of a host
+        # parameter mirror and the bytes of the packed vectors they moved
+        # device→host (cache hits and disabled mirrors count nothing)
+        self.publish_refreshes = 0
+        self.publish_bytes = 0
         # actor–learner plane (sheeprl_tpu/plane): trajectory slabs received
         # by the learner over the shared-memory queues, the newest published
         # policy version (a gauge — max, not a sum), and player processes
@@ -256,6 +261,8 @@ class Counters:
                 "train_bursts": self.train_bursts,
                 "train_dispatches": self.train_dispatches,
                 "train_burst_steps": self.train_burst_steps,
+                "publish_refreshes": self.publish_refreshes,
+                "publish_bytes": self.publish_bytes,
                 "plane_traj_slabs": self.plane_traj_slabs,
                 "plane_policy_version": self.plane_policy_version,
                 "plane_player_restarts": self.plane_player_restarts,
@@ -451,6 +458,23 @@ def add_train_burst(steps: int = 0, dispatches: int = 1) -> None:
             c.train_bursts += 1
             c.train_dispatches += int(dispatches)
             c.train_burst_steps += int(steps)
+
+
+def train_bursts() -> Optional[int]:
+    """Train bursts counted so far (the cycle a span belongs to), or None
+    with no counters installed."""
+    c = _COUNTERS
+    return c.train_bursts if c is not None else None
+
+
+def add_publish(nbytes: int) -> None:
+    """Record one refresh of a host parameter mirror that moved ``nbytes``
+    (the packed vector) device→host."""
+    c = _COUNTERS
+    if c is not None:
+        with c._lock:
+            c.publish_refreshes += 1
+            c.publish_bytes += int(nbytes)
 
 
 def add_learn_fetch(n: int = 1) -> None:

@@ -12,63 +12,15 @@ formula.
 
 from __future__ import annotations
 
-import os
-import time
 from typing import Any, Dict, Optional
 
 __all__ = [
-    "LoopProbe",
     "cost_flops",
     "log_sps_metrics",
     "mfu_pct",
     "register_train_cost",
     "shape_specs",
 ]
-
-class LoopProbe:
-    """Env-gated per-phase wall-time probe for latency-dominated hot loops.
-
-    ``SHEEPRL_LOOP_TRACE=1`` prints the mean per-iteration wall time of each
-    ``lap``-delimited slice every ``every`` iterations — the TB timers
-    can't see through async dispatch, so this is the ground truth for where
-    a slow loop spends its host time. The algorithms use this instead of hand-rolled
-    ``time.perf_counter()`` deltas (``tools/lint_telemetry.py`` rejects those
-    in ``sheeprl_tpu/algos/`` so loop accounting stays in one place); when
-    the env var is unset every call is a single attribute check.
-    """
-
-    __slots__ = ("enabled", "every", "_acc", "_n", "_t")
-
-    def __init__(self, every: int = 50, env_var: str = "SHEEPRL_LOOP_TRACE"):
-        self.enabled = os.environ.get(env_var) not in (None, "", "0")
-        self.every = int(every)
-        self._acc: Dict[str, float] = {}
-        self._n = 0
-        self._t = 0.0
-
-    def mark(self) -> None:
-        """Start (or restart) the slice clock — call at the top of the loop."""
-        if self.enabled:
-            self._t = time.perf_counter()
-
-    def lap(self, name: str) -> None:
-        """Account the time since the last mark/lap to ``name``."""
-        if self.enabled:
-            t = time.perf_counter()
-            self._acc[name] = self._acc.get(name, 0.0) + (t - self._t)
-            self._t = t
-
-    def tick(self, update: int) -> None:
-        """End of one iteration; prints and resets every ``every`` calls."""
-        if not self.enabled:
-            return
-        self._n += 1
-        if self._n % self.every == 0:
-            parts = " ".join(
-                f"{k}={v / self.every * 1000:.0f}ms" for k, v in sorted(self._acc.items())
-            )
-            print(f"[loop-trace] update={update} mean/iter: {parts}", flush=True)
-            self._acc.clear()
 
 
 def cost_flops(compiled) -> float:

@@ -20,7 +20,13 @@ Trace-event schema (one JSON object per line; the "complete event" subset of
 the Chrome trace-event format):
 
 ``{"name": str, "cat": phase, "ph": "X", "ts": µs, "dur": µs,
-  "pid": jax process index, "tid": host thread id}``
+  "pid": jax process index, "tid": host thread id,
+  "args": {"parent": enclosing open span on this thread or null,
+           "burst": the run counter ``train_bursts`` when the span opened}}``
+
+(``parent`` says which span caused this one, ``burst`` which train cycle it
+belongs to; the per-thread stack of open spans behind ``parent`` exists only
+while a tracer is installed)
 
 plus ``{"ph": "M", ...}`` thread-name metadata and ``{"ph": "C", ...}``
 counter samples from the device poller. Load in Perfetto / chrome://tracing
@@ -33,18 +39,22 @@ import json
 import os
 import threading
 import time
-from contextlib import ContextDecorator
+from contextlib import ContextDecorator, contextmanager
 from typing import Any, Dict, Optional
 
+from sheeprl_tpu.obs import counters as _counters
 from sheeprl_tpu.obs import hist as _hist
 from sheeprl_tpu.utils.timer import timer
 
-__all__ = ["span", "TraceWriter", "get_tracer", "set_tracer"]
+__all__ = ["span", "TraceWriter", "get_tracer", "set_tracer", "scoped_compile_key"]
 
 #: events buffered before a file flush (bounds write syscalls in hot loops)
 _FLUSH_EVERY = 128
 
 _TRACER: Optional["TraceWriter"] = None
+
+#: per-thread stack of open span names, grown only under an installed tracer
+_OPEN = threading.local()
 
 
 def get_tracer() -> Optional["TraceWriter"]:
@@ -55,6 +65,35 @@ def get_tracer() -> Optional["TraceWriter"]:
 def set_tracer(tracer: Optional["TraceWriter"]) -> None:
     global _TRACER
     _TRACER = tracer
+
+
+@contextmanager
+def scoped_compile_key():
+    """Around a dispatch that may compile a program whose ``jax.named_scope``
+    names a device profile is to show.
+
+    jax keys its persistent compile cache on the program with its debug
+    information stripped, so a program that differs from a cached one in its
+    scopes alone gets the cached executable back — without the scopes. While
+    the tracer mirrors spans into the profiler (``xla_annotations``), the
+    key includes the metadata for the duration of this scope: the annotated
+    program gets a cache entry of its own, and un-instrumented runs stay on
+    the entries they have. Two config writes per use with a tracer; with
+    none, nothing (no jax call).
+    """
+    tracer = _TRACER
+    if tracer is None or not tracer.xla_annotations:
+        yield
+        return
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
 
 
 class TraceWriter:
@@ -256,12 +295,21 @@ class span(ContextDecorator):
         self._timer = timer(name, metric)
         self._t0: Optional[float] = None
         self._annotation = None
+        self._args: Optional[Dict[str, Any]] = None
 
     def __enter__(self):
         tracer = _TRACER
         if tracer is not None or _hist.installed() is not None:
             self._t0 = time.perf_counter()
         if tracer is not None:
+            stack = getattr(_OPEN, "stack", None)
+            if stack is None:
+                stack = _OPEN.stack = []
+            self._args = {
+                "parent": stack[-1] if stack else None,
+                "burst": _counters.train_bursts(),
+            }
+            stack.append(self.name)
             self._annotation = tracer.annotation(self.name)
             if self._annotation is not None:
                 self._annotation.__enter__()
@@ -273,6 +321,9 @@ class span(ContextDecorator):
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
             self._annotation = None
+        args, self._args = self._args, None
+        if args is not None:
+            _OPEN.stack.pop()  # spans are scopes: last opened, first closed
         if self._t0 is not None:
             t0, self._t0 = self._t0, None
             t1 = time.perf_counter()
@@ -281,5 +332,5 @@ class span(ContextDecorator):
             _hist.observe(self.name, t1 - t0)
             tracer = _TRACER
             if tracer is not None:
-                tracer.complete(self.name, self.phase, t0, t1)
+                tracer.complete(self.name, self.phase, t0, t1, args)
         return False

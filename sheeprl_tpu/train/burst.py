@@ -34,10 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from sheeprl_tpu.obs import get_telemetry, register_train_cost, shape_specs
+from sheeprl_tpu.obs import get_telemetry, register_train_cost, shape_specs, span
 from sheeprl_tpu.obs import learn as _learn
 from sheeprl_tpu.obs.counters import add_train_burst
 from sheeprl_tpu.obs.learn import split_probes
+from sheeprl_tpu.obs.spans import scoped_compile_key
 
 
 class TrainProgram:
@@ -311,7 +312,6 @@ def run_train_burst(
     world_size: int = 1,
     fetch_metrics: bool = True,
     pacing_metric: str = "Loss/world_model_loss",
-    probe=None,
 ) -> Tuple[Any, Optional[Any], Tuple[Any, ...]]:
     """Dispatch one training burst and account for it.
 
@@ -330,8 +330,14 @@ def run_train_burst(
     per-step reference loop (``n_samples`` dispatches, identical
     ``(batch, key, schedule)`` tuples → bitwise-identical state).
 
-    ``probe`` (an ``obs.LoopProbe`` or anything with ``.lap(name)``) gets
-    ``train_dispatch``/``metric_fetch`` lap marks around the two phases.
+    Two spans split the burst where the host pays for it:
+    ``Time/train_dispatch_time`` until the dispatch returns (every dispatch
+    of the per-step loop under one span), and ``Time/train_sync_time``
+    around the fetch or pacing pull, which ends with the burst's own outputs
+    ready — dispatch + sync is the train time the host sees. The dispatch
+    also runs under :func:`~sheeprl_tpu.obs.spans.scoped_compile_key`, so
+    that a traced run's train program keeps its named scopes whatever the
+    persistent compile cache holds.
 
     When the step's metrics carry ``learn/`` probe keys (obs/learn), the
     stacked probe subtree is split off before the fetch/pacing logic and fed
@@ -346,7 +352,8 @@ def run_train_burst(
         burst_args = (agent_state, data_stack, np.int32(0), np.int32(n)) + scanned
         # specs captured pre-call: the burst donates agent_state
         specs = shape_specs(burst_args) if want_cost else None
-        out = train_fn.burst(*burst_args)
+        with span("Time/train_dispatch_time", phase="train"), scoped_compile_key():
+            out = train_fn.burst(*burst_args)
         agent_state, metrics = out[0], out[1]
         metrics, learn_dev = split_probes(metrics)
         extras = tuple(out[2:])
@@ -366,23 +373,24 @@ def run_train_burst(
         metrics = None
         out = None
         learn_rows = []
-        for i in range(n):
-            step_args = (agent_state, data_stack, np.int32(i), np.int32(1)) + scanned
-            if specs is None and want_cost:
-                specs = shape_specs(step_args)
-            out = train_fn.burst(*step_args)
-            agent_state, metrics = out[0], out[1]
-            metrics, learn_i = split_probes(metrics)
-            if learn_i:
-                # each count=1 call writes exactly slot i of its [n] learn
-                # buffers; that row is bitwise the fused stack's row i (same
-                # executable wrote it)
-                learn_rows.append(
-                    jax.tree_util.tree_map(
-                        lambda x: jax.lax.index_in_dim(x, i, 0, keepdims=False),
-                        learn_i,
+        with span("Time/train_dispatch_time", phase="train"), scoped_compile_key():
+            for i in range(n):
+                step_args = (agent_state, data_stack, np.int32(i), np.int32(1)) + scanned
+                if specs is None and want_cost:
+                    specs = shape_specs(step_args)
+                out = train_fn.burst(*step_args)
+                agent_state, metrics = out[0], out[1]
+                metrics, learn_i = split_probes(metrics)
+                if learn_i:
+                    # each count=1 call writes exactly slot i of its [n] learn
+                    # buffers; that row is bitwise the fused stack's row i
+                    # (same executable wrote it)
+                    learn_rows.append(
+                        jax.tree_util.tree_map(
+                            lambda x: jax.lax.index_in_dim(x, i, 0, keepdims=False),
+                            learn_i,
+                        )
                     )
-                )
         learn_dev = (
             {k: jnp.stack([r[k] for r in learn_rows]) for k in learn_rows[0]}
             if learn_rows
@@ -398,20 +406,17 @@ def run_train_burst(
                 world_size=world_size,
                 dispatches_per_step=n,
             )
-    if probe is not None:
-        probe.lap("train_dispatch")
-    # learn-probe feed: at most ONE extra device_get per burst (cadence- and
-    # install-gated inside observe_probes; uninstrumented runs see no learn
-    # keys at all and pay nothing here)
-    _learn.observe_probes(learn_dev)
-    if metrics is not None and fetch_metrics:
-        metrics = jax.device_get(metrics)
-    elif metrics is not None:
-        leaf = metrics.get(pacing_metric) if isinstance(metrics, dict) else None
-        if leaf is None:
-            leaf = jax.tree_util.tree_leaves(metrics)[0]
-        np.asarray(leaf)
-        metrics = None
-    if probe is not None:
-        probe.lap("metric_fetch")
+    with span("Time/train_sync_time", phase="train"):
+        # learn-probe feed: at most ONE extra device_get per burst (cadence-
+        # and install-gated inside observe_probes; uninstrumented runs see no
+        # learn keys at all and pay nothing here)
+        _learn.observe_probes(learn_dev)
+        if metrics is not None and fetch_metrics:
+            metrics = jax.device_get(metrics)
+        elif metrics is not None:
+            leaf = metrics.get(pacing_metric) if isinstance(metrics, dict) else None
+            if leaf is None:
+                leaf = jax.tree_util.tree_leaves(metrics)[0]
+            np.asarray(leaf)
+            metrics = None
     return agent_state, metrics, extras

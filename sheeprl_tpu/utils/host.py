@@ -25,6 +25,9 @@ from typing import Any
 import jax
 import numpy as np
 
+from sheeprl_tpu.obs.counters import add_publish
+from sheeprl_tpu.obs.spans import span
+
 
 class HostParamMirror:
     @staticmethod
@@ -67,8 +70,12 @@ class HostParamMirror:
             # the transfer without blocking; the unravel runs on the CPU
             # backend and only waits when the player first reads the params,
             # by which time env bookkeeping has overlapped it
-            flat = jax.device_put(self._pack(tree), self._host)
-            self._cache = self._unravel(flat)
+            # the span measures what the loop waits here, whatever part of
+            # the copy is asynchronous: nothing in it blocks on the result
+            with span("Time/publish_time", phase="publish"):
+                flat = jax.device_put(self._pack(tree), self._host)
+                self._cache = self._unravel(flat)
+            add_publish(flat.nbytes)
         self._calls += 1
         return self._cache
 

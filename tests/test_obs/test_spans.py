@@ -134,3 +134,84 @@ def test_disabled_timer_still_emits_trace_events(tmp_path):
     assert timer.compute() == {}
     events = _read_events(writer.path)
     assert any(e.get("name") == "Time/train_time" for e in events)
+
+
+# -- what caused a span, and the cycle it belongs to ---------------------------
+
+
+def test_span_events_carry_parent_and_burst(tmp_path):
+    """``args.parent`` is the enclosing open span on the same thread (null at
+    the top, and on another thread), ``args.burst`` the run counter
+    ``train_bursts`` when the span opened (null with no counters)."""
+    from sheeprl_tpu.obs import counters as obs_counters
+
+    def staged():
+        with span("Time/stage_h2d_time", phase="stage_h2d"):
+            pass
+
+    writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=False)
+    set_tracer(writer)
+    run_counters = obs_counters.Counters()
+    try:
+        with span("Time/rollout_time", phase="rollout"):
+            pass  # no counters installed yet
+        obs_counters.install(run_counters)
+        obs_counters.add_train_burst(steps=4, dispatches=1)
+        with span("Time/train_time", phase="train"):
+            with span("Time/train_dispatch_time", phase="train"):
+                obs_counters.add_train_burst(steps=4, dispatches=1)
+            with span("Time/publish_time", phase="publish"):
+                worker = threading.Thread(target=staged)
+                worker.start()
+                worker.join(timeout=5)
+    finally:
+        obs_counters.install(None)
+        set_tracer(None)
+        writer.close()
+    args = {e["name"]: e["args"] for e in _read_events(writer.path) if e["ph"] == "X"}
+    assert args["Time/rollout_time"] == {"parent": None, "burst": None}
+    assert args["Time/train_time"] == {"parent": None, "burst": 1}
+    assert args["Time/train_dispatch_time"] == {"parent": "Time/train_time", "burst": 1}
+    # opened after the dispatch counted its burst; the stack popped the dispatch
+    assert args["Time/publish_time"] == {"parent": "Time/train_time", "burst": 2}
+    # a pool thread's span has no parent on its own thread
+    assert args["Time/stage_h2d_time"] == {"parent": None, "burst": 2}
+
+
+def test_span_without_tracer_keeps_no_stack_and_makes_no_jax_call(monkeypatch):
+    """Telemetry off: a span (and the compile-key scope) is a timer and
+    nothing else — no stack of open spans, no call into jax."""
+    import jax
+
+    from sheeprl_tpu.obs import spans as spans_mod
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a jax call with no tracer installed")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(jax.config, "update", refuse)
+    spans_mod._OPEN.__dict__.pop("stack", None)
+    with span("Time/train_time", phase="train"), spans_mod.scoped_compile_key():
+        with span("Time/train_dispatch_time", phase="train"):
+            pass
+    assert not hasattr(spans_mod._OPEN, "stack")
+    assert set(timer.compute()) == {"Time/train_time", "Time/train_dispatch_time"}
+
+
+@pytest.mark.parametrize("annotations", [True, False])
+def test_compile_key_includes_metadata_only_under_an_annotating_tracer(tmp_path, annotations):
+    import jax
+
+    from sheeprl_tpu.obs.spans import scoped_compile_key
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=annotations)
+    set_tracer(writer)
+    try:
+        with scoped_compile_key():
+            assert getattr(jax.config, flag) is (True if annotations else before)
+    finally:
+        set_tracer(None)
+        writer.close()
+    assert getattr(jax.config, flag) is before

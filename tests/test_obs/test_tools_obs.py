@@ -214,11 +214,11 @@ def test_lint_allows_span_scopes_and_docstring_mentions(tmp_path):
     good = tmp_path / "good_algo.py"
     good.write_text(
         '"""Uses time.perf_counter() only in prose."""\n'
-        "from sheeprl_tpu.obs import LoopProbe, span\n"
-        "def loop():\n"
-        "    probe = LoopProbe(every=50)\n"
+        "from sheeprl_tpu.obs import span\n"
+        "def loop(rb, step_data):\n"
         "    with span('Time/train_time', phase='train'):\n"
-        "        probe.lap('train')\n"
+        "        with span('Time/replay_add_time', phase='store'):\n"
+        "            rb.add(step_data)\n"
     )
     assert lint.lint_file(str(good)) == []
 

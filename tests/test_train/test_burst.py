@@ -194,3 +194,62 @@ def test_dreamer_v2_resume_mid_run_fused_bitwise_per_step(tmp_path, monkeypatch)
     monkeypatch.setenv("SHEEPRL_TRAIN_NO_FUSE", "1")
     cli.run(_burst_args(tmp_path, "dreamer_v2", "rperstep", resume))
     _assert_bitwise(tmp_path, "rfused", "rperstep")
+
+
+# -- the burst's two spans -----------------------------------------------------
+
+
+@pytest.mark.parametrize("mode", ["fused", "per_step"])
+def test_run_train_burst_splits_into_dispatch_and_sync_spans(tmp_path, monkeypatch, mode):
+    """Under a tracer the burst emits ``Time/train_dispatch_time`` then
+    ``Time/train_sync_time`` (once each, also when the per-step loop
+    dispatches n times) and returns bitwise what it returns without one."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.fabric import Fabric
+    from sheeprl_tpu.obs.spans import TraceWriter, set_tracer
+    from sheeprl_tpu.train import build_train_burst, run_train_burst
+
+    if mode == "fused":
+        monkeypatch.delenv("SHEEPRL_TRAIN_NO_FUSE", raising=False)
+    else:
+        monkeypatch.setenv("SHEEPRL_TRAIN_NO_FUSE", "1")
+
+    def local_step(agent_state, data, key):
+        w = agent_state["params"]["w"]
+        noise = jax.random.normal(key, w.shape)
+        w = w - 0.1 * (w - jnp.mean(data, 0)) + 1e-3 * noise
+        return {"params": {"w": w}}, {"Loss/world_model_loss": jnp.sum(w * w), "User/x": jnp.mean(data)}
+
+    program = build_train_burst(local_step, Fabric(devices=1, accelerator="cpu"), n_scanned=1, data_dim=0)
+    data = jnp.arange(24, dtype=jnp.float32).reshape(3, 4, 2)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+
+    def burst(fetch):
+        state = {"params": {"w": jnp.ones(2)}}
+        state, metrics, extras = run_train_burst(program, state, data, (keys,), fetch_metrics=fetch)
+        return jax.device_get(state), metrics, extras
+
+    plain = [burst(True), burst(False)]
+    writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=True)
+    set_tracer(writer)
+    try:
+        traced = [burst(True), burst(False)]
+    finally:
+        set_tracer(None)
+        writer.close()
+    for (state_a, metrics_a, extras_a), (state_b, metrics_b, extras_b) in zip(plain, traced):
+        np.testing.assert_array_equal(state_a["params"]["w"], state_b["params"]["w"])
+        assert extras_a == extras_b == ()
+        assert (metrics_a is None) == (metrics_b is None)
+        for k in metrics_a or ():
+            np.testing.assert_array_equal(metrics_a[k], metrics_b[k])
+    assert plain[1][1] is None and set(plain[0][1]) == {"Loss/world_model_loss", "User/x"}
+    with open(writer.path) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    names = [e["name"] for e in events if e["ph"] == "X"]
+    assert names == ["Time/train_dispatch_time", "Time/train_sync_time"] * 2
+    assert {e["cat"] for e in events if e["ph"] == "X"} == {"train"}

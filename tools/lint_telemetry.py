@@ -15,8 +15,11 @@ lint fails when a file under ``sheeprl_tpu/algos/`` re-grows its own copy:
 - an ad-hoc wall-clock read (``time.time()`` / ``time.perf_counter()`` /
   ``time.monotonic()``, under any import alias) — the span phases already
   time the hot loops and feed the streaming histograms/flight recorder;
-  private deltas measure the same thing invisibly. For the env-gated
-  loop-latency printout use ``obs.LoopProbe``;
+  private deltas measure the same thing invisibly. A slice of the loop
+  that no span covers yet gets a span of its own (``Time/<what>_time`` with
+  a ``phase``; the shared layers already bring ``train_dispatch``/
+  ``train_sync``, ``publish``, ``replay_sample`` and ``stage_h2d``, the
+  DreamerV3 loop ``replay_add``), never a second timing system;
 - a ``log_sps_metrics`` call without a matching ``profile_tick`` call in
   the same file — the in-run device-profile scheduler (``obs/prof``)
   advances at the log boundary, so an entrypoint that logs rates but never
@@ -198,8 +201,8 @@ def lint_file(path: str) -> list:
                     (node.lineno,
                      f"ad-hoc {clock}() wall-clock read — the span phases "
                      "already time this loop (and feed the histograms/flight "
-                     "recorder); for the env-gated loop-latency printout use "
-                     "sheeprl_tpu.obs.LoopProbe")
+                     "recorder); give a slice none of them covers its own "
+                     "sheeprl_tpu.obs.span")
                 )
             if (
                 isinstance(fn, ast.Attribute)
