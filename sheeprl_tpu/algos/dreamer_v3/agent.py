@@ -1166,6 +1166,21 @@ def build_agent(
 # ---------------------------------------------------------------------------
 
 
+def acting_params(wm_params):
+    """The part of a world-model parameter tree that acting reads.
+
+    The player functions of :func:`build_player_fns` call four
+    :class:`WorldModel` methods — ``encode`` (``encoder``), ``recurrent_step``,
+    ``representation`` and ``initial_posterior`` (all three ``rssm``) — and
+    flax looks a parameter up only when its module runs, so these two
+    subtrees are a complete ``wm_params`` for every one of them. The decoders
+    and the reward and continue heads are training's alone: a caller that
+    copies parameters to where acting runs (``utils/host.py``) hands over
+    this selection, not the whole tree.
+    """
+    return {"encoder": wm_params["encoder"], "rssm": wm_params["rssm"]}
+
+
 def build_player_fns(
     world_model: WorldModel,
     actor: Actor,

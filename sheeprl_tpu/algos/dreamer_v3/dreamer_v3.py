@@ -39,6 +39,7 @@ import optax
 from sheeprl_tpu.algos.dreamer_v3.agent import (
     Actor,
     WorldModel,
+    acting_params,
     build_actor_dists,
     build_agent,
     build_player_fns,
@@ -664,7 +665,8 @@ def main(fabric, cfg: Dict[str, Any]):
         plan=plan,
     )
     # Two acting modes: host-mirrored (player_on_host=True on an accelerator
-    # mesh — CPU snapshots refreshed per burst, utils/host.py) or packed
+    # mesh — CPU snapshots of the leaves acting reads, agent.acting_params
+    # and the actor, refreshed per burst, utils/host.py) or packed
     # device/local acting — params cross into the player jit as ONE flat
     # vector that the train burst itself emits: one argument buffer per
     # acting dispatch instead of one per parameter leaf.
@@ -679,9 +681,11 @@ def main(fabric, cfg: Dict[str, Any]):
         packed_template=packed_template,
     )
 
-    wm_mirror = HostParamMirror.from_cfg(agent_state["params"]["world_model"], fabric, cfg)
+    wm_mirror = HostParamMirror.from_cfg(
+        acting_params(agent_state["params"]["world_model"]), fabric, cfg
+    )
     actor_mirror = HostParamMirror.from_cfg(agent_state["params"]["actor"], fabric, cfg)
-    play_wm = wm_mirror(agent_state["params"]["world_model"])
+    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
     play_actor = actor_mirror(agent_state["params"]["actor"])
     play_packed = None
     if use_packed_player:
@@ -1132,7 +1136,7 @@ def main(fabric, cfg: Dict[str, Any]):
                         play_packed = extras[0]
                         _dump_digest = None
                     else:
-                        play_wm = wm_mirror(agent_state["params"]["world_model"])
+                        play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
                         play_actor = actor_mirror(agent_state["params"]["actor"])
                     # the cached fresh player state (episode resets) belongs
                     # to the previous params version

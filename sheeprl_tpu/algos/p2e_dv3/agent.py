@@ -31,6 +31,7 @@ from sheeprl_tpu.algos.dreamer_v3.agent import (
     Actor,
     MLPWithHead,
     WorldModel,
+    acting_params,  # noqa: F401  (the same player, so the same leaves)
     build_player_fns,  # noqa: F401  (players are identical; actor params select task/exploration)
     hafner_initialization,
     resolve_actor_distribution,

@@ -53,7 +53,12 @@ from sheeprl_tpu.algos.dreamer_v3.utils import (
     test,
     update_moments,
 )
-from sheeprl_tpu.algos.p2e_dv3.agent import apply_ensemble, build_agent, build_player_fns
+from sheeprl_tpu.algos.p2e_dv3.agent import (
+    acting_params,
+    apply_ensemble,
+    build_agent,
+    build_player_fns,
+)
 from sheeprl_tpu.ckpt import preemption_requested, should_checkpoint, warn_checkpoint_rounding
 from sheeprl_tpu.config.instantiate import instantiate
 from sheeprl_tpu.utils.host import HostParamMirror
@@ -653,15 +658,17 @@ def main(fabric, cfg: Dict[str, Any]):
     )
     player_fns = build_player_fns(world_model, actor, cfg, actions_dim, is_continuous)
 
-    # host-mirrored acting snapshots (utils/host.py)
-    wm_mirror = HostParamMirror.from_cfg(agent_state["params"]["world_model"], fabric, cfg)
+    # host-mirrored acting snapshots (utils/host.py) of the leaves acting reads
+    wm_mirror = HostParamMirror.from_cfg(
+        acting_params(agent_state["params"]["world_model"]), fabric, cfg
+    )
     actor_expl_mirror = HostParamMirror.from_cfg(
         agent_state["params"]["actor_exploration"], fabric, cfg
     )
     actor_task_mirror = HostParamMirror.from_cfg(
         agent_state["params"]["actor_task"], fabric, cfg
     )
-    play_wm = wm_mirror(agent_state["params"]["world_model"])
+    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
     play_actor_expl = actor_expl_mirror(agent_state["params"]["actor_exploration"])
     play_actor_task = actor_task_mirror(agent_state["params"]["actor_task"])
 
@@ -975,7 +982,7 @@ def main(fabric, cfg: Dict[str, Any]):
                         fetch_metrics=fetch_metrics,
                     )
                     per_rank_gradient_steps += n_samples
-                    play_wm = wm_mirror(agent_state["params"]["world_model"])
+                    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
                     play_actor_expl = actor_expl_mirror(agent_state["params"]["actor_exploration"])
                     play_actor_task = actor_task_mirror(agent_state["params"]["actor_task"])
                     # cached fresh player state belongs to the previous

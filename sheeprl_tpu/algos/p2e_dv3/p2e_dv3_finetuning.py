@@ -28,7 +28,7 @@ from sheeprl_tpu.algos.dreamer_v3.utils import (
     prepare_obs,
     test,
 )
-from sheeprl_tpu.algos.p2e_dv3.agent import build_agent, build_player_fns
+from sheeprl_tpu.algos.p2e_dv3.agent import acting_params, build_agent, build_player_fns
 from sheeprl_tpu.ckpt import preemption_requested, should_checkpoint, warn_checkpoint_rounding
 from sheeprl_tpu.config.instantiate import instantiate
 from sheeprl_tpu.utils.host import HostParamMirror
@@ -166,11 +166,13 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
         cfg, fabric, actions_dim, is_continuous,
     )
     player_fns = build_player_fns(world_model, actor, cfg, actions_dim, is_continuous)
-    # host-mirrored acting snapshots (utils/host.py); the frozen
-    # exploration actor is mirrored once
-    wm_mirror = HostParamMirror.from_cfg(agent_state["params"]["world_model"], fabric, cfg)
+    # host-mirrored acting snapshots (utils/host.py) of the leaves acting
+    # reads; the frozen exploration actor is mirrored once
+    wm_mirror = HostParamMirror.from_cfg(
+        acting_params(agent_state["params"]["world_model"]), fabric, cfg
+    )
     actor_mirror = HostParamMirror.from_cfg(agent_state["params"]["actor"], fabric, cfg)
-    play_wm = wm_mirror(agent_state["params"]["world_model"])
+    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
     play_actor = actor_mirror(agent_state["params"]["actor"])
     play_actor_expl = HostParamMirror.from_cfg(actor_expl_params, fabric, cfg)(
         actor_expl_params
@@ -496,7 +498,7 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
                         fetch_metrics=fetch_metrics,
                     )
                     per_rank_gradient_steps += n_samples
-                    play_wm = wm_mirror(agent_state["params"]["world_model"])
+                    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
                     play_actor = actor_mirror(agent_state["params"]["actor"])
                     # cached fresh player state belongs to the previous
                     # params version — recompute on next episode reset

@@ -10,6 +10,12 @@ snapshot leaves the device as one array instead of one transfer per leaf,
 then unraveled on the host. Whether acting on the mirror beats acting on the
 device is ROADMAP S1/D4's measurement, not settled here.
 
+The mirror copies the tree its caller hands it, all of it, and knows nothing
+of what is in it: a refresh costs time in proportion to its bytes, so the
+choice of leaves is the caller's, made where the code knows what acting reads.
+DreamerV3 and P2E-DV3 hand it ``agent.acting_params(world_model)`` — encoder
+and RSSM, not the decoders or the reward and continue heads — and the actor.
+
 Usage::
 
     mirror = HostParamMirror(params, enabled=fabric.on_accelerator)

@@ -2,10 +2,24 @@
 ``tests/test_algos/test_algos.py`` dreamer_v3 cases) + numeric units for the
 λ-return scan and the Moments percentile EMA."""
 
+import os
+
 import numpy as np
 import pytest
 
 from sheeprl_tpu import cli
+
+
+TINY_WIDTHS = (
+    "algo.dense_units=8",
+    "algo.mlp_layers=1",
+    "algo.world_model.encoder.cnn_channels_multiplier=2",
+    "algo.world_model.recurrent_model.recurrent_state_size=8",
+    "algo.world_model.transition_model.hidden_size=8",
+    "algo.world_model.representation_model.hidden_size=8",
+    "algo.world_model.stochastic_size=4",
+    "algo.world_model.discrete_size=4",
+)
 
 
 def dv3_args(tmp_path, extra=()):
@@ -26,14 +40,7 @@ def dv3_args(tmp_path, extra=()):
         "per_rank_batch_size=2",
         "per_rank_sequence_length=1",
         "algo.horizon=4",
-        "algo.dense_units=8",
-        "algo.mlp_layers=1",
-        "algo.world_model.encoder.cnn_channels_multiplier=2",
-        "algo.world_model.recurrent_model.recurrent_state_size=8",
-        "algo.world_model.transition_model.hidden_size=8",
-        "algo.world_model.representation_model.hidden_size=8",
-        "algo.world_model.stochastic_size=4",
-        "algo.world_model.discrete_size=4",
+        *TINY_WIDTHS,
         "algo.learning_starts=0",
         "cnn_keys.encoder=[rgb]",
         *extra,
@@ -80,14 +87,7 @@ def test_bf16_param_dtype_stays_f32():
             "env=dummy",
             "metric.log_level=0",
             "fabric.precision=bf16-mixed",
-            "algo.dense_units=8",
-            "algo.mlp_layers=1",
-            "algo.world_model.encoder.cnn_channels_multiplier=2",
-            "algo.world_model.recurrent_model.recurrent_state_size=8",
-            "algo.world_model.transition_model.hidden_size=8",
-            "algo.world_model.representation_model.hidden_size=8",
-            "algo.world_model.stochastic_size=4",
-            "algo.world_model.discrete_size=4",
+            *TINY_WIDTHS,
             "cnn_keys.encoder=[rgb]",
         ],
     )
@@ -259,3 +259,227 @@ def test_hafner_initialization_heads():
     std = np.sqrt(1.0 / 12.0) / 0.87962566103423978
     assert np.abs(k).max() <= 2 * std + 1e-6
     assert k.std() > 0.1 * std
+
+
+# -- the acting subset of the world model (agent.acting_params) -----------------
+
+
+@pytest.fixture(scope="module")
+def tiny_player():
+    """A tiny DreamerV3 agent with a pixel and a vector key, as the benchmark's
+    configuration has: its parameters, and every player function that takes
+    world-model parameters as a call ``wm_params -> outputs`` (the state the
+    stepping ones start from is made from the same ``wm_params``)."""
+    import gymnasium as gym
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent, build_player_fns
+    from sheeprl_tpu.config.engine import compose
+
+    cfg = compose(
+        "config",
+        overrides=[
+            "exp=dreamer_v3",
+            "env=dummy",
+            "metric.log_level=0",
+            *TINY_WIDTHS,
+            "cnn_keys.encoder=[rgb]",
+            "mlp_keys.encoder=[state]",
+        ],
+    )
+    obs_space = gym.spaces.Dict(
+        {
+            "rgb": gym.spaces.Box(0, 255, (3, 64, 64), np.uint8),
+            "state": gym.spaces.Box(-1, 1, (5,), np.float32),
+        }
+    )
+    world_model, actor, _, params = build_agent(cfg, (4,), False, obs_space, jax.random.PRNGKey(0))
+    fns = build_player_fns(world_model, actor, cfg, (4,), False)
+    rng = np.random.default_rng(0)
+    n_envs = 2
+    obs = {
+        "rgb": rng.integers(0, 255, (n_envs, 3, 64, 64)).astype(np.uint8),
+        "state": rng.standard_normal((n_envs, 5)).astype(np.float32),
+    }
+    key, expl, mask = jax.random.PRNGKey(1), jnp.float32(0.3), jnp.asarray([[1.0], [0.0]])
+
+    def init(wm):
+        return fns["init_states"](wm, n_envs)
+
+    def explore(wm):
+        return fns["exploration_action_raw"](wm, params["actor"], init(wm), obs, key, expl)
+
+    calls = {
+        "init_states": init,
+        "reset_states": lambda wm: fns["reset_states"](wm, explore(wm)[1], mask),
+        "exploration_action_raw": explore,
+        "greedy_action_raw": lambda wm: fns["greedy_action_raw"](wm, params["actor"], init(wm), obs, key),
+    }
+    return params, calls
+
+
+@pytest.mark.parametrize(
+    "fn_name", ["init_states", "reset_states", "exploration_action_raw", "greedy_action_raw"]
+)
+def test_acting_params_give_the_whole_trees_bits(tiny_player, fn_name):
+    import jax
+
+    from sheeprl_tpu.algos.dreamer_v3.agent import acting_params
+
+    params, calls = tiny_player
+    whole = jax.tree_util.tree_leaves(calls[fn_name](params["world_model"]))
+    subset = jax.tree_util.tree_leaves(calls[fn_name](acting_params(params["world_model"])))
+    assert len(whole) == len(subset) > 0
+    for a, b in zip(whole, subset):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_acting_params_are_minimal_and_complete(tiny_player):
+    """Removing a top-level key of the subset raises (nothing falls back), and
+    every leaf of it is an input that acting's jaxpr reads: a ``WorldModel``
+    method that acting starts to call outside the subset fails here (or in the
+    test above) and does not act on leaves that are never refreshed."""
+    import jax
+    from flax.errors import ScopeParamNotFoundError
+    from jax._src.interpreters import partial_eval as pe
+
+    from sheeprl_tpu.algos.dreamer_v3.agent import acting_params
+
+    params, calls = tiny_player
+    subset = acting_params(params["world_model"])
+    assert set(subset) < set(params["world_model"])
+    for dropped in subset:
+        rest = {k: v for k, v in subset.items() if k != dropped}
+        with pytest.raises(ScopeParamNotFoundError):
+            calls["reset_states"](rest)
+
+    def leaves_read(wm_params):
+        # init_states and a step, traced with the parameters as the only inputs
+        jaxpr = jax.make_jaxpr(calls["exploration_action_raw"])(wm_params).jaxpr
+        return pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))[1]
+
+    assert all(leaves_read(subset))
+    # and the selection drops something: of the whole tree, acting reads exactly the subset's leaves
+    whole_read = leaves_read(params["world_model"])
+    assert sum(whole_read) == len(jax.tree_util.tree_leaves(subset)) < len(whole_read)
+
+
+def test_acting_params_bytes_at_the_benchmarks_widths():
+    """At the ``dv3-XL`` shapes the refresh moves 432,006,468 bytes (the acting
+    subset and the actor, 60 leaves) of the 822,933,076 (124 leaves) that the
+    whole world model and the actor hold: PERF.md's prediction, held by a number."""
+    import json
+    import os
+
+    import jax
+    from flax.traverse_util import unflatten_dict
+
+    from benchmarks.manifest import ROOT, load_module
+    from sheeprl_tpu.algos.dreamer_v3.agent import acting_params
+
+    with open(os.path.join(ROOT, "configs", "dv3-XL.json")) as f:
+        config = json.load(f)
+    shapes = load_module(os.path.join(ROOT, "configs", config["reference"])).param_shapes(config["sizes"])
+    tree = unflatten_dict(
+        {name: jax.ShapeDtypeStruct(tuple(shape), np.float32) for name, shape in shapes.items()}, sep="/"
+    )
+
+    def count(*trees):
+        leaves = jax.tree_util.tree_leaves(trees)
+        return len(leaves), sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in leaves)
+
+    assert count(tree["world_model"], tree["actor"]) == (124, 822_933_076)
+    assert count(acting_params(tree["world_model"]), tree["actor"]) == (60, 432_006_468)
+
+
+def _seeded_run(tmp_path, run_name):
+    """A few seeded updates of ``exp=dreamer_v3`` through ``cli.run``: the
+    actions the policy took (``SHEEPRL_ACT_DUMP``) and the run's counters."""
+    import json
+    import pickle
+
+    summary = tmp_path / f"{run_name}.telemetry.json"
+    cli.run(
+        dv3_args(
+            tmp_path,
+            [
+                "fabric.devices=1",
+                "env.id=discrete_dummy",
+                "dry_run=False",
+                "total_steps=40",
+                "per_rank_sequence_length=4",
+                "buffer.size=128",
+                "buffer.prefetch=False",  # the synchronous sampling path, for a bitwise comparison
+                "algo.learning_starts=8",
+                "algo.train_every=8",
+                "algo.run_test=False",
+                f"run_name={run_name}",
+                "metric.telemetry.enabled=true",
+                f"metric.telemetry.summary_path={summary}",
+            ],
+        )
+    )
+    rows = []
+    with open(os.environ["SHEEPRL_ACT_DUMP"], "rb") as f:
+        while True:
+            try:
+                rows.append(pickle.load(f))
+            except EOFError:
+                break
+    with open(summary) as f:
+        counters = json.load(f)
+    # the first row is the reset observation, every other one a policy step
+    return np.stack([row["actions"] for row in rows[1:]]), counters
+
+
+def test_dreamer_v3_acts_on_the_mirrored_subset(tmp_path, monkeypatch):
+    """With the host mirror switched on (on the CPU it is off, so no other
+    tier-1 test acts on mirrored parameters) the entrypoint hands it the acting
+    subset and the actor, a refresh moves their bytes and no more, and the
+    policy's actions are those of the same seeded run whose mirror is handed
+    the whole tree."""
+    import jax
+
+    import sheeprl_tpu.algos.dreamer_v3.dreamer_v3 as dv3
+    from sheeprl_tpu.algos.dreamer_v3.agent import acting_params
+    from sheeprl_tpu.utils.host import HostParamMirror
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("SHEEPRL_ACT_DUMP", str(tmp_path / "actions.pkl"))  # a run truncates it first
+    monkeypatch.setattr(HostParamMirror, "enabled_for", staticmethod(lambda fabric, cfg: True))
+    built, handed = {}, []
+    build_agent, mirror_call = dv3.build_agent, HostParamMirror.__call__
+
+    def keeping_build_agent(*args, **kwargs):
+        out = build_agent(*args, **kwargs)
+        built["params"] = out[3]
+        return out
+
+    def recording_call(mirror, tree):
+        handed.append(tuple(sorted(tree)))
+        return mirror_call(mirror, tree)
+
+    monkeypatch.setattr(dv3, "build_agent", keeping_build_agent)
+    monkeypatch.setattr(HostParamMirror, "__call__", recording_call)
+
+    def nbytes(*trees):
+        return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(trees))
+
+    actions, counters = _seeded_run(tmp_path, "subset")
+    params = built["params"]
+    per_burst = nbytes(acting_params(params["world_model"]), params["actor"])
+    assert per_burst < nbytes(params["world_model"])
+    assert set(handed) == {("encoder", "rssm"), tuple(sorted(params["actor"]))}
+    refreshes = counters["publish_refreshes"]
+    # one refresh of each mirror at start-up and after every burst
+    assert refreshes == len(handed) >= 6 and refreshes % 2 == 0
+    assert counters["publish_bytes"] * 2 == per_burst * refreshes
+    assert len(actions) >= 16
+
+    handed.clear()
+    monkeypatch.setattr(dv3, "acting_params", lambda wm_params: wm_params)
+    whole_actions, whole_counters = _seeded_run(tmp_path, "whole")
+    assert tuple(sorted(params["world_model"])) in handed
+    assert whole_counters["publish_bytes"] * 2 == nbytes(params["world_model"], params["actor"]) * refreshes
+    np.testing.assert_array_equal(actions, whole_actions)

@@ -64,7 +64,11 @@ def test_jax_cartpole_matches_gymnasium():
 def test_jax_pendulum_matches_gymnasium():
     env = JaxPendulum()
     genv = gym.make("Pendulum-v1")
-    state, _ = env.reset(jax.random.PRNGKey(1))
+    # the start state is pinned to jax's own key implementation: a Fabric built
+    # earlier in the process (any cli.run) leaves ``jax_default_prng_impl`` at
+    # rbg, whose start state drifts past the tolerance below within 50 steps
+    with jax.default_prng_impl("threefry2x32"):
+        state, _ = env.reset(jax.random.PRNGKey(1))
     genv.reset(seed=0)
     genv.unwrapped.state = np.array([float(state["th"]), float(state["thdot"])])
     for t in range(50):

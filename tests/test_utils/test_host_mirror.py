@@ -5,6 +5,7 @@ pinned here on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sheeprl_tpu.utils.host import HostParamMirror
 
@@ -17,8 +18,15 @@ def _tree():
     }
 
 
-def test_enabled_roundtrip_is_exact():
-    tree = _tree()
+@pytest.mark.parametrize(
+    "select",
+    [lambda tree: tree, lambda tree: {"dense": tree["dense"], "embed": tree["embed"]}],
+    ids=["whole", "subtree"],
+)
+def test_enabled_roundtrip_is_exact(select):
+    """The mirror copies the tree its caller hands it, whole or a selection the
+    caller made of a larger one (DreamerV3 hands it the leaves acting reads)."""
+    tree = select(_tree())
     mirror = HostParamMirror(tree, enabled=True)
     out = mirror(tree)
     # identical structure and bit-exact leaves
