@@ -1054,11 +1054,34 @@ def build_agent(
     is_continuous: bool,
     observation_space,
     key: jax.Array,
+    sequence_models: Optional[Sequence[str]] = None,
 ) -> Tuple[WorldModel, Actor, MLPWithHead, Dict[str, Any]]:
     """Construct module defs + initialized params (reference build_models,
     agent.py:900-1144). Returns ``(world_model, actor, critic, params)`` with
-    ``params = {world_model, actor, critic, target_critic}``."""
+    ``params = {world_model, actor, critic, target_critic}``.
+
+    ``sequence_models`` names the world-model cores the calling entrypoint
+    can train (``algo.world_model.sequence_model``); left out, it follows
+    from ``algo.name``: ``dreamer_v3`` trains every core of
+    ``seq_agent.SEQUENCE_MODELS``, every other entrypoint that builds its
+    agent here (P2E-DV3) the GRU alone. Another core is refused here, with
+    the reason, rather than inside a trace."""
     wm_cfg = cfg.algo.world_model
+    sequence_model = str(wm_cfg.get("sequence_model", "gru") or "gru")
+    if sequence_models is None:
+        from sheeprl_tpu.algos.dreamer_v3.seq_agent import SEQUENCE_MODELS
+
+        sequence_models = SEQUENCE_MODELS if cfg.algo.name == "dreamer_v3" else ("gru",)
+    if sequence_model not in sequence_models:
+        raise ValueError(
+            f"algo.world_model.sequence_model={sequence_model!r} is not supported by this entrypoint "
+            f"({cfg.algo.name}); it trains {list(sequence_models)}. The sequence cores are "
+            "dreamer_v3's (howto/sequence_core.md)."
+        )
+    if sequence_model != "gru":
+        from sheeprl_tpu.algos.dreamer_v3.seq_agent import build_seq_agent
+
+        return build_seq_agent(cfg, actions_dim, is_continuous, observation_space, key)
     cnn_keys = list(cfg.cnn_keys.encoder)
     mlp_keys = list(cfg.mlp_keys.encoder)
     screen = int(cfg.env.screen_size)
@@ -1176,8 +1199,11 @@ def acting_params(wm_params):
     subtrees are a complete ``wm_params`` for every one of them. The decoders
     and the reward and continue heads are training's alone: a caller that
     copies parameters to where acting runs (``utils/host.py``) hands over
-    this selection, not the whole tree.
+    this selection, not the whole tree. A sequence core (``seq_agent.py``)
+    acts through its encoder, its posterior head and the whole core.
     """
+    if "core" in wm_params:
+        return {k: wm_params[k] for k in ("encoder", "posterior", "core")}
     return {"encoder": wm_params["encoder"], "rssm": wm_params["rssm"]}
 
 

@@ -46,6 +46,7 @@ from sheeprl_tpu.algos.dreamer_v3.agent import (
     actor_entropy,
     sample_actor_actions,
 )
+from sheeprl_tpu.algos.dreamer_v3 import seq_agent
 from sheeprl_tpu.algos.dreamer_v3.loss import continue_distribution, reconstruction_loss
 from sheeprl_tpu.algos.dreamer_v3.utils import (
     compute_lambda_values,
@@ -60,7 +61,7 @@ from sheeprl_tpu.utils.host import HostParamMirror
 from sheeprl_tpu.replay import make_replay_buffer
 from sheeprl_tpu.data.staging import make_replay_staging
 from sheeprl_tpu.distributions import MSEDistribution, SymlogDistribution, TwoHotEncodingDistribution
-from sheeprl_tpu.envs.rollout import BurstActor
+from sheeprl_tpu.envs.rollout import BurstActor, DeviceActor
 from sheeprl_tpu.envs.vector import make_vector_env
 from sheeprl_tpu.plane import train_gated_burst_plan
 from sheeprl_tpu.utils.logger import create_tensorboard_logger
@@ -74,6 +75,7 @@ from sheeprl_tpu.obs import (
     set_shard_footprint,
     span,
 )
+from sheeprl_tpu.obs.counters import add_seq_core, installed as counters_installed, set_seq_core_state_bytes
 from sheeprl_tpu.obs.dist import pmean
 from sheeprl_tpu.utils.optim import clip_norm_of
 from sheeprl_tpu.parallel.shard import measured_bytes_per_device
@@ -617,6 +619,14 @@ def main(fabric, cfg: Dict[str, Any]):
     world_model, actor, critic, params = build_agent(
         cfg, actions_dim, is_continuous, observation_space, build_key
     )
+    # a sequence core (algo.world_model.sequence_model, howto/sequence_core.md)
+    # trains through the same loop; its acting state lives on the device
+    seq_core = isinstance(world_model, seq_agent.SeqWorldModel)
+    if seq_core and HostParamMirror.enabled_for(fabric, cfg):
+        raise ValueError(
+            "the sequence core acts on the device (its acting set is gigabytes, and the host mirror "
+            "would copy it after every burst): set algo.player_on_host=False"
+        )
     world_tx, actor_tx, critic_tx, agent_state = build_optimizers_and_state(cfg, params)
 
     expl_decay_steps = 0
@@ -651,7 +661,7 @@ def main(fabric, cfg: Dict[str, Any]):
         fabric.model_axis_size,
     )
 
-    train_fn = build_train_fn(
+    train_fn = (seq_agent.build_seq_train_fn if seq_core else build_train_fn)(
         world_model,
         actor,
         critic,
@@ -670,13 +680,13 @@ def main(fabric, cfg: Dict[str, Any]):
     # device/local acting — params cross into the player jit as ONE flat
     # vector that the train burst itself emits: one argument buffer per
     # acting dispatch instead of one per parameter leaf.
-    use_packed_player = not HostParamMirror.enabled_for(fabric, cfg)
+    use_packed_player = not seq_core and not HostParamMirror.enabled_for(fabric, cfg)
     packed_template = (
         {"wm": params["world_model"], "actor": params["actor"]}
         if use_packed_player
         else None
     )
-    player_fns = build_player_fns(
+    player_fns = None if seq_core else build_player_fns(
         world_model, actor, cfg, actions_dim, is_continuous,
         packed_template=packed_template,
     )
@@ -800,7 +810,12 @@ def main(fabric, cfg: Dict[str, Any]):
     step_data["dones"] = np.zeros((1, n_envs, 1), np.float32)
     step_data["rewards"] = np.zeros((1, n_envs, 1), np.float32)
     step_data["is_first"] = np.ones((1, n_envs, 1), np.float32)
-    player_state = player_fns["init_states"](play_wm, n_envs)
+    if seq_core:
+        # the per-env state stays on the device (DeviceActor below); what rides
+        # the host loop is the flag that resets it at an episode's end
+        player_state = {"reset": np.zeros((n_envs, 1), np.float32)}
+    else:
+        player_state = player_fns["init_states"](play_wm, n_envs)
 
     # SHEEPRL_GC_TUNE=1: move everything built so far out of GC's reach and
     # relax collection thresholds — the hot loop allocates heavily (numpy
@@ -850,6 +865,8 @@ def main(fabric, cfg: Dict[str, Any]):
     def _fresh_player():
         # host copy of init_states under the CURRENT acting params; the
         # train block clears it whenever the params version advances
+        if state_box["fresh"] is None and seq_core:
+            state_box["fresh"] = {"reset": np.ones((n_envs, 1), np.float32)}
         if state_box["fresh"] is None:
             fresh = (
                 player_fns["init_states_packed"](play_packed, n_envs)
@@ -1035,7 +1052,30 @@ def main(fabric, cfg: Dict[str, Any]):
         )
         return cb_args, key
 
-    burst_actor = BurstActor(_act_fn, _host_env_step, state_box["carry"])
+    if seq_core:
+        init_player, player_step = seq_agent.build_player(world_model, actor, cfg, n_envs)
+
+        def _device_step(p, state, carry, key):
+            key, act_key = jax.random.split(key)
+            action, computed, state = player_step(
+                {"wm": p["wm"], "actor": p["actor"]}, state, carry["obs"], carry["player"]["reset"],
+                act_key, p["expl"], greedy=act_greedy,
+            )
+            return action, computed, state, key
+
+        def _host_device_step(actions):
+            actions = np.asarray(actions)
+            return _host_step_core(
+                actions, np.argmax(actions, -1)[:, None], {"reset": np.zeros((n_envs, 1), np.float32)}
+            )
+
+        with jax.default_device(fabric.device):
+            burst_actor = DeviceActor(_device_step, _host_device_step, jax.jit(init_player)())
+        set_seq_core_state_bytes(
+            sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(burst_actor.state)) // n_envs
+        )
+    else:
+        burst_actor = BurstActor(_act_fn, _host_env_step, state_box["carry"])
 
     # in-run eval (howto/evaluation.md): rank 0 publishes the frozen params
     # through the policy channel every eval.every_n_steps; a separate process
@@ -1129,10 +1169,20 @@ def main(fabric, cfg: Dict[str, Any]):
                         local_data,
                         (jax.random.split(train_key, n_samples), jnp.asarray(taus)),
                         world_size=world_size,
-                        fetch_metrics=fetch_metrics,
+                        # the sequence core's counters ride the step's metrics
+                        fetch_metrics=fetch_metrics or (seq_core and counters_installed() is not None),
                     )
                     per_rank_gradient_steps += n_samples
-                    if use_packed_player:
+                    if seq_core:
+                        # acting reads the trained leaves where they are
+                        play_wm = acting_params(agent_state["params"]["world_model"])
+                        play_actor = agent_state["params"]["actor"]
+                        if metrics is not None:
+                            add_seq_core(
+                                steps=n_samples,
+                                **{k: float(metrics[f"Core/{k}"]) * n_samples for k in seq_agent.CORE_COUNTERS},
+                            )
+                    elif use_packed_player:
                         play_packed = extras[0]
                         _dump_digest = None
                     else:
@@ -1218,5 +1268,8 @@ def main(fabric, cfg: Dict[str, Any]):
         inrun.close()
     staging.close()
     envs.close()
-    if fabric.is_global_zero and cfg.algo.get("run_test", True) and not preemption_requested():
+    if seq_core:
+        if cfg.algo.get("run_test", True):
+            fabric.print("the sequence core has no test episode yet (algo.run_test is skipped)")
+    elif fabric.is_global_zero and cfg.algo.get("run_test", True) and not preemption_requested():
         test(player_fns, jax.device_get(agent_state["params"]), fabric, cfg, log_dir, sample_actions=True)

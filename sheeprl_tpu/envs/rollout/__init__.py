@@ -24,7 +24,7 @@ Telemetry: each burst bumps ``rollout_bursts``/``act_dispatches`` (and
 (phase ``rollout``). See ``howto/rollout_engine.md``.
 """
 
-from sheeprl_tpu.envs.rollout.burst import BurstActor
+from sheeprl_tpu.envs.rollout.burst import BurstActor, DeviceActor
 from sheeprl_tpu.envs.rollout.engine import JaxRolloutEngine
 from sheeprl_tpu.envs.rollout.jax_envs import (
     JaxCartPole,
@@ -35,6 +35,7 @@ from sheeprl_tpu.envs.rollout.jax_envs import (
 
 __all__ = [
     "BurstActor",
+    "DeviceActor",
     "JaxCartPole",
     "JaxPendulum",
     "JaxRolloutEngine",

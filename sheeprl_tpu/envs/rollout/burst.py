@@ -34,9 +34,10 @@ from typing import Any, Callable, Tuple
 
 import numpy as np
 
+from sheeprl_tpu.obs import span
 from sheeprl_tpu.obs.counters import add_rollout_burst
 
-__all__ = ["BurstActor"]
+__all__ = ["BurstActor", "DeviceActor"]
 
 
 class BurstActor:
@@ -141,4 +142,39 @@ class BurstActor:
         # here proves every host_step of the burst has run.
         jax.block_until_ready(obs)
         add_rollout_burst(act_dispatches=1)
+        return obs, key
+
+
+class DeviceActor:
+    """Acting whose per-env state never leaves the device.
+
+    For a policy whose state is too large to ride a host callback (the
+    sequence core's delta-rule state and key-value cache, megabytes an env):
+    ``step(params, state, obs, key) -> (to_host, on_device, state, key)`` is
+    one jitted program a policy step, its ``state`` donated; ``to_host`` (the
+    actions) is fetched and handed to ``host_step(to_host) -> next_obs``, the
+    same Python loop body a :class:`BurstActor` calls; ``on_device`` (what the
+    step computed besides: tokens, logits) is kept as ``self.last`` and never
+    waited for. The dispatch and the fetch run under the span
+    ``Time/act_decode_time``, a child of the caller's ``Time/rollout_time``.
+    """
+
+    def __init__(self, step: Callable, host_step: Callable[[Any], Any], state: Any):
+        import jax
+
+        self._step = jax.jit(step, donate_argnums=(1,))
+        self._host_step = host_step
+        self.state = state
+        self.last: Any = None
+
+    def rollout(self, params: Any, obs: Any, key: Any, burst_len: int) -> Tuple[Any, Any]:
+        """``burst_len`` policy steps; returns ``(next_obs, key)``."""
+        import jax
+
+        for _ in range(int(burst_len)):
+            with span("Time/act_decode_time", phase="rollout"):
+                to_host, self.last, self.state, key = self._step(params, self.state, obs, key)
+                to_host = jax.device_get(to_host)
+            obs = self._host_step(to_host)
+            add_rollout_burst(act_dispatches=1)
         return obs, key

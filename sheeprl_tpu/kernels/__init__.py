@@ -3,11 +3,12 @@
 Tiered recurrent-core kernels behind the ``algo.fused_kernels`` knob:
 ``kernels/reference.py`` is the bitwise flax math (tier ``off``),
 ``kernels/xla.py`` the padded+fused pure-XLA tier, ``kernels/pallas_tpu.py``
-the Pallas TPU kernels, and ``kernels/registry.py`` the build-time tier
+the Pallas TPU kernels, and ``kernels/delta_rule.py`` the gated delta rule (recurrent reference and
+chunked tier), and ``kernels/registry.py`` the build-time tier
 resolution + trace-time dispatch + reference-cost accounting hooks.
 """
 
-from sheeprl_tpu.kernels import reference, registry, xla
+from sheeprl_tpu.kernels import delta_rule, reference, registry, xla
 from sheeprl_tpu.kernels.registry import (
     KERNELS,
     TIERS,
@@ -24,6 +25,7 @@ from sheeprl_tpu.kernels.registry import (
 )
 
 __all__ = [
+    "delta_rule",
     "reference",
     "registry",
     "xla",

@@ -48,6 +48,7 @@ __all__ = [
     "add_replay_priority_updates",
     "add_ring_gather",
     "add_rollout_burst",
+    "add_seq_core",
     "add_serve_batch",
     "add_serve_failed",
     "add_serve_requests",
@@ -56,6 +57,7 @@ __all__ = [
     "add_slo_alert",
     "add_train_burst",
     "set_replay_shard_fill",
+    "set_seq_core_state_bytes",
     "note_plane_policy_version",
     "device_memory_stats",
     "DevicePoller",
@@ -132,6 +134,15 @@ class Counters:
         self.train_bursts = 0
         self.train_dispatches = 0
         self.train_burst_steps = 0
+        # sequence core (algos/dreamer_v3/seq_agent.py), summed over the
+        # gradient steps whose metrics were fetched (`seq_core_steps` of them):
+        # token-expert pairs routed to held experts and held experts hit, of
+        # the window pass and of imagination's one-token steps, the per-step
+        # largest load of a held expert (summed: divide by the steps), pairs
+        # dropped (has to stay 0), episode ends inside sampled windows,
+        # imagination starts, one-token decode steps; and a gauge, the acting
+        # state's bytes an env
+        self.seq_core: Dict[str, float] = {}
         # publication (utils/host.py::HostParamMirror): refreshes of a host
         # parameter mirror and the bytes of the packed vectors they moved
         # device→host (cache hits and disabled mirrors count nothing)
@@ -261,6 +272,7 @@ class Counters:
                 "train_bursts": self.train_bursts,
                 "train_dispatches": self.train_dispatches,
                 "train_burst_steps": self.train_burst_steps,
+                "seq_core": dict(self.seq_core),
                 "publish_refreshes": self.publish_refreshes,
                 "publish_bytes": self.publish_bytes,
                 "plane_traj_slabs": self.plane_traj_slabs,
@@ -458,6 +470,24 @@ def add_train_burst(steps: int = 0, dispatches: int = 1) -> None:
             c.train_bursts += 1
             c.train_dispatches += int(dispatches)
             c.train_burst_steps += int(steps)
+
+
+def add_seq_core(steps: int = 0, **amounts: float) -> None:
+    """Add one burst's sequence-core counts (``steps`` gradient steps)."""
+    c = _COUNTERS
+    if c is not None:
+        with c._lock:
+            c.seq_core["steps"] = c.seq_core.get("steps", 0) + int(steps)
+            for name, amount in amounts.items():
+                c.seq_core[name] = c.seq_core.get(name, 0.0) + float(amount)
+
+
+def set_seq_core_state_bytes(nbytes: int) -> None:
+    """Gauge: bytes of acting state one env keeps on the device."""
+    c = _COUNTERS
+    if c is not None:
+        with c._lock:
+            c.seq_core["state_bytes_per_env"] = int(nbytes)
 
 
 def train_bursts() -> Optional[int]:

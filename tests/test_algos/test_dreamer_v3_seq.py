@@ -1,0 +1,117 @@
+"""DreamerV3 over a sequence core (``algo.world_model.sequence_model``,
+howto/sequence_core.md): the recipe through ``cli.run`` at tiny widths, the
+entrypoints that refuse the core, and what acting reads of either core."""
+
+import json
+
+import gymnasium as gym
+import jax
+import numpy as np
+import pytest
+
+from sheeprl_tpu import cli
+from sheeprl_tpu.config.engine import compose
+
+TINY_CORE = [
+    "algo.dense_units=16", "algo.horizon=3",
+    "algo.world_model.encoder.cnn_channels_multiplier=2", "algo.world_model.representation_model.hidden_size=16",
+    "algo.world_model.discrete_size=254",
+] + [f"algo.world_model.core.{k}={v}" for k, v in dict(
+    hidden_size=64, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16,
+    num_attention_heads=4, head_dim=32, num_experts=16, num_experts_per_tok=3, moe_intermediate_size=32,
+    shared_expert_intermediate_size=32, vocab_size=256, chunk=16, cache_len=32,
+).items()] + ["algo.world_model.core.held.index=0", "algo.world_model.core.held.of=4"]
+OBS_SPACE = gym.spaces.Dict({"rgb": gym.spaces.Box(0, 255, (3, 64, 64), np.uint8)})
+
+
+def recipe_args(tmp_path, extra=()):
+    return [
+        "exp=dreamer_v3_qwen3next_ep16", "fabric.accelerator=cpu", "fabric.precision=32-true", "metric.log_level=0",
+        "env.num_envs=2", "per_rank_batch_size=2", "per_rank_sequence_length=32", "algo.learning_starts=128",
+        "algo.train_every=8", "algo.per_rank_gradient_steps=2", "total_steps=176", "buffer.size=4096",
+        "buffer.memmap=False", "checkpoint.every=1000000", "checkpoint.save_last=False", "algo.run_test=False",
+        "env.capture_video=False", f"root_dir={tmp_path}/logs", "run_name=test", *TINY_CORE, *extra,
+    ]
+
+
+def test_the_recipe_trains_through_cli_run_and_counts_what_its_core_did(tmp_path, monkeypatch):
+    """A few bursts of the recipe at tiny widths: windows of 32 steps over
+    episodes of five, so every row holds resets; acting on the device."""
+    monkeypatch.chdir(tmp_path)
+    summary = tmp_path / "telemetry.json"
+    cli.run(recipe_args(tmp_path, [
+        "metric.telemetry.enabled=true", f"metric.telemetry.summary_path={summary}",
+        f"metric.telemetry.trace_file={tmp_path / 'spans.jsonl'}", "metric.telemetry.learn.enabled=false",
+        "metric.telemetry.flight.enabled=false", "metric.telemetry.live_interval_s=0", "metric.telemetry.poll_interval_s=0",
+    ]))
+    with open(summary) as f:
+        told = json.load(f)
+    counts = told.get("seq_core") or told["counters"]["seq_core"]
+    # seven bursts: the pretrain step and six of two steps
+    assert counts["steps"] == 13
+    assert counts["dropped_pairs"] == 0 and counts["held_pairs"] > 0
+    assert counts["imagination_starts"] == 13 * 2 * 4 and counts["decode_steps"] == 13 * 7
+    # 4 held experts a layer, 4 layers: a pass hits at most 16; a step's 7 one-token steps at most 7 * 16
+    assert 0 < counts["experts_hit"] <= 13 * 16 and 0 < counts["imagination_experts_hit"] <= 13 * 7 * 16
+    assert 0 < counts["imagination_pairs"] <= 13 * 7 * 4 * (2 * 4) * 3  # 8 streams, 3 experts a token
+    assert counts["episode_ends"] > 13 * 2  # more than one a row
+    assert counts["state_bytes_per_env"] == 3 * (4 * 256 + 3 * 128) * 4 + 2 * 32 * 64 * 4 + 8
+    spans = [json.loads(line) for line in open(tmp_path / "spans.jsonl")]
+    decode = [s for s in spans if s.get("name") == "Time/act_decode_time"]
+    assert decode and all(s.get("args", {}).get("parent") == "Time/rollout_time" for s in decode)
+
+
+@pytest.mark.parametrize("exp,why", [
+    ("p2e_dv3_exploration", "not supported by this entrypoint"),
+    ("dreamer_v3", "no_such_core"),
+])
+def test_a_core_the_entrypoint_cannot_train_is_refused_when_the_agent_is_built(exp, why):
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
+
+    core = "qwen3_next" if exp != "dreamer_v3" else "no_such_core"
+    cfg = compose("config", overrides=[f"exp={exp}", "env=dummy", f"algo.world_model.sequence_model={core}"])
+    with pytest.raises(ValueError, match=why) as refused:
+        build_agent(cfg, (4,), False, OBS_SPACE, jax.random.PRNGKey(0))
+    assert "sequence_model" in str(refused.value)
+
+
+@pytest.mark.parametrize("setting,message", [
+    (["algo.player_on_host=True"], None),  # on the CPU no mirror is made: the recipe runs either way
+    (["env.id=continuous_dummy"], "one discrete action"),
+    (["algo.world_model.stochastic_size=2"], "one categorical"),
+    (["algo.world_model.core.vocab_size=200"], "no room"),
+])
+def test_what_the_sequence_core_cannot_take_is_said_at_the_start(setting, message):
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
+
+    cfg = compose("config", overrides=["exp=dreamer_v3_qwen3next_ep16", "fabric.precision=32-true", *TINY_CORE, *setting])
+    continuous = "env.id=continuous_dummy" in setting
+    if message is None:
+        world_model, _, _, params = build_agent(cfg, (2,), continuous, OBS_SPACE, jax.random.PRNGKey(0))
+        assert world_model.core.experts_held == 4 and "core" in params["world_model"]
+        return
+    with pytest.raises(ValueError, match=message):
+        build_agent(cfg, (2,), continuous, OBS_SPACE, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("core", ["gru", "qwen3_next"])
+def test_acting_params_select_what_each_cores_acting_reads(core):
+    from sheeprl_tpu.algos.dreamer_v3.agent import acting_params, build_agent
+
+    if core == "gru":
+        cfg = compose("config", overrides=[
+            "exp=dreamer_v3", "env=dummy", "algo.dense_units=8", "algo.mlp_layers=1",
+            "algo.world_model.encoder.cnn_channels_multiplier=2", "algo.world_model.recurrent_model.recurrent_state_size=8",
+            "algo.world_model.transition_model.hidden_size=8", "algo.world_model.representation_model.hidden_size=8",
+            "algo.world_model.stochastic_size=4", "algo.world_model.discrete_size=4", "cnn_keys.encoder=[rgb]",
+        ])
+        want = {"encoder", "rssm"}
+    else:
+        cfg = compose("config", overrides=["exp=dreamer_v3_qwen3next_ep16", "fabric.precision=32-true", *TINY_CORE])
+        want = {"encoder", "posterior", "core"}
+    _, _, _, params = build_agent(cfg, (2,), False, OBS_SPACE, jax.random.PRNGKey(0))
+    subset = acting_params(params["world_model"])
+    assert set(subset) == want
+    assert all(subset[k] is params["world_model"][k] for k in want)
+    # the decoder and the reward and continue heads are training's alone, for either core
+    assert {"cnn_decoder", "reward_model", "continue_model"} <= set(params["world_model"]) - set(subset)
