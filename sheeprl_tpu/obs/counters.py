@@ -144,10 +144,14 @@ class Counters:
         # state's bytes an env
         self.seq_core: Dict[str, float] = {}
         # publication (utils/host.py::HostParamMirror): refreshes of a host
-        # parameter mirror and the bytes of the packed vectors they moved
-        # device→host (cache hits and disabled mirrors count nothing)
+        # parameter mirror, the bytes of the leaves they moved device→host
+        # (cache hits and disabled mirrors count nothing), and the leaves
+        # that were not landed in reused host memory and aliased by the CPU
+        # backend: the backend copied them, or the landing set was still held
+        # (stays 0 while the route works as meant)
         self.publish_refreshes = 0
         self.publish_bytes = 0
+        self.publish_copied_leaves = 0
         # actor–learner plane (sheeprl_tpu/plane): trajectory slabs received
         # by the learner over the shared-memory queues, the newest published
         # policy version (a gauge — max, not a sum), and player processes
@@ -275,6 +279,7 @@ class Counters:
                 "seq_core": dict(self.seq_core),
                 "publish_refreshes": self.publish_refreshes,
                 "publish_bytes": self.publish_bytes,
+                "publish_copied_leaves": self.publish_copied_leaves,
                 "plane_traj_slabs": self.plane_traj_slabs,
                 "plane_policy_version": self.plane_policy_version,
                 "plane_player_restarts": self.plane_player_restarts,
@@ -497,14 +502,16 @@ def train_bursts() -> Optional[int]:
     return c.train_bursts if c is not None else None
 
 
-def add_publish(nbytes: int) -> None:
+def add_publish(nbytes: int, copied_leaves: int = 0) -> None:
     """Record one refresh of a host parameter mirror that moved ``nbytes``
-    (the packed vector) device→host."""
+    (its leaves) device→host, ``copied_leaves`` of them into memory that was
+    not reused and aliased."""
     c = _COUNTERS
     if c is not None:
         with c._lock:
             c.publish_refreshes += 1
             c.publish_bytes += int(nbytes)
+            c.publish_copied_leaves += int(copied_leaves)
 
 
 def add_learn_fetch(n: int = 1) -> None:

@@ -475,6 +475,9 @@ def test_dreamer_v3_acts_on_the_mirrored_subset(tmp_path, monkeypatch):
     # one refresh of each mirror at start-up and after every burst
     assert refreshes == len(handed) >= 6 and refreshes % 2 == 0
     assert counters["publish_bytes"] * 2 == per_burst * refreshes
+    # every refresh landed in the mirror's reused memory and was aliased: the
+    # loop lets go of a snapshot before its landing set's turn comes again
+    assert counters["publish_copied_leaves"] == 0
     assert len(actions) >= 16
 
     handed.clear()
