@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import math
 import re
-from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -1213,7 +1212,6 @@ def build_player_fns(
     cfg,
     actions_dim: Sequence[int],
     is_continuous: bool,
-    packed_template: Any = None,
 ):
     """Pure jitted player functions over an explicit state pytree
     ``{"actions", "recurrent", "stochastic"}`` (each ``[n_envs, ...]``).
@@ -1335,7 +1333,7 @@ def build_player_fns(
             masks=masks,
         )
 
-    fns = {
+    return {
         "init_states": init_states,
         "reset_states": jax.jit(reset_states),
         "greedy_action": greedy_action,
@@ -1343,49 +1341,3 @@ def build_player_fns(
         "greedy_action_raw": greedy_action_raw,
         "exploration_action_raw": exploration_action_raw,
     }
-
-    # packed variants: all acting params arrive as ONE flat vector and are
-    # unraveled inside the jit: per-call overhead scales with the number of
-    # argument buffers, and the train burst emits this packed vector
-    # directly (dreamer_v3.py).
-    if packed_template is not None:
-        from jax.flatten_util import ravel_pytree
-
-        _, unravel_packed = ravel_pytree(packed_template)
-
-        @jax.jit
-        def exploration_action_packed(packed, state, raw_obs, key, expl_amount, masks=None):
-            tree = unravel_packed(packed)
-            return exploration_action(
-                tree["wm"], tree["actor"], state, _normalize(raw_obs), key,
-                expl_amount, masks=masks,
-            )
-
-        @jax.jit
-        def greedy_action_packed(packed, state, raw_obs, key, masks=None):
-            tree = unravel_packed(packed)
-            return _step(
-                tree["wm"], tree["actor"], state, _normalize(raw_obs), key,
-                is_training=False, masks=masks,
-            )
-
-        @jax.jit
-        def reset_states_packed(packed, state, reset_mask):
-            tree = unravel_packed(packed)
-            return reset_states(tree["wm"], state, reset_mask)
-
-        @partial(jax.jit, static_argnums=(1,))
-        def init_states_packed(packed, n_envs: int):
-            # the burst-acting host callback applies episode resets as
-            # mask * fresh + (1 - mask) * state with a host copy of this
-            # fresh state, refreshed once per params version
-            tree = unravel_packed(packed)
-            return init_states(tree["wm"], n_envs)
-
-        fns.update(
-            exploration_action_packed=exploration_action_packed,
-            greedy_action_packed=greedy_action_packed,
-            reset_states_packed=reset_states_packed,
-            init_states_packed=init_states_packed,
-        )
-    return fns

@@ -498,26 +498,9 @@ def build_train_fn(
         }
         return new_state, metrics
 
-    def packed_play_params(state):
-        from jax.flatten_util import ravel_pytree
-
-        # the fresh acting params leave the burst as ONE flat vector so the
-        # player's next dispatch marshals a single buffer (packed player fns);
-        # under a sharding plan they leave replicated, so the all-gather
-        # happens once per burst instead of at every acting dispatch
-        return ravel_pytree(
-            {"wm": state["params"]["world_model"], "actor": state["params"]["actor"]}
-        )[0]
-
     # step + fused-burst programs (scanned per-step inputs: key, tau): one
     # dispatch per training burst through the shared engine (train/burst.py)
-    return build_train_burst(
-        local_step,
-        fabric,
-        n_scanned=2,
-        plan=plan,
-        extra_outputs=packed_play_params,
-    )
+    return build_train_burst(local_step, fabric, n_scanned=2, plan=plan)
 
 
 def build_optimizers_and_state(cfg, params):
@@ -674,43 +657,21 @@ def main(fabric, cfg: Dict[str, Any]):
         is_continuous,
         plan=plan,
     )
-    # Two acting modes: host-mirrored (player_on_host=True on an accelerator
-    # mesh — CPU snapshots of the leaves acting reads, agent.acting_params
-    # and the actor, refreshed per burst, utils/host.py) or packed
-    # device/local acting — params cross into the player jit as ONE flat
-    # vector that the train burst itself emits: one argument buffer per
-    # acting dispatch instead of one per parameter leaf.
-    use_packed_player = not seq_core and not HostParamMirror.enabled_for(fabric, cfg)
-    packed_template = (
-        {"wm": params["world_model"], "actor": params["actor"]}
-        if use_packed_player
-        else None
-    )
     player_fns = None if seq_core else build_player_fns(
-        world_model, actor, cfg, actions_dim, is_continuous,
-        packed_template=packed_template,
+        world_model, actor, cfg, actions_dim, is_continuous
     )
 
+    # Acting is handed parameter trees: the leaves it reads
+    # (agent.acting_params and the actor) as CPU snapshots refreshed per burst
+    # where the host mirror is on (player_on_host=True on an accelerator mesh,
+    # utils/host.py), the trained leaves themselves where it is off (a
+    # disabled mirror is the identity).
     wm_mirror = HostParamMirror.from_cfg(
         acting_params(agent_state["params"]["world_model"]), fabric, cfg
     )
     actor_mirror = HostParamMirror.from_cfg(agent_state["params"]["actor"], fabric, cfg)
     play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
     play_actor = actor_mirror(agent_state["params"]["actor"])
-    play_packed = None
-    if use_packed_player:
-        from jax.flatten_util import ravel_pytree
-
-        # under a sharding plan the packed vector is forced replicated (one
-        # all-gather) so the single-device player consumes it whole
-        pack_fn = (
-            jax.jit(lambda t: ravel_pytree(t)[0])
-            if plan is None
-            else jax.jit(lambda t: ravel_pytree(t)[0], out_shardings=fabric.replicated)
-        )
-        play_packed = pack_fn(
-            {"wm": agent_state["params"]["world_model"], "actor": agent_state["params"]["actor"]}
-        )
 
     aggregator = None
     if not MetricAggregator.disabled:
@@ -817,24 +778,8 @@ def main(fabric, cfg: Dict[str, Any]):
     else:
         player_state = player_fns["init_states"](play_wm, n_envs)
 
-    # SHEEPRL_GC_TUNE=1: move everything built so far out of GC's reach and
-    # relax collection thresholds — the hot loop allocates heavily (numpy
-    # views, jax array wrappers) and full collections otherwise scan a
-    # steadily growing object graph.
-    if os.environ.get("SHEEPRL_GC_TUNE") not in (None, "", "0"):
-        import gc
-
-        gc.collect()
-        gc.freeze()
-        gc.set_threshold(100000, 50, 50)
-
     per_rank_gradient_steps = 0
     dumped_rows = 0
-    _dump_digest = None
-    # SHEEPRL_ACT_GREEDY=1 (diagnostic): act with the policy MODE instead of
-    # sampling — with a seeded env this makes the whole collection loop
-    # deterministic and comparable bit-for-bit against external eval tooling
-    act_greedy = bool(os.environ.get("SHEEPRL_ACT_GREEDY"))
     dump_path = os.environ.get("SHEEPRL_ACT_DUMP")
 
     # Burst acting (tier b, howto/rollout_engine.md): K env steps per device
@@ -868,16 +813,12 @@ def main(fabric, cfg: Dict[str, Any]):
         if state_box["fresh"] is None and seq_core:
             state_box["fresh"] = {"reset": np.ones((n_envs, 1), np.float32)}
         if state_box["fresh"] is None:
-            fresh = (
-                player_fns["init_states_packed"](play_packed, n_envs)
-                if use_packed_player
-                else player_fns["init_states"](play_wm, n_envs)
-            )
+            fresh = player_fns["init_states"](play_wm, n_envs)
             state_box["fresh"] = {k: np.asarray(v) for k, v in fresh.items()}
         return state_box["fresh"]
 
     def _host_step_core(actions, real_actions, player_np, key_data=None):
-        nonlocal dumped_rows, _dump_digest
+        nonlocal dumped_rows
         cur_update = state_box["update"]
         state_box["update"] += 1
         state_box["policy_step"] += n_envs
@@ -948,11 +889,6 @@ def main(fabric, cfg: Dict[str, Any]):
             import pickle
 
             dumped_rows += 1
-            if _dump_digest is None and play_packed is not None:
-                # device->host pull of the full packed param vector: once per
-                # params version, NOT per step (play_packed changes only on
-                # train bursts, which reset the cache)
-                _dump_digest = float(np.abs(np.asarray(play_packed)).sum())
             with open(dump_path, "ab") as _f:
                 pickle.dump(
                     {
@@ -962,7 +898,6 @@ def main(fabric, cfg: Dict[str, Any]):
                         "rewards": rewards.copy(),
                         "dones": dones.copy(),
                         "rec_norm": float(np.linalg.norm(player_np["recurrent"])),
-                        "packed_digest": _dump_digest,
                         **{k: np.asarray(new_obs[k]) for k in obs_keys},
                     },
                     _f,
@@ -1024,26 +959,11 @@ def main(fabric, cfg: Dict[str, Any]):
         key, act_key = jax.random.split(key)
         masks = carry["masks"] if is_minedojo else None
         player = carry["player"]
-        # raw-obs variants: uint8 pixels cross the host→device link and are
-        # normalized inside the jit; packed variants take all acting params
-        # as the ONE flat vector the train burst emits
-        if act_greedy:
-            if use_packed_player:
-                actions_j, new_player = player_fns["greedy_action_packed"](
-                    p["packed"], player, carry["obs"], act_key, masks=masks
-                )
-            else:
-                actions_j, new_player = player_fns["greedy_action_raw"](
-                    p["wm"], p["actor"], player, carry["obs"], act_key, masks=masks
-                )
-        elif use_packed_player:
-            actions_j, new_player = player_fns["exploration_action_packed"](
-                p["packed"], player, carry["obs"], act_key, p["expl"], masks=masks
-            )
-        else:
-            actions_j, new_player = player_fns["exploration_action_raw"](
-                p["wm"], p["actor"], player, carry["obs"], act_key, p["expl"], masks=masks
-            )
+        # raw-obs variant: uint8 pixels cross the host→device link and are
+        # normalized inside the jit
+        actions_j, new_player = player_fns["exploration_action_raw"](
+            p["wm"], p["actor"], player, carry["obs"], act_key, p["expl"], masks=masks
+        )
         cb_args = tuple(actions_j) + (
             new_player["actions"],
             new_player["recurrent"],
@@ -1059,7 +979,7 @@ def main(fabric, cfg: Dict[str, Any]):
             key, act_key = jax.random.split(key)
             action, computed, state = player_step(
                 {"wm": p["wm"], "actor": p["actor"]}, state, carry["obs"], carry["player"]["reset"],
-                act_key, p["expl"], greedy=act_greedy,
+                act_key, p["expl"],
             )
             return action, computed, state, key
 
@@ -1108,11 +1028,12 @@ def main(fabric, cfg: Dict[str, Any]):
                 )
             _host_step_core(actions, real_actions, state_box["carry"]["player"])
         else:
-            burst_params = (
-                {"packed": play_packed, "expl": jnp.float32(expl_amount)}
-                if use_packed_player
-                else {"wm": play_wm, "actor": play_actor, "expl": jnp.float32(expl_amount)}
-            )
+            burst_params = {"wm": play_wm, "actor": play_actor, "expl": jnp.float32(expl_amount)}
+            if not wm_mirror.enabled:
+                # acting runs on the device that holds the trained leaves, and its
+                # program waits for the host callback: the callback has to find the
+                # fresh player state made, not ask it of the device it holds
+                _fresh_player()
             with span("Time/rollout_time", SumMetric(sync_on_compute=False), phase="rollout"):
                 _, root_key = burst_actor.rollout(
                     burst_params, state_box["carry"], root_key, n_act
@@ -1163,7 +1084,9 @@ def main(fabric, cfg: Dict[str, Any]):
                 )
                 with span("Time/train_time", SumMetric(sync_on_compute=cfg.metric.sync_on_compute), phase="train"):
                     root_key, train_key = jax.random.split(root_key)
-                    agent_state, metrics, extras = run_train_burst(
+                    # two values; the `*_` is for the benchmark's sequence adapter, which
+                    # returns a triple in this call's place (benchmarks/dv3_seq_adapter.py:169)
+                    agent_state, metrics, *_ = run_train_burst(
                         train_fn,
                         agent_state,
                         local_data,
@@ -1173,21 +1096,14 @@ def main(fabric, cfg: Dict[str, Any]):
                         fetch_metrics=fetch_metrics or (seq_core and counters_installed() is not None),
                     )
                     per_rank_gradient_steps += n_samples
-                    if seq_core:
-                        # acting reads the trained leaves where they are
-                        play_wm = acting_params(agent_state["params"]["world_model"])
-                        play_actor = agent_state["params"]["actor"]
-                        if metrics is not None:
-                            add_seq_core(
-                                steps=n_samples,
-                                **{k: float(metrics[f"Core/{k}"]) * n_samples for k in seq_agent.CORE_COUNTERS},
-                            )
-                    elif use_packed_player:
-                        play_packed = extras[0]
-                        _dump_digest = None
-                    else:
-                        play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
-                        play_actor = actor_mirror(agent_state["params"]["actor"])
+                    # the burst donated the state acting read: hand it the new leaves
+                    play_wm = wm_mirror(acting_params(agent_state["params"]["world_model"]))
+                    play_actor = actor_mirror(agent_state["params"]["actor"])
+                    if seq_core and metrics is not None:
+                        add_seq_core(
+                            steps=n_samples,
+                            **{k: float(metrics[f"Core/{k}"]) * n_samples for k in seq_agent.CORE_COUNTERS},
+                        )
                     # the cached fresh player state (episode resets) belongs
                     # to the previous params version
                     state_box["fresh"] = None

@@ -487,20 +487,19 @@ def build_player(world_model: SeqWorldModel, actor, cfg, n_envs: int):
     def init_state():
         return qn.init_state(c, n_envs, 1, None, dtype)
 
-    def step(params, state, raw_obs, reset, key, expl_amount, greedy: bool = False):
+    def step(params, state, raw_obs, reset, key, expl_amount):
         p = params["wm"]
         obs = normalize_obs_jnp(raw_obs, wm.cnn_keys)
         k_z, k_a, k_e = jax.random.split(key, 3)
         post = wm.posterior_logits(p, obs)
-        z = (jnp.argmax(post, -1) if greedy else jax.random.categorical(k_z, post, -1)).astype(jnp.int32)
+        z = jax.random.categorical(k_z, post, -1).astype(jnp.int32)
         state = qn.reset_state(state, reset.reshape(n_envs, 1) > 0)
         h, state, _ = qn.decode(p["core"], state, z[:, None], c, dtype, scope=CORE_SCOPE)
         feat = jnp.concatenate([qn.embed(p["core"], z), h[:, 0]], -1)
         pre = actor.apply({"params": params["actor"]}, feat)
         dists = build_actor_dists(pre, False, distribution, init_std, min_std, unimix)
-        actions = sample_actor_actions(dists, False, k_a, not greedy)
-        if not greedy:
-            actions = add_exploration_noise(actions, expl_amount, False, k_e)
+        actions = sample_actor_actions(dists, False, k_a, True)
+        actions = add_exploration_noise(actions, expl_amount, False, k_e)
         a = (wm.codes + jnp.argmax(actions[0], -1)).astype(jnp.int32)
         h_a, state, _ = qn.decode(p["core"], state, a[:, None], c, dtype, scope=CORE_SCOPE)
         prior = wm.prior_logits(p, h_a[:, 0])

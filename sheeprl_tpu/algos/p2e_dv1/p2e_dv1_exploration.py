@@ -753,7 +753,7 @@ def main(fabric, cfg: Dict[str, Any]):
                     # the whole burst (n_samples gradient steps) is ONE
                     # scanned dispatch (sheeprl_tpu/train)
                     root_key, train_key = jax.random.split(root_key)
-                    agent_state, metrics, _ = run_train_burst(
+                    agent_state, metrics = run_train_burst(
                         train_fn,
                         agent_state,
                         local_data,

@@ -46,14 +46,12 @@ class TrainProgram:
 
     Callable like the plain step (existing tests/benches and the per-step
     reference loop), with ``.burst`` for the scan-over-samples program the
-    train loops dispatch and ``.extras`` (optional) for the burst's extra
-    outputs recomputed standalone on the per-step path.
+    train loops dispatch.
     """
 
-    def __init__(self, step_fn, burst_fn, extras_fn=None):
+    def __init__(self, step_fn, burst_fn):
         self._step = step_fn
         self.burst = burst_fn
-        self.extras = extras_fn
 
     def __call__(self, *args, **kwargs):
         return self._step(*args, **kwargs)
@@ -70,7 +68,6 @@ def build_train_burst(
     data_dim: int = 1,
     plan=None,
     metric_mode: str = "last",
-    extra_outputs: Optional[Callable] = None,
 ) -> TrainProgram:
     """Wrap a single-gradient-step function into a :class:`TrainProgram`.
 
@@ -94,12 +91,7 @@ def build_train_burst(
       ``count`` are runtime scalars, so one compiled program serves every
       burst length — and the per-step reference mode (see
       :func:`run_train_burst`) bitwise-matches the fused mode by
-      construction.
-
-    ``extra_outputs(state) -> pytree`` appends extra burst outputs computed
-    from the final state inside the same program (DV3's packed acting
-    vector); the same function is compiled standalone as ``.extras`` so the
-    per-step reference path can reproduce it.
+      construction. It returns ``(state, metrics)`` and nothing else.
     """
     if metric_mode not in ("last", "mean", "stack"):
         raise ValueError(f"metric_mode must be last|mean|stack, got {metric_mode!r}")
@@ -184,12 +176,8 @@ def build_train_burst(
             )
         if learn:
             metrics = {**metrics, **learn}
-        outs = (state, metrics)
-        if extra_outputs is not None:
-            outs = outs + (extra_outputs(state),)
-        return outs
+        return state, metrics
 
-    n_extra = 1 if extra_outputs is not None else 0
     if plan is None:
         step_fn = jax.jit(
             jax.shard_map(
@@ -206,12 +194,11 @@ def build_train_burst(
                 local_burst,
                 mesh=fabric.mesh,
                 in_specs=(P(), P(None, *step_data_dims), P(), P()) + (P(),) * n_scanned,
-                out_specs=(P(), P()) + (P(),) * n_extra,
+                out_specs=(P(), P()),
                 check_vma=False,
             ),
             donate_argnums=(0,),
         )
-        extras_fn = jax.jit(extra_outputs) if extra_outputs is not None else None
     else:
         state_sh = plan.shardings()
         rep = fabric.replicated
@@ -221,22 +208,14 @@ def build_train_burst(
             out_shardings=(state_sh, rep),
             donate_argnums=(0,),
         )
-        # the extra outputs (e.g. the packed acting vector) leave replicated:
-        # the player consumes them whole, so any all-gather happens once here
-        # instead of at every acting dispatch
         burst_fn = jax.jit(
             local_burst,
             in_shardings=(state_sh, fabric.sharding(None, *step_data_dims), rep, rep)
             + (rep,) * n_scanned,
-            out_shardings=(state_sh, rep) + (rep,) * n_extra,
+            out_shardings=(state_sh, rep),
             donate_argnums=(0,),
         )
-        extras_fn = (
-            jax.jit(extra_outputs, in_shardings=(state_sh,), out_shardings=rep)
-            if extra_outputs is not None
-            else None
-        )
-    return TrainProgram(step_fn, burst_fn, extras_fn)
+    return TrainProgram(step_fn, burst_fn)
 
 
 def tau_schedule(
@@ -312,11 +291,11 @@ def run_train_burst(
     world_size: int = 1,
     fetch_metrics: bool = True,
     pacing_metric: str = "Loss/world_model_loss",
-) -> Tuple[Any, Optional[Any], Tuple[Any, ...]]:
+) -> Tuple[Any, Optional[Any]]:
     """Dispatch one training burst and account for it.
 
     ``scanned`` are the per-step arrays (keys first, then schedules), each
-    ``[n_samples, ...]``. Returns ``(agent_state, metrics_or_None, extras)``:
+    ``[n_samples, ...]``. Returns ``(agent_state, metrics_or_None)``:
     metrics are device_get-fetched only when ``fetch_metrics`` (the
     :func:`metric_fetch_gate` decision); otherwise one scalar is pulled as a
     pacing barrier that bounds dispatch run-ahead to one burst — the wait
@@ -353,10 +332,8 @@ def run_train_burst(
         # specs captured pre-call: the burst donates agent_state
         specs = shape_specs(burst_args) if want_cost else None
         with span("Time/train_dispatch_time", phase="train"), scoped_compile_key():
-            out = train_fn.burst(*burst_args)
-        agent_state, metrics = out[0], out[1]
+            agent_state, metrics = train_fn.burst(*burst_args)
         metrics, learn_dev = split_probes(metrics)
-        extras = tuple(out[2:])
         add_train_burst(steps=n, dispatches=1)
         if specs is not None:
             # one AOT cost analysis of the burst program (FLOPs + bytes
@@ -371,15 +348,13 @@ def run_train_burst(
         # time (already committed on device: no re-upload), only start moves.
         specs = None
         metrics = None
-        out = None
         learn_rows = []
         with span("Time/train_dispatch_time", phase="train"), scoped_compile_key():
             for i in range(n):
                 step_args = (agent_state, data_stack, np.int32(i), np.int32(1)) + scanned
                 if specs is None and want_cost:
                     specs = shape_specs(step_args)
-                out = train_fn.burst(*step_args)
-                agent_state, metrics = out[0], out[1]
+                agent_state, metrics = train_fn.burst(*step_args)
                 metrics, learn_i = split_probes(metrics)
                 if learn_i:
                     # each count=1 call writes exactly slot i of its [n] learn
@@ -396,7 +371,6 @@ def run_train_burst(
             if learn_rows
             else None
         )
-        extras = tuple(out[2:]) if out is not None else ()
         add_train_burst(steps=n, dispatches=n)
         if specs is not None:
             register_train_cost(
@@ -419,4 +393,4 @@ def run_train_burst(
                 leaf = jax.tree_util.tree_leaves(metrics)[0]
             np.asarray(leaf)
             metrics = None
-    return agent_state, metrics, extras
+    return agent_state, metrics

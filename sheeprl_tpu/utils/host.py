@@ -4,9 +4,9 @@ Environment interaction is one jitted policy call per env step. With
 ``algo.player_on_host`` (default on) and a mesh on an accelerator, that call
 runs on the host CPU instead of the mesh (SURVEY §5.8 — players live on CPU
 hosts feeding the trainer mesh): :class:`HostParamMirror` keeps a CPU copy
-of the acting parameters, refreshed once per update. Whether acting on the
-mirror beats acting on the device is ROADMAP S1/D4's measurement, not settled
-here.
+of the acting parameters, and every call of it refreshes that copy (the
+entrypoints call it once per update). Whether acting on the mirror beats
+acting on the device is ROADMAP S2/D4's measurement, not settled here.
 
 **The route of a refresh.** The leaves are fetched from the device with all
 their copies in flight together (``jax.device_get``: a leaf sharded over the
@@ -114,22 +114,11 @@ class HostParamMirror:
 
     @classmethod
     def from_cfg(cls, example_tree: Any, fabric, cfg) -> "HostParamMirror":
-        """The one construction rule: enable per :meth:`enabled_for`,
-        refresh cadence from ``algo.player_on_host_refresh_every``."""
-        return cls(
-            example_tree,
-            enabled=cls.enabled_for(fabric, cfg),
-            refresh_every=cfg.algo.get("player_on_host_refresh_every", 1),
-        )
+        """The one construction rule: enable per :meth:`enabled_for`."""
+        return cls(example_tree, enabled=cls.enabled_for(fabric, cfg))
 
-    def __init__(self, example_tree: Any, enabled: bool = True, refresh_every: int = 1):
+    def __init__(self, example_tree: Any, enabled: bool = True):
         self.enabled = bool(enabled)
-        # refreshing costs one full-model transfer; a cadence > 1 lets the
-        # player act on a snapshot stale by up to refresh_every-1 updates
-        # (algo.player_on_host_refresh_every)
-        self.refresh_every = max(int(refresh_every or 1), 1)
-        self._calls = 0
-        self._cache: Any = None
         if self.enabled:
             self._host = jax.devices("cpu")[0]
             leaves, self._treedef = jax.tree_util.tree_flatten(example_tree)
@@ -139,13 +128,11 @@ class HostParamMirror:
     def __call__(self, tree: Any) -> Any:
         if not self.enabled:
             return tree
-        if self._cache is None or self._calls % self.refresh_every == 0:
-            # the route is synchronous: the span holds all of a refresh
-            with span("Time/publish_time", phase="publish"):
-                self._cache, copied = self._refresh(tree)
-            add_publish(self._sets[0].nbytes, copied_leaves=copied)
-        self._calls += 1
-        return self._cache
+        # the route is synchronous: the span holds all of a refresh
+        with span("Time/publish_time", phase="publish"):
+            snapshot, copied = self._refresh(tree)
+        add_publish(self._sets[0].nbytes, copied_leaves=copied)
+        return snapshot
 
     def _refresh(self, tree: Any) -> Tuple[Any, int]:
         leaves = jax.device_get(self._treedef.flatten_up_to(tree))

@@ -393,7 +393,7 @@ def test_acting_params_bytes_at_the_benchmarks_widths():
     assert count(acting_params(tree["world_model"]), tree["actor"]) == (60, 432_006_468)
 
 
-def _seeded_run(tmp_path, run_name):
+def _seeded_run(tmp_path, run_name, extra=()):
     """A few seeded updates of ``exp=dreamer_v3`` through ``cli.run``: the
     actions the policy took (``SHEEPRL_ACT_DUMP``) and the run's counters."""
     import json
@@ -417,6 +417,7 @@ def _seeded_run(tmp_path, run_name):
                 f"run_name={run_name}",
                 "metric.telemetry.enabled=true",
                 f"metric.telemetry.summary_path={summary}",
+                *extra,
             ],
         )
     )
@@ -486,3 +487,131 @@ def test_dreamer_v3_acts_on_the_mirrored_subset(tmp_path, monkeypatch):
     assert tuple(sorted(params["world_model"])) in handed
     assert whole_counters["publish_bytes"] * 2 == nbytes(params["world_model"], params["actor"]) * refreshes
     np.testing.assert_array_equal(actions, whole_actions)
+
+
+@pytest.mark.parametrize(
+    "mesh",
+    [
+        ["fabric.devices=1"],
+        # the leaves split over two model shards: acting gathers them either way
+        # (a world of two: the step counts doubled, so that the run has the same updates)
+        ["fabric.devices=2", "parallel.model_axis=2", "parallel.shard_min_bytes=0",
+         "total_steps=80", "algo.learning_starts=16", "algo.train_every=16"],
+    ],
+    ids=["one_device", "model_axis_2"],
+)
+def test_dreamer_v3_acts_alike_on_the_mirror_and_on_the_trained_leaves(tmp_path, monkeypatch, mesh):
+    """The two ways acting is handed its parameters, through ``cli.run`` on the
+    same seed: the trained leaves themselves (mirror off, what the CPU runs by
+    default) and the host mirror's snapshot of them (forced on) give the same
+    actions bit for bit."""
+    from sheeprl_tpu.utils.host import HostParamMirror
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("SHEEPRL_ACT_DUMP", str(tmp_path / "actions.pkl"))  # a run truncates it first
+    leaf_actions, leaf_counters = _seeded_run(tmp_path, "leaves", mesh)
+    assert leaf_counters["publish_refreshes"] == 0 and len(leaf_actions) >= 16
+    monkeypatch.setattr(HostParamMirror, "enabled_for", staticmethod(lambda fabric, cfg: True))
+    mirror_actions, mirror_counters = _seeded_run(tmp_path, "mirror", mesh)
+    assert mirror_counters["publish_refreshes"] >= 6
+    np.testing.assert_array_equal(leaf_actions, mirror_actions)
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["replicated", "model_axis_2"])
+def test_the_train_burst_returns_the_state_and_the_metrics_and_nothing_of_the_parameters_size(sharded):
+    """Both builds of the DreamerV3 train program (``shard_map`` on the data
+    mesh, GSPMD under a sharding plan): ``.burst`` returns ``(state, metrics)``,
+    the state in the shapes it came in, and no metric is as large as the
+    smallest parameter matrix: acting reads the state's own leaves, so the
+    program has no second parameter-sized output."""
+    import gymnasium as gym
+    import jax
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
+    from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import build_optimizers_and_state, build_train_fn
+    from sheeprl_tpu.config.engine import compose
+    from sheeprl_tpu.fabric import Fabric
+
+    cfg = compose(
+        "config",
+        overrides=[
+            "exp=dreamer_v3", "env=dummy", "env.id=discrete_dummy", "per_rank_batch_size=2",
+            "per_rank_sequence_length=4", "algo.horizon=3", *TINY_WIDTHS, "cnn_keys.encoder=[rgb]",
+            "metric.log_level=0",
+        ],
+    )
+    fabric = (
+        Fabric(devices=2, accelerator="cpu", model_axis=2, shard_min_bytes=0)
+        if sharded
+        else Fabric(devices=1, accelerator="cpu")
+    )
+    obs_space = gym.spaces.Dict({"rgb": gym.spaces.Box(0, 255, (3, 64, 64), np.uint8)})
+    world_model, actor, critic, params = build_agent(cfg, (4,), False, obs_space, jax.random.PRNGKey(0))
+    world_tx, actor_tx, critic_tx, agent_state = build_optimizers_and_state(cfg, params)
+    plan = fabric.shard_plan(agent_state)
+    assert (plan is not None) == sharded
+    train_fn = build_train_fn(
+        world_model, actor, critic, world_tx, actor_tx, critic_tx, cfg, fabric, (4,), False, plan=plan
+    )
+    n, T, B = 2, 4, 2
+    stack = {
+        "rgb": jnp.zeros((n, T, B, 3, 64, 64), jnp.uint8),
+        "actions": jnp.zeros((n, T, B, 4), jnp.float32),
+        "rewards": jnp.zeros((n, T, B, 1), jnp.float32),
+        "dones": jnp.zeros((n, T, B, 1), jnp.float32),
+        "is_first": jnp.zeros((n, T, B, 1), jnp.float32),
+    }
+    keys = jax.random.split(jax.random.PRNGKey(1), n)
+    out = jax.eval_shape(train_fn.burst, agent_state, stack, np.int32(0), np.int32(n), keys, jnp.zeros((n,), jnp.float32))
+    assert isinstance(out, tuple) and len(out) == 2
+    state, metrics = out
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(lambda x: (tuple(x.shape), x.dtype), tree)
+
+    assert shapes(state) == shapes(agent_state)
+    smallest_matrix = min(x.size for x in jax.tree_util.tree_leaves(params) if x.ndim >= 2)
+    assert "Loss/world_model_loss" in metrics
+    assert all(x.size < smallest_matrix for x in jax.tree_util.tree_leaves(metrics))
+
+
+def test_the_acting_callback_asks_nothing_of_the_device_that_runs_it(tmp_path, monkeypatch):
+    """With the mirror off, acting's program runs on the device that holds the
+    trained leaves and waits there for its host callback: a callback that asked
+    that device for the fresh player state would wait for the program that
+    waits for it (on the chip a hang at the first episode's end after a burst;
+    the CPU backend lets it through). So that state is made before a rollout,
+    never inside one."""
+    import sheeprl_tpu.algos.dreamer_v3.dreamer_v3 as dv3
+    from sheeprl_tpu.envs.rollout import BurstActor
+
+    seen = {"in_rollout": False, "made": 0, "made_in_rollout": 0}
+    rollout, build_player_fns = BurstActor.rollout, dv3.build_player_fns
+
+    def flagged_rollout(actor, *args, **kwargs):
+        seen["in_rollout"] = True
+        try:
+            return rollout(actor, *args, **kwargs)
+        finally:
+            seen["in_rollout"] = False
+
+    def watched_player_fns(*args, **kwargs):
+        fns = build_player_fns(*args, **kwargs)
+
+        def init_states(wm_params, n_envs):
+            seen["made"] += 1
+            seen["made_in_rollout"] += seen["in_rollout"]
+            return fns["init_states"](wm_params, n_envs)
+
+        return {**fns, "init_states": init_states}
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("SHEEPRL_ACT_DUMP", str(tmp_path / "actions.pkl"))
+    monkeypatch.setattr(BurstActor, "rollout", flagged_rollout)
+    monkeypatch.setattr(dv3, "build_player_fns", watched_player_fns)
+    actions, _ = _seeded_run(tmp_path, "fresh")
+    # the dummy env's episodes last five steps, so episodes end inside these rollouts;
+    # one fresh state at start-up and one for each burst's parameters
+    assert len(actions) >= 16 and seen["made"] >= 4
+    assert seen["made_in_rollout"] == 0

@@ -356,7 +356,7 @@ def _run_probe_burst(n=4):
     cap = _CaptureSentinel()
     obs_learn.install(cap)
     try:
-        state, metrics, _ = run_train_burst(
+        state, metrics = run_train_burst(
             program, state, data, (keys,), world_size=1, fetch_metrics=True
         )
     finally:
@@ -426,7 +426,7 @@ def test_probes_disabled_program_carries_no_learn_keys(monkeypatch):
     state = {"params": {"w": jnp.ones(2)}}
     data = jnp.ones((3, 2))
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    state, metrics, _ = run_train_burst(
+    state, metrics = run_train_burst(
         program, state, data, (keys,), world_size=1, fetch_metrics=True
     )
     assert set(metrics) == {"Loss/x"}

@@ -230,8 +230,8 @@ def test_run_train_burst_splits_into_dispatch_and_sync_spans(tmp_path, monkeypat
 
     def burst(fetch):
         state = {"params": {"w": jnp.ones(2)}}
-        state, metrics, extras = run_train_burst(program, state, data, (keys,), fetch_metrics=fetch)
-        return jax.device_get(state), metrics, extras
+        state, metrics = run_train_burst(program, state, data, (keys,), fetch_metrics=fetch)
+        return jax.device_get(state), metrics
 
     plain = [burst(True), burst(False)]
     writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=True)
@@ -241,9 +241,8 @@ def test_run_train_burst_splits_into_dispatch_and_sync_spans(tmp_path, monkeypat
     finally:
         set_tracer(None)
         writer.close()
-    for (state_a, metrics_a, extras_a), (state_b, metrics_b, extras_b) in zip(plain, traced):
+    for (state_a, metrics_a), (state_b, metrics_b) in zip(plain, traced):
         np.testing.assert_array_equal(state_a["params"]["w"], state_b["params"]["w"])
-        assert extras_a == extras_b == ()
         assert (metrics_a is None) == (metrics_b is None)
         for k in metrics_a or ():
             np.testing.assert_array_equal(metrics_a[k], metrics_b[k])

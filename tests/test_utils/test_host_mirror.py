@@ -228,39 +228,22 @@ def test_enabled_for_rule():
     assert not HostParamMirror.enabled_for(FakeFabric(), FakeCfg())
 
 
-def test_refresh_every_caches_between_refreshes():
-    tree = _tree()
-    mirror = HostParamMirror(tree, enabled=True, refresh_every=3)
-    first = mirror(tree)
-    updated = jax.tree_util.tree_map(lambda x: x + 1.0, tree)
-    # calls 2 and 3 return the cached (stale) snapshot
-    assert mirror(updated) is first
-    assert mirror(updated) is first
-    # call 4 starts a new cadence window → fresh values
-    out = mirror(updated)
-    assert out is not first
-    np.testing.assert_array_equal(
-        np.asarray(out["scale"]), np.asarray(updated["scale"])
-    )
-
-
 def test_refresh_is_a_publish_span_and_counts_its_bytes(tmp_path):
-    """Every refresh (not a cache hit, not a disabled mirror) is one
-    ``Time/publish_time`` span and adds the packed vector's bytes to
-    ``publish_bytes``."""
+    """Every call of an enabled mirror (not of a disabled one) refreshes: one
+    ``Time/publish_time`` span, the tree's bytes added to ``publish_bytes``."""
     import json
 
     from sheeprl_tpu.obs import counters as obs_counters
     from sheeprl_tpu.obs.spans import TraceWriter, set_tracer
 
     tree = _tree()
-    packed_bytes = sum(np.asarray(leaf).nbytes for leaf in jax.tree_util.tree_leaves(tree))
+    tree_bytes = sum(np.asarray(leaf).nbytes for leaf in jax.tree_util.tree_leaves(tree))
     writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=False)
     set_tracer(writer)
     run_counters = obs_counters.Counters()
     obs_counters.install(run_counters)
     try:
-        mirror = HostParamMirror(tree, enabled=True, refresh_every=2)
+        mirror = HostParamMirror(tree, enabled=True)
         seen = []
         for _ in range(4):
             mirror(tree)
@@ -270,9 +253,9 @@ def test_refresh_is_a_publish_span_and_counts_its_bytes(tmp_path):
         obs_counters.install(None)
         set_tracer(None)
         writer.close()
-    assert seen == [(1, packed_bytes), (1, packed_bytes), (2, 2 * packed_bytes), (2, 2 * packed_bytes)]
-    assert run_counters.as_dict()["publish_bytes"] == 2 * packed_bytes
+    assert seen == [(n, n * tree_bytes) for n in (1, 2, 3, 4)]
+    assert run_counters.as_dict()["publish_bytes"] == 4 * tree_bytes
     with open(writer.path) as f:
         events = [json.loads(line) for line in f if line.strip()]
     spans = [e for e in events if e.get("ph") == "X"]
-    assert [(e["name"], e["cat"]) for e in spans] == [("Time/publish_time", "publish")] * 2
+    assert [(e["name"], e["cat"]) for e in spans] == [("Time/publish_time", "publish")] * 4
