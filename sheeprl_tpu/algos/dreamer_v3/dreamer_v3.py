@@ -13,7 +13,15 @@ TPU-native design (NOT a translation):
   target-EMA, world-model update, imagination rollout, actor update, critic
   update, and Moments state all live in a single ``shard_map``-ped jit with
   the batch dim sharded over the mesh's ``data`` axis. Sequence (T) and
-  horizon (H) loops are ``lax.scan``; XLA fuses the GRU cell across steps.
+  horizon (H) loops are ``lax.scan``: each is one ``while`` whose body is one
+  step's fused operations (nothing is fused *across* steps). What does not
+  feed the recurrence stays out of the T loop — the embed projection, the
+  prior logits, the Gumbel noise run over ``[T, B]`` at once, before or
+  after it — and so do the gradients of the loop's ``Dense`` kernels
+  (``models/hoist.py``): a scan's transpose would add each to a kernel-sized
+  float32 buffer once an iteration (the GRU's is 252 MB at XL), so the
+  backward loop keeps ``dx_t = dy_t @ W^T``, the recurrence, and hands out
+  ``dy_t`` stacked for one ``[T*B, in]^T x [T*B, out]`` product after it.
 - **Gradient psum via shardings.** Each of the three losses takes
   ``lax.pmean`` on its grads over the data axis — the DDP allreduce —
   and the Moments percentile EMA all-gathers λ-returns across the mesh
@@ -63,6 +71,7 @@ from sheeprl_tpu.data.staging import make_replay_staging
 from sheeprl_tpu.distributions import MSEDistribution, SymlogDistribution, TwoHotEncodingDistribution
 from sheeprl_tpu.envs.rollout import BurstActor, DeviceActor
 from sheeprl_tpu.envs.vector import make_vector_env
+from sheeprl_tpu.models.hoist import scan_hoisting_dense_grads
 from sheeprl_tpu.plane import train_gated_burst_plan
 from sheeprl_tpu.utils.logger import create_tensorboard_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
@@ -192,11 +201,11 @@ def build_train_fn(
                 wm_params, WorldModel.initial_posterior, jnp.zeros((1, rec_size))
             )
 
-        def step(carry, inp):
+        def step(params, init_post, carry, inp):
             posterior, recurrent = carry
             action, eproj, first, g = inp
             recurrent, posterior, post_logits = world_model.apply(
-                {"params": wm_params},
+                {"params": params},
                 posterior,
                 recurrent,
                 action,
@@ -210,11 +219,16 @@ def build_train_fn(
             return (posterior, recurrent), (recurrent, posterior, post_logits)
 
         # pre-draw the posterior sampling noise for the whole sequence in one
-        # vectorized call; the scan body is left with add+argmax only
+        # vectorized call; the scan body is left with add+argmax only. The
+        # scan's Dense kernels (the GRU's joint [h, x] -> 3H above all) get
+        # their gradients from one [T*B, in]^T x [T*B, out] product after the
+        # backward loop, not from a kernel-sized buffer added to T times
         with jax.named_scope("dv3/rssm"):
             gumbels = jax.random.gumbel(key, (T, B, S, D))
-            (_, _), (recurrents, posteriors, post_logits) = jax.lax.scan(
+            (_, _), (recurrents, posteriors, post_logits) = scan_hoisting_dense_grads(
                 step,
+                {"rssm": wm_params["rssm"]},
+                init_post,
                 (jnp.zeros((B, stoch_flat)), jnp.zeros((B, rec_size))),
                 (batch_actions, embed_proj, is_first, gumbels),
             )

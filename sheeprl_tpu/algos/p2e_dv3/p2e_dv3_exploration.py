@@ -67,6 +67,7 @@ from sheeprl_tpu.data.staging import make_replay_staging
 from sheeprl_tpu.distributions import MSEDistribution, SymlogDistribution, TwoHotEncodingDistribution
 from sheeprl_tpu.envs.rollout import BurstActor
 from sheeprl_tpu.envs.vector import make_vector_env
+from sheeprl_tpu.models.hoist import scan_hoisting_dense_grads
 from sheeprl_tpu.plane import train_gated_burst_plan
 from sheeprl_tpu.utils.logger import create_tensorboard_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
@@ -156,20 +157,24 @@ def build_train_fn(
             wm_params, WorldModel.initial_posterior, jnp.zeros((1, rec_size))
         )
 
-        def step(carry, inp):
+        def step(params, init_post, carry, inp):
             posterior, recurrent = carry
             action, eproj, first, g = inp
             recurrent, posterior, post_logits = world_model.apply(
-                {"params": wm_params},
+                {"params": params},
                 posterior, recurrent, action, eproj, first, init_post, None, g,
                 method=WorldModel.dynamic_posterior,
             )
             return (posterior, recurrent), (recurrent, posterior, post_logits)
 
-        # posterior sampling noise for the whole sequence drawn in one call
+        # posterior sampling noise for the whole sequence drawn in one call;
+        # the scan's Dense kernels get their gradients after the backward
+        # loop (models/hoist.py), as in dreamer_v3.py
         gumbels = jax.random.gumbel(key, (T, B, S, D))
-        (_, _), (recurrents, posteriors, post_logits) = jax.lax.scan(
+        (_, _), (recurrents, posteriors, post_logits) = scan_hoisting_dense_grads(
             step,
+            {"rssm": wm_params["rssm"]},
+            init_post,
             (jnp.zeros((B, stoch_flat)), jnp.zeros((B, rec_size))),
             (batch_actions, embed_proj, is_first, gumbels),
         )
