@@ -1116,7 +1116,8 @@ def main(fabric, cfg: Dict[str, Any]):
                     if seq_core and metrics is not None:
                         add_seq_core(
                             steps=n_samples,
-                            **{k: float(metrics[f"Core/{k}"]) * n_samples for k in seq_agent.CORE_COUNTERS},
+                            **{k: float(metrics[f"Core/{k}"]) * n_samples
+                               for k in seq_agent.CORE_COUNTERS if f"Core/{k}" in metrics},
                         )
                     # the cached fresh player state (episode resets) belongs
                     # to the previous params version

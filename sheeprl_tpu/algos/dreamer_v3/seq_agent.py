@@ -1,9 +1,15 @@
 """DreamerV3 with an autoregressive sequence model as its world-model core
-(``algo.world_model.sequence_model=qwen3_next``; howto/sequence_core.md).
+(``algo.world_model.sequence_model``, one of :data:`CORES`; howto/sequence_core.md).
 
 What TransDreamer, IRIS and STORM do to DreamerV3: the recurrent core gives
 way to a decoder over observation and action tokens, and everything else of
-the agent stays. Here the decoder is ``models/qwen3_next.py``.
+the agent stays. The decoder is the module :data:`CORES` names for the
+configured model; nothing below knows which. A core module answers ``Config``
+(with ``from_mapping``, ``hidden_size``, ``vocab_size``, ``chunk``,
+``experts_held``, ``moe_layers``, ``balance_loss``), ``init_params``,
+``window``, ``decode``, ``boundary_state``, ``init_state``, ``reset_state``,
+``embed``, ``head_logits``, and names the statistics it counts
+(``DECODE_COUNTS``, ``WINDOW_COUNTS``).
 
 - The stochastic state is *one* categorical over ``discrete_size`` observation
   codes, its posterior from the encoder alone. Token ids below
@@ -16,7 +22,7 @@ the agent stays. Here the decoder is ``models/qwen3_next.py``.
 - An episode's first step resets the core inside the window.
 - Imagination is one-token decoding, forward only (discrete actions: the
   actor learns by REINFORCE), from the state the training pass had at every
-  chunk boundary of every row.
+  ``core.chunk``-th token of every row.
 - Acting is the same one-token decoding against per-env state kept on the
   device (:func:`build_player`); the parameters are read where training left
   them, no copy is made.
@@ -24,6 +30,7 @@ the agent stays. Here the decoder is ``models/qwen3_next.py``.
 
 from __future__ import annotations
 
+import importlib
 from typing import Sequence
 
 import jax
@@ -54,13 +61,14 @@ from sheeprl_tpu.distributions import (
     OneHotCategorical,
     TwoHotEncodingDistribution,
 )
-from sheeprl_tpu.models import qwen3_next as qn
 from sheeprl_tpu.obs.dist import pmean
 
 sg = jax.lax.stop_gradient
 f32 = jnp.float32
 
-SEQUENCE_MODELS = ("gru", "qwen3_next")
+#: ``algo.world_model.sequence_model`` -> the module that is the core
+CORES = {"qwen3_next": "sheeprl_tpu.models.qwen3_next", "deepseek_v2": "sheeprl_tpu.models.deepseek_v2"}
+SEQUENCE_MODELS = ("gru", *CORES)
 #: the scope the core's parts are named under, beside ``dv3/encoder``, ``dv3/heads``, ...
 CORE_SCOPE = "dv3/core"
 WM_UNIFORM_HEADS = ((r"reward_model/head/", 0.0), (r"continue_model/head/", 1.0), (r"cnn_decoder/head/", 1.0))
@@ -82,9 +90,10 @@ class SeqWorldModel:
             raise ValueError("the sequence core's stochastic state is one categorical: algo.world_model.stochastic_size=1")
         self.codes = int(wm.discrete_size)
         self.n_actions = int(n_actions)
+        self.core_module = importlib.import_module(CORES[str(wm.sequence_model)])
         core = dict(wm.core)
         held = core.pop("held", {"index": 0, "of": 1})
-        self.core = qn.Qwen3NextConfig.from_mapping(
+        self.core = self.core_module.Config.from_mapping(
             {**core, "held_index": int(held["index"]), "held_of": int(held["of"])}
         )
         if self.core.vocab_size < self.codes + self.n_actions:
@@ -131,7 +140,7 @@ class SeqWorldModel:
 
     def prior_logits(self, p, h):
         """The head at an action position, over the codes."""
-        logits = qn.head_logits(p["core"], h, self.dtype, scope=CORE_SCOPE)
+        logits = self.core_module.head_logits(p["core"], h, self.dtype, scope=CORE_SCOPE)
         return uniform_mix(logits[..., : self.codes], self.codes, self.unimix)
 
     def code_embedding(self, p, onehot):
@@ -161,7 +170,7 @@ def build_seq_agent(cfg, actions_dim: Sequence[int], is_continuous: bool, observ
 
     if is_continuous or len(actions_dim) != 1:
         raise ValueError(
-            "algo.world_model.sequence_model=qwen3_next takes one discrete action a step "
+            f"algo.world_model.sequence_model={cfg.algo.world_model.sequence_model} takes one discrete action a step "
             f"(an action is a token); got actions_dim={tuple(actions_dim)}, continuous={is_continuous}"
         )
     dtype = compute_dtype_from_precision(cfg.fabric.get("precision", "32-true"))
@@ -195,7 +204,7 @@ def build_seq_agent(cfg, actions_dim: Sequence[int], is_continuous: bool, observ
         wm_params = hafner_initialization(wm_params, keys[7], WM_UNIFORM_HEADS)
         actor_params = hafner_initialization(actor_params, keys[8], ACTOR_UNIFORM_HEADS)
         critic_params = hafner_initialization(critic_params, keys[9], CRITIC_UNIFORM_HEADS)
-    wm_params["core"] = jax.jit(lambda k: qn.init_params(k, wm.core))(keys[10])
+    wm_params["core"] = jax.jit(lambda k: wm.core_module.init_params(k, wm.core))(keys[10])
     params = {
         "world_model": wm_params,
         "actor": actor_params,
@@ -223,7 +232,8 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
     learn_clips = {"world_model": clip_norm_of(world_tx), "actor": clip_norm_of(actor_tx), "critic": clip_norm_of(critic_tx)}
     if plan is not None:
         raise ValueError("the sequence core has no model-axis sharding plan yet (parallel.model_axis=1)")
-    wm, c = world_model, world_model.core
+    wm, c, core_module = world_model, world_model.core, world_model.core_module
+    decode_counts = tuple(core_module.DECODE_COUNTS)
     axis = fabric.data_axis
     cnn_keys = wm.cnn_keys
     codes, n_act, dtype = wm.codes, wm.n_actions, wm.dtype
@@ -259,7 +269,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
         a = codes + jnp.argmax(data["actions"], -1)  # [T, B]
         tokens = jnp.stack([z.T, a.T], -1).reshape(B, 2 * T).astype(jnp.int32)
         reset = jnp.stack([is_first.T, jnp.zeros_like(is_first.T)], -1).reshape(B, 2 * T).astype(jnp.int32)
-        h, states, stats = qn.window(p["core"], tokens, reset, c, dtype, scope=CORE_SCOPE)
+        h, states, stats = core_module.window(p["core"], tokens, reset, c, dtype, scope=CORE_SCOPE)
         h = h.reshape(B, T, 2, -1)
         h_obs, h_act = jnp.moveaxis(h[:, :, 0], 0, 1), jnp.moveaxis(h[:, :, 1], 0, 1)  # [T, B, D]
         prior_logits = wm.prior_logits(p, h_act[:-1])  # the prior of steps 1..T-1
@@ -284,9 +294,9 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
             dyn_loss = kl_dynamic * jnp.maximum(kl, kl_free_nats)
             repr_loss = kl_representation * jnp.maximum(categorical_kl(post, sg(prior)), kl_free_nats)
             kl_loss = jnp.concatenate([jnp.zeros((1, B), f32), (dyn_loss + repr_loss) * has_prior], 0)
-            aux = stats["aux"] / c.num_hidden_layers
+            aux = stats["aux"] / c.moe_layers
             loss = jnp.mean(kl_regularizer * kl_loss + observation_loss + reward_loss + continue_loss) \
-                + c.router_aux_loss_coef * aux
+                + c.balance_loss(stats["aux"])
             metrics = {
                 "Loss/world_model_loss": loss,
                 "Loss/observation_loss": jnp.mean(observation_loss),
@@ -302,28 +312,31 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
                 "Core/experts_hit": stats["experts_hit"],
                 "Core/dropped_pairs": stats["dropped_pairs"],
                 "Core/episode_ends": jnp.sum(is_first[1:]),
+                **{f"Core/{name}": stats[name] for name in core_module.WINDOW_COUNTS},
             }
         carry = {"states": sg(states), "tokens": tokens, "reset": reset}
         return loss, (metrics, carry)
 
     def imagine(p, actor_params, carry, key):
-        """``horizon`` imagined steps of two tokens from the token at every
-        chunk boundary of every row. Returns ``(features [H+1, N, F], actions
-        [H+1, N, A], start indices into the row's env steps, the one-token
-        steps' routing: pairs sent to held experts and held experts hit,
-        summed over steps and layers)``."""
+        """``horizon`` imagined steps of two tokens from every ``chunk``-th
+        token of every row. Returns ``(features [H+1, N, F], actions [H+1, N,
+        A], start indices into the row's env steps, the one-token steps' counts
+        (the core's ``DECODE_COUNTS``: pairs sent to held experts, held experts
+        hit, ...), summed over steps and layers)``."""
         B, L = carry["tokens"].shape
-        state, context = qn.boundary_state(carry["states"], carry["reset"], c, own_len=2 * horizon + 2, dtype=dtype)
+        state, context = core_module.boundary_state(
+            carry["states"], carry["reset"], c, own_len=2 * horizon + 2, dtype=dtype
+        )
         at = jnp.arange(L // c.chunk) * c.chunk
         core = jax.tree_util.tree_map(lambda x: x.astype(dtype) if x.ndim >= 2 else x, p["core"])
         pc = {**p, "core": core}
 
         def feed(state, routed, tokens):
-            h, state, stats = qn.decode(core, state, tokens, c, dtype, context=context, scope=CORE_SCOPE)
-            return h, state, routed + jnp.stack([stats["held_pairs"], stats["experts_hit"]])
+            h, state, stats = core_module.decode(core, state, tokens, c, dtype, context=context, scope=CORE_SCOPE)
+            return h, state, routed + jnp.stack([stats[name] for name in decode_counts])
 
         z0 = carry["tokens"][:, at]  # the observation token at each start
-        h0, state, routed = feed(state, jnp.zeros((2,), f32), z0)
+        h0, state, routed = feed(state, jnp.zeros((len(decode_counts),), f32), z0)
         feat0 = jnp.concatenate([core["embed"][z0].astype(f32), h0], -1)
         k0, key = jax.random.split(key)
         a0 = policy(actor_params, feat0, k0)
@@ -381,8 +394,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
                 "User/PredictedValues": jnp.mean(sg(predicted_values)),
                 "Core/imagination_starts": jnp.float32(traj.shape[1]),
                 "Core/decode_steps": jnp.float32(2 * horizon + 1),
-                "Core/imagination_pairs": routed[0],
-                "Core/imagination_experts_hit": routed[1],
+                **{f"Core/{counter}": count for counter, count in zip(core_module.DECODE_COUNTS.values(), routed)},
             }
         return policy_loss, aux
 
@@ -457,10 +469,14 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
 #: train-step metrics that are counts of the sequence core, summed into the run counters.
 #: ``held_pairs``/``experts_hit`` are the window pass's (pairs routed to held experts and
 #: held experts with at least one pair, over the layers), ``imagination_*`` the same of
-#: imagination's one-token steps, over steps and layers
+#: imagination's one-token steps, over steps and layers. A latent-attention core adds
+#: ``attended_pairs`` (query-key pairs inside an episode's segment, a window pass, over
+#: the layers), ``decode_context_tokens`` (latent positions the one-token steps attended
+#: to) and ``decode_cache_tokens`` (those that had to be read: a row's shared cache once);
+#: a counter the configured core does not report is not in the step's metrics
 CORE_COUNTERS = (
     "held_pairs", "experts_hit", "max_load", "dropped_pairs", "episode_ends", "imagination_starts", "decode_steps",
-    "imagination_pairs", "imagination_experts_hit",
+    "imagination_pairs", "imagination_experts_hit", "attended_pairs", "decode_context_tokens", "decode_cache_tokens",
 )
 
 
@@ -479,13 +495,13 @@ def build_player(world_model: SeqWorldModel, actor, cfg, n_envs: int):
     tokens and the prior over the next observation code that the action
     token's step gave.
     """
-    wm, c = world_model, world_model.core
+    wm, c, core_module = world_model, world_model.core, world_model.core_module
     dtype = wm.dtype
     distribution = resolve_actor_distribution(cfg.distribution.get("type", "auto"), False)
     init_std, min_std, unimix = float(cfg.algo.actor.init_std), float(cfg.algo.actor.min_std), float(cfg.algo.unimix)
 
     def init_state():
-        return qn.init_state(c, n_envs, 1, None, dtype)
+        return core_module.init_state(c, n_envs, 1, None, dtype)
 
     def step(params, state, raw_obs, reset, key, expl_amount):
         p = params["wm"]
@@ -493,15 +509,15 @@ def build_player(world_model: SeqWorldModel, actor, cfg, n_envs: int):
         k_z, k_a, k_e = jax.random.split(key, 3)
         post = wm.posterior_logits(p, obs)
         z = jax.random.categorical(k_z, post, -1).astype(jnp.int32)
-        state = qn.reset_state(state, reset.reshape(n_envs, 1) > 0)
-        h, state, _ = qn.decode(p["core"], state, z[:, None], c, dtype, scope=CORE_SCOPE)
-        feat = jnp.concatenate([qn.embed(p["core"], z), h[:, 0]], -1)
+        state = core_module.reset_state(state, reset.reshape(n_envs, 1) > 0)
+        h, state, _ = core_module.decode(p["core"], state, z[:, None], c, dtype, scope=CORE_SCOPE)
+        feat = jnp.concatenate([core_module.embed(p["core"], z), h[:, 0]], -1)
         pre = actor.apply({"params": params["actor"]}, feat)
         dists = build_actor_dists(pre, False, distribution, init_std, min_std, unimix)
         actions = sample_actor_actions(dists, False, k_a, True)
         actions = add_exploration_noise(actions, expl_amount, False, k_e)
         a = (wm.codes + jnp.argmax(actions[0], -1)).astype(jnp.int32)
-        h_a, state, _ = qn.decode(p["core"], state, a[:, None], c, dtype, scope=CORE_SCOPE)
+        h_a, state, _ = core_module.decode(p["core"], state, a[:, None], c, dtype, scope=CORE_SCOPE)
         prior = wm.prior_logits(p, h_a[:, 0])
         return actions[0], {"tokens": jnp.stack([z, a], -1), "prior_logits": prior}, state
 

@@ -149,7 +149,8 @@ class DeviceActor:
     """Acting whose per-env state never leaves the device.
 
     For a policy whose state is too large to ride a host callback (the
-    sequence core's delta-rule state and key-value cache, megabytes an env):
+    sequence cores' per-env state, megabytes an env: a delta-rule matrix and a
+    key-value ring a layer, or a latent ring a layer):
     ``step(params, state, obs, key) -> (to_host, on_device, state, key)`` is
     one jitted program a policy step, its ``state`` donated; ``to_host`` (the
     actions) is fetched and handed to ``host_step(to_host) -> next_obs``, the

@@ -15,12 +15,9 @@ two entry points that must agree:
   share row ``r``'s *context* keys and values (imagination starts of one
   replay row); acting has ``S = 1`` and no context.
 
-The expert layer is told which experts it holds (``held = (index, of)``): the
-router keeps all its outputs and its experts per token, the layer computes its
-own experts' part for the pairs routed to them, as grouped products over
-pairs sorted by expert (``jax.lax.ragged_dot``: a Mosaic kernel on the TPU,
-XLA elsewhere), plus the shared expert. What absent experts would add is left
-out; no pair is dropped (:func:`held_experts` walks the sorted pairs in windows).
+The expert layer is told which experts it holds (``held = (index, of)``) and is
+``models/moe.py``'s, shared with the other cores: softmax over all the router's
+outputs, the ten largest renormalised, a shared expert behind a ``sigmoid``.
 
 Equations follow the family's published implementation (``model_type:
 qwen3_next``): RMSNorm with weight ``1 + w`` (the norm inside the DeltaNet
@@ -32,18 +29,23 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.kernels import delta_rule
+from sheeprl_tpu.models import moe as _moe
+from sheeprl_tpu.models.moe import mm as _mm
 
 f32 = jnp.float32
 #: the ``jax.named_scope`` the stack's parts (``gdn``, ``attn``, ``moe``, ``head``)
 #: are named under, unless the caller gives its own
 SCOPE = "core"
+#: one-token statistics ``seq_agent`` sums over imagination's steps -> the run counter each feeds
+DECODE_COUNTS = {"held_pairs": "imagination_pairs", "experts_hit": "imagination_experts_hit"}
+#: window-pass statistics reported as run counters beside the expert layer's
+WINDOW_COUNTS = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,10 +76,20 @@ class Qwen3NextConfig:
     router_aux_loss_coef: float = 0.001
 
     @property
+    def moe_spec(self) -> _moe.MoESpec:
+        return _moe.MoESpec(self.num_experts, self.num_experts_per_tok, self.held_index, self.held_of)
+
+    @property
     def experts_held(self) -> int:
-        if self.num_experts % self.held_of:
-            raise ValueError(f"{self.num_experts} experts do not divide over {self.held_of} shares")
-        return self.num_experts // self.held_of
+        return self.moe_spec.experts_held
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_hidden_layers
+
+    def balance_loss(self, aux_sum):
+        """The coefficient times the expert layers' mean term."""
+        return self.router_aux_loss_coef * (aux_sum / self.num_hidden_layers)
 
     def is_attention(self, layer: int) -> bool:
         return (layer + 1) % self.full_attention_interval == 0
@@ -96,6 +108,10 @@ class Qwen3NextConfig:
     def from_mapping(cls, m) -> "Qwen3NextConfig":
         names = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: m[k] for k in m if k in names})
+
+
+#: the name ``seq_agent`` asks every core module for
+Config = Qwen3NextConfig
 
 
 # ---------------------------------------------------------------------------
@@ -158,10 +174,6 @@ def init_params(key, c: Qwen3NextConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _mm(x, w, dtype):
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=f32)
-
-
 def rms_norm(x, w, eps, plain: bool = False):
     x = x.astype(f32)
     y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
@@ -192,124 +204,13 @@ def segment_positions(reset):
     return seg, idx - start
 
 
-# -- the expert layer ----------------------------------------------------------
-
-
-def _expert_mlp(xs, gate, up, down, sizes, valid, dtype):
-    """The held experts over one window of sorted pairs: grouped products
-    (``jax.lax.ragged_dot``; rows outside every group are not computed, so
-    they are masked on both sides of each product)."""
-    xs = jnp.where(valid, xs, 0)
-    g = jax.lax.ragged_dot(xs, gate.astype(dtype), sizes, preferred_element_type=dtype)
-    u = jax.lax.ragged_dot(xs, up.astype(dtype), sizes, preferred_element_type=dtype)
-    hidden = jnp.where(valid, jax.nn.silu(g.astype(f32)) * u.astype(f32), 0).astype(dtype)
-    y = jax.lax.ragged_dot(hidden, down.astype(dtype), sizes, preferred_element_type=dtype)
-    return jnp.where(valid, y, 0)
-
-
-def _window_of(i, tok, weight, cum, n_held, W):
-    lo = i * W
-    tok_w = jax.lax.dynamic_slice_in_dim(tok, lo, W)
-    w_w = jax.lax.dynamic_slice_in_dim(weight, lo, W)
-    valid = ((lo + jnp.arange(W)) < n_held)[:, None]
-    sizes = jnp.clip(cum[1:], lo, lo + W) - jnp.clip(cum[:-1], lo, lo + W)
-    return lo, tok_w, w_w, valid, sizes
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def held_experts(x, gate, up, down, weight, tok, sizes, W, dtype):
-    """``sum_pairs weight * E_expert(x[token])`` over the pairs routed to held
-    experts, sorted by expert: ``tok``/``weight`` ``[M]`` (``M`` a multiple of
-    ``W``), ``sizes`` ``[E_held]`` pairs an expert. The pairs are taken ``W`` at
-    a time, as many windows as hold them (one, unless routing is far from
-    even), so no pair is dropped and no buffer has the worst case's size; the
-    backward pass walks the same windows and recomputes each."""
-    cum = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
-    n_held = cum[-1]
-
-    def body(i, out):
-        _, tok_w, w_w, valid, sizes_w = _window_of(i, tok, weight, cum, n_held, W)
-        y = _expert_mlp(x[tok_w], gate, up, down, sizes_w, valid, dtype)
-        return out.at[tok_w].add(y.astype(f32) * w_w[:, None])
-
-    return jax.lax.fori_loop(0, (n_held + W - 1) // W, body, jnp.zeros(x.shape, f32))
-
-
-def _held_experts_fwd(x, gate, up, down, weight, tok, sizes, W, dtype):
-    return held_experts(x, gate, up, down, weight, tok, sizes, W, dtype), (x, gate, up, down, weight, tok, sizes)
-
-
-def _held_experts_bwd(W, dtype, res, g):
-    x, gate, up, down, weight, tok, sizes = res
-    cum = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
-    n_held = cum[-1]
-
-    def body(i, acc):
-        dx, dgate, dup, ddown, dweight = acc
-        lo, tok_w, w_w, valid, sizes_w = _window_of(i, tok, weight, cum, n_held, W)
-        y, vjp = jax.vjp(lambda xs, a, b, d: _expert_mlp(xs, a, b, d, sizes_w, valid, dtype), x[tok_w], gate, up, down)
-        g_w = jnp.where(valid, g[tok_w], 0)
-        dxs, dg, du, dd = vjp((g_w * w_w[:, None]).astype(dtype))
-        dw = jnp.sum(y.astype(f32) * g_w, -1)
-        return (
-            dx.at[tok_w].add(jnp.where(valid, dxs, 0).astype(f32)), dgate + dg, dup + du, ddown + dd,
-            jax.lax.dynamic_update_slice_in_dim(dweight, dw, lo, 0),
-        )
-
-    zeros = lambda a: jnp.zeros(a.shape, f32)
-    dx, dgate, dup, ddown, dweight = jax.lax.fori_loop(
-        0, (n_held + W - 1) // W, body, (zeros(x), zeros(gate), zeros(up), zeros(down), zeros(weight))
-    )
-    return dx.astype(x.dtype), dgate, dup, ddown, dweight, None, None
-
-
-held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
-
-
-def route(p, x, c: Qwen3NextConfig, dtype):
-    """Softmax over all experts, the ``k`` largest renormalised. Returns
-    ``(probs [N, E], top_p [N, k], top_i [N, k])``."""
-    probs = jax.nn.softmax(_mm(x, p["router"], dtype), -1)
-    top_p, top_i = jax.lax.top_k(probs, c.num_experts_per_tok)
-    return probs, top_p / jnp.sum(top_p, -1, keepdims=True), top_i
+# -- the expert layer (``models/moe.py``, shared with the other cores) ----------
 
 
 def moe(p, x, c: Qwen3NextConfig, dtype, rows: int = 1) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """``x`` ``[N, D]`` (``rows`` window rows of ``N / rows`` tokens) -> the
-    held experts' part plus the shared expert, and the layer's routing
-    statistics. The load-balancing term is taken row by row and averaged."""
-    N, D = x.shape
-    k, Eh = c.num_experts_per_tok, c.experts_held
-    probs, top_p, top_i = route(p, x, c, dtype)
-    local = top_i - c.held_index * Eh
-    held = (local >= 0) & (local < Eh)
-    group = jnp.where(held, local, Eh).reshape(-1)  # pairs of absent experts sort last
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
-    n_held = jnp.sum(sizes)
-    # a window is twice the pairs an even routing sends here, at least a tile
-    W = min(-(-max(2 * N * k // c.held_of, 1) // 512) * 512, -(-N * k // 512) * 512)
-    pad = -(-N * k // W) * W - N * k
-    tok = jnp.pad(order // k, (0, pad)).astype(jnp.int32)
-    weight = jnp.pad(jnp.where(held, top_p, 0.0).reshape(-1)[order], (0, pad))
-    out = held_experts(x.astype(dtype), p["gate"], p["up"], p["down"], weight, tok, sizes, W, dtype)
-    shared = _mm(jax.nn.silu(_mm(x, p["shared_gate"], dtype)) * _mm(x, p["shared_up"], dtype),
-                 p["shared_down"], dtype)
-    out = out + jax.nn.sigmoid(_mm(x, p["shared_router"], dtype)) * shared
-    # load balancing over all of the router's outputs (Switch): E * sum_e f_e P_e,
-    # f_e the share of a row's tokens that chose e, P_e their mean probability
-    row = jnp.repeat(jnp.arange(rows), N // rows)
-    chosen = jnp.zeros((rows, c.num_experts), f32).at[row[:, None], top_i].add(1.0) * (rows / N)
-    mean_p = jnp.mean(probs.reshape(rows, N // rows, -1), 1)
-    aux = jnp.mean(c.num_experts * jnp.sum(jax.lax.stop_gradient(chosen) * mean_p, -1))
-    stats = {
-        "aux": aux,
-        "held_pairs": n_held.astype(f32),
-        "max_load": jnp.max(sizes).astype(f32),
-        "experts_hit": jnp.sum(sizes > 0).astype(f32),
-        "dropped_pairs": (jnp.sum(held) - n_held).astype(f32),
-    }
-    return out, stats
+    """The shared expert layer as this model has it: the ten largest
+    renormalised, a shared expert behind its own ``sigmoid`` gate."""
+    return _moe.moe(p, x, c.moe_spec, dtype, rows)
 
 
 # -- Gated DeltaNet ------------------------------------------------------------
@@ -457,16 +358,6 @@ def attn_decode(p, x, state, pos, rope_pos, context, c: Qwen3NextConfig, dtype):
 # ---------------------------------------------------------------------------
 
 
-def _add_stats(total, stats):
-    return stats if total is None else {
-        "aux": total["aux"] + stats["aux"],
-        "held_pairs": total["held_pairs"] + stats["held_pairs"],
-        "max_load": jnp.maximum(total["max_load"], stats["max_load"]),
-        "experts_hit": total["experts_hit"] + stats["experts_hit"],
-        "dropped_pairs": total["dropped_pairs"] + stats["dropped_pairs"],
-    }
-
-
 def embed(params, tokens):
     return params["embed"][tokens].astype(f32)
 
@@ -504,7 +395,7 @@ def window(params, tokens, reset, c: Qwen3NextConfig, dtype=f32, scope: str = SC
 
         x, st, stats = jax.checkpoint(block)(p, x)
         states[f"layers_{l}"] = st
-        total = _add_stats(total, stats)
+        total = _moe.add_stats(total, stats)
     with jax.named_scope(f"{scope}/head"):
         h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     return h, states, total
@@ -536,7 +427,7 @@ def decode(params, state, tokens, c: Qwen3NextConfig, dtype=f32, context=None, s
             y, stats = moe(p["moe"], h.reshape(R * S, -1), c, dtype)
         x = x + y.reshape(R, S, -1)
         new_state[name] = st
-        total = _add_stats(total, stats)
+        total = _moe.add_stats(total, stats)
     with jax.named_scope(f"{scope}/head"):
         h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     return h, new_state, total
