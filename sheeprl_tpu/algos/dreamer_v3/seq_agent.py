@@ -473,10 +473,13 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
 #: ``attended_pairs`` (query-key pairs inside an episode's segment, a window pass, over
 #: the layers), ``decode_context_tokens`` (latent positions the one-token steps attended
 #: to) and ``decode_cache_tokens`` (those that had to be read: a row's shared cache once);
+#: the delta-rule core adds ``delta_rule_fused_tiles`` (tiles of the chunk-local WY form
+#: that the fused kernels built, the three passes of a gradient step; 0 in the XLA form);
 #: a counter the configured core does not report is not in the step's metrics
 CORE_COUNTERS = (
     "held_pairs", "experts_hit", "max_load", "dropped_pairs", "episode_ends", "imagination_starts", "decode_steps",
     "imagination_pairs", "imagination_experts_hit", "attended_pairs", "decode_context_tokens", "decode_cache_tokens",
+    "delta_rule_fused_tiles",
 )
 
 

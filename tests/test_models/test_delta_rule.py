@@ -1,6 +1,8 @@
 """The third kernel family: the chunked gated delta rule against its reference
 tier (the recurrence, one token at a time), values and gradients, with resets
-inside chunks; and the states it hands over at chunk boundaries."""
+inside chunks; and the states it hands over at chunk boundaries. Each case runs
+the XLA form and, where its shapes allow, the fused kernels of the chunk-local
+WY build through the Pallas interpreter (what a TPU program would run)."""
 
 import jax
 import jax.numpy as jnp
@@ -30,11 +32,37 @@ CASES = {
     "reset_inside_a_chunk": dict(B=2, T=64, H=3, dk=16, dv=16, chunk=32, resets=((0, 37), (1, 5), (1, 50))),
     "reset_on_a_boundary_and_at_zero": dict(B=2, T=96, H=2, dk=8, dv=16, chunk=32, resets=((0, 0), (0, 32), (1, 64))),
     "two_resets_in_one_chunk": dict(B=1, T=128, H=2, dk=16, dv=8, chunk=64, resets=((0, 70), (0, 100))),
+    # head widths the fused kernels take (multiples of 128)
+    "wide_no_reset": dict(B=1, T=32, H=2, dk=128, dv=128, chunk=16, resets=()),
+    "wide_resets_inside_chunks": dict(B=2, T=48, H=4, dk=128, dv=256, chunk=16, resets=((0, 21), (1, 5), (1, 40))),
+    "wide_one_chunk_of_64": dict(B=1, T=64, H=2, dk=128, dv=128, chunk=64, resets=((0, 0), (0, 30))),
 }
+#: (case, tier): every case in the XLA form, the wide ones through the kernels too
+TIERS = [(case, "xla") for case in sorted(CASES)] + [(case, "kernel") for case in sorted(CASES) if case.startswith("wide")]
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_chunked_tier_gives_the_recurrences_values(case):
+@pytest.fixture
+def tier(request, monkeypatch):
+    """``kernel``: what a program lowered for a TPU runs, through the Pallas
+    interpreter (the CPU has no Mosaic); the calls are counted."""
+    calls = []
+    if request.param == "kernel":
+        def interpreted(kernel, xla_form, *operands):
+            calls.append(kernel)
+            return kernel(*operands, interpret=True)
+
+        monkeypatch.setattr(delta_rule, "_on_platform", interpreted)
+    yield request.param, calls
+    if request.param == "kernel":
+        assert calls, "the case never reached the kernels"
+
+
+def with_tier(test):
+    return pytest.mark.parametrize("case,tier", TIERS, indirect=["tier"])(test)
+
+
+@with_tier
+def test_chunked_tier_gives_the_recurrences_values(case, tier):
     c = CASES[case]
     args, reset = inputs(c["B"], c["T"], c["H"], c["dk"], c["dv"], 3, c["resets"])
     o_ref, S_ref = delta_rule.recurrent(*args, reset)
@@ -48,18 +76,212 @@ def test_chunked_tier_gives_the_recurrences_values(case):
     np.testing.assert_allclose(S_before[n], S_n, atol=5e-6)
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_chunked_tier_gives_the_recurrences_gradients(case):
+@with_tier
+def test_chunked_tier_gives_the_recurrences_gradients(case, tier):
+    """Every cotangent: ``q, k, v, g, beta`` and the state handed in; the loss
+    reads the outputs and the final state."""
     c = CASES[case]
     args, reset = inputs(c["B"], c["T"], c["H"], c["dk"], c["dv"], 4, c["resets"])
-    ref = jax.grad(lambda *a: jnp.sum(jnp.sin(delta_rule.recurrent(*a, reset)[0])), argnums=(0, 1, 2, 3, 4))(*args)
-    got = jax.grad(lambda *a: jnp.sum(jnp.sin(delta_rule.chunked(*a, reset, chunk=c["chunk"])[0])),
-                   argnums=(0, 1, 2, 3, 4))(*args)
+    S0 = 0.1 * jax.random.normal(jax.random.PRNGKey(5), (c["B"], c["H"], c["dk"], c["dv"]))
+
+    def loss(run):
+        def f(S0, *a):
+            o, S, *_ = run(*a, reset, S0)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(S))
+        return f
+
+    ref = jax.grad(loss(delta_rule.recurrent), argnums=tuple(range(6)))(S0, *args)
+    got = jax.grad(loss(lambda *a: delta_rule.chunked(*a, chunk=c["chunk"])), argnums=tuple(range(6)))(S0, *args)
     for a, b in zip(got, ref):
         assert float(jnp.abs(a - b).max()) <= 2e-5 * max(float(jnp.abs(b).max()), 1.0)
+
+
+@pytest.mark.parametrize("tier", ["xla", "kernel"], indirect=True)
+def test_chunked_tier_in_bf16_stays_near_the_recurrence(tier):
+    """``dtype=bf16`` rounds the scan's operands; the chunk-local build stays
+    float32 in both tiers, which therefore agree far closer than either does
+    with the recurrence."""
+    args, reset = inputs(2, 64, 2, 128, 128, 6, ((0, 20), (1, 33)))
+    o_ref, _ = delta_rule.recurrent(*args, reset)
+    run = lambda *a: delta_rule.chunked(*a, reset, chunk=16, dtype=jnp.bfloat16)[0]
+    o = run(*args)
+    assert float(jnp.abs(o - o_ref).max()) <= 2e-2 * float(jnp.abs(o_ref).max())
+    with pytest.MonkeyPatch.context() as m:  # the other tier, whichever this one is
+        m.setattr(delta_rule, "_fusable", lambda *a: False)
+        other = run(*args)
+    np.testing.assert_allclose(o, other, atol=1e-6 * float(jnp.abs(o_ref).max()))
+    grads = jax.grad(lambda *a: jnp.sum(jnp.sin(run(*a))), argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
+
+
+@pytest.mark.parametrize("shape", ["narrow_keys", "narrow_values", "odd_chunk", "eighteen_tiles", "three_tiles"])
+def test_a_shape_the_kernels_refuse_keeps_the_xla_form(shape, monkeypatch):
+    c = dict(narrow_keys=dict(H=2, dk=64, dv=128, chunk=16), narrow_values=dict(H=2, dk=128, dv=64, chunk=16),
+             odd_chunk=dict(H=2, dk=128, dv=128, chunk=12),
+             eighteen_tiles=dict(H=6, dk=128, dv=128, chunk=16),  # no block of 8 tiles, too many for one
+             three_tiles=dict(H=1, dk=128, dv=128, chunk=16))[shape]  # the kernels take tiles in pairs
+    monkeypatch.setattr(delta_rule, "_on_platform",
+                        lambda *a: pytest.fail("a refused shape reached the choice of platform"))
+    args, reset = inputs(1, 48, c["H"], c["dk"], c["dv"], 8, ((0, 17),))
+    o_ref, S_ref = delta_rule.recurrent(*args, reset)
+    o, S, _ = delta_rule.chunked(*args, reset, chunk=c["chunk"])
+    np.testing.assert_allclose(o, o_ref, atol=2e-6)
+    np.testing.assert_allclose(S, S_ref, atol=5e-6)
+    assert float(delta_rule.fused_tiles(args[1].shape, c["dv"], c["chunk"])) == 0.0
+
+
+def lower_triangles(n, C, seed):
+    return jnp.tril(jax.random.normal(jax.random.PRNGKey(seed), (n, C, C)) * 0.4 * C**-0.5, -1)
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+def test_the_kernels_inverse_times_its_matrix_is_the_identity(chunk):
+    """``T (I + L) = I`` to 1e-6, ``T`` read off the forward kernel: every key
+    the first unit vector and ``beta`` one (``K_b K^T`` all ones), ``D = L``,
+    ``V = I`` padded to the head width: ``U0 = T``; ``decay_in`` two: ``W = 2 T K``."""
+    L = lower_triangles(4, chunk, 9)
+    eye = jnp.broadcast_to(jnp.eye(chunk, 128), (4, chunk, 128))
+    first = jnp.zeros((4, chunk, 128)).at[..., 0].set(1.0)
+    ones = jnp.ones((4, chunk))
+    U0, W, T = delta_rule._wy_pallas(first, eye, L, ones, 2.0 * ones, interpret=True)
+    T = delta_rule._unpack(T)  # the kernel hands it on two tiles side by side
+    np.testing.assert_allclose(U0[..., :chunk], T, atol=0)
+    np.testing.assert_allclose(T @ (jnp.eye(chunk) + L), jnp.broadcast_to(jnp.eye(chunk), T.shape), atol=1e-6)
+    np.testing.assert_allclose(W, 2.0 * T @ first, atol=1e-6)
+    np.testing.assert_allclose(U0[..., chunk:], 0.0, atol=0)
+
+
+def test_packing_lays_two_tiles_side_by_side_and_back():
+    x = jnp.arange(4 * 3 * 3, dtype=jnp.float32).reshape(4, 3, 3)
+    packed = delta_rule._pack(x)
+    assert packed.shape == (2, 3, 6)
+    np.testing.assert_array_equal(packed[1, :, :3], x[2])
+    np.testing.assert_array_equal(packed[1, :, 3:], x[3])
+    np.testing.assert_array_equal(delta_rule._unpack(packed), x)
+
+
+def test_the_transpose_kernel_is_the_xla_forms_vjp():
+    """All five cotangents of the seam, and nothing on or above the diagonal of ``dD``."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 7)
+    n, C = 6, 16
+    k, dW = (0.3 * jax.random.normal(ks[i], (n, C, 128)) for i in range(2))
+    v, dU0 = (0.3 * jax.random.normal(ks[2 + i], (n, C, 256)) for i in range(2))
+    D = jnp.tril(jax.random.uniform(ks[4], (n, C, C)))
+    beta, decay_in = jax.random.uniform(ks[5], (n, C)), jax.random.uniform(ks[6], (n, C))
+    (_, _, T), back = jax.vjp(delta_rule._wy_xla, k, v, D, beta, decay_in)
+    want = back((dU0, dW, jnp.zeros_like(T)))
+    got = delta_rule._wy_transpose_pallas(k, v, D, beta, decay_in, delta_rule._pack(T), dU0, dW, interpret=True)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-6 * max(float(jnp.abs(b).max()), 1.0))
+    assert float(jnp.abs(jnp.triu(got[2])).max()) == 0.0
+
+
+def test_a_tpu_program_takes_the_kernels_and_a_cpu_program_the_xla_form():
+    """Platform and shape choose, no option: the same traced function lowers to
+    three Mosaic calls (forward, its rematerialisation, the transpose) for a
+    TPU and to none for the CPU, and counts its tiles accordingly."""
+    args, reset = inputs(1, 32, 2, 128, 128, 0, ())
+
+    def f(*a):
+        run = jax.checkpoint(lambda *a: delta_rule.chunked(*a, reset, chunk=16)[0])
+        return jax.value_and_grad(lambda *a: jnp.sum(run(*a)), argnums=(0, 1, 2, 3, 4))(*a), \
+            delta_rule.fused_tiles(a[1].shape, 128, 16)
+
+    traced = jax.jit(f).trace(*args)
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert tpu.count("tpu_custom_call") == 3
+    assert "4.000000e+00" in tpu  # 1 row x 2 chunks x 2 heads
+    assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert float(f(*args)[1]) == 0.0  # on this CPU backend the call itself runs the XLA form
 
 
 def test_a_window_that_is_no_multiple_of_the_chunk_is_refused():
     args, reset = inputs(1, 40, 1, 8, 8, 0, ())
     with pytest.raises(ValueError, match="multiple of the chunk"):
         delta_rule.chunked(*args, reset, chunk=16)
+
+
+# -- compiled for a described v5e, no chip (tools/aot_hlo.py) ---------------------
+
+
+def load_aot_hlo():
+    """``tools/aot_hlo.py`` as a module (``tools`` is no package)."""
+    import importlib.util
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        "aot_hlo", pathlib.Path(__file__).parents[2] / "tools" / "aot_hlo.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def aot_hlo():
+    """``tools/aot_hlo.py`` where a ``v5e`` topology can be described; the
+    persistent compile cache is off around these compiles (an entry written for
+    a described chip cannot be read back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says that no TPU compiler is here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    module = load_aot_hlo()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield module
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_mosaic_compiles_both_kernels_at_the_cells_shapes(aot_hlo):
+    """4,096 tiles of 64 tokens, heads of 128: what interpret mode cannot show
+    (alignment, VMEM) the chip's compiler refuses here, at no chip time."""
+    f32 = jnp.float32
+    wide, square, row = (jax.ShapeDtypeStruct((4096, 64) + tail, f32) for tail in ((128,), (64,), ()))
+    packed = jax.ShapeDtypeStruct((2048, 64, 128), f32)
+    forward = aot_hlo.compile_for(delta_rule._wy_pallas, wide, wide, square, row, row)
+    assert forward.as_text().count("tpu_custom_call") == 1
+    transpose = aot_hlo.compile_for(delta_rule._wy_transpose_pallas, wide, wide, square, row, row, packed, wide, wide)
+    assert transpose.as_text().count("tpu_custom_call") == 1
+
+
+def test_a_v5e_compile_of_the_chunked_tier_holds_the_kernels_and_reckons_the_rest(aot_hlo):
+    """The whole tier lowered for the described chip takes the kernels; the rows of
+    ``aot_hlo`` weigh the scan's body by its 4 trips and name the program's scopes."""
+    B, T, H, d = 1, 256, 8, 128
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((B, T, H, d),) * 3 + ((B, T, H),) * 2]
+
+    def tier(q, k, v, g, beta):
+        with jax.named_scope("delta_rule"):
+            return delta_rule.chunked(q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16)[0]
+
+    text = aot_hlo.compile_for(tier, *shapes).as_text()
+    assert text.count("tpu_custom_call") == 1
+    rows = aot_hlo.rows(text)
+    assert rows == sorted(rows, reverse=True) and rows[0][0] > 0
+    in_loop = [r for r in rows if "delta_rule/while/body" in r[2]]
+    assert in_loop and all(operations % 4 == 0 for _, operations, _ in in_loop)
+
+
+def test_aot_hlo_weighs_a_loop_body_by_its_trip_count():
+    """The reader alone, on a module written by hand: no compiler needed."""
+    hlo = """
+%cond (p: (s32[])) -> pred[] {
+  %c = s32[]{:T(128)} constant(16)
+  ROOT %lt = pred[] compare(%g, %c), direction=LT
+}
+
+%body (p: (s32[])) -> (s32[]) {
+  %f = f32[8] fusion(%x), kind=kLoop, metadata={op_name="jit(f)/scan/mul"}, backend_config={"estimated_cycles":"100"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %w = (s32[]) while(%t), condition=%cond, body=%body
+  %g = f32[8] fusion(%a), kind=kLoop, metadata={op_name="jit(f)/add"}, backend_config={"estimated_cycles":"700"}
+}
+"""
+    assert load_aot_hlo().rows(hlo) == [(1600, 16, "jit(f)/scan/mul"), (700, 1, "jit(f)/add")]
