@@ -84,7 +84,12 @@ from sheeprl_tpu.obs import (
     set_shard_footprint,
     span,
 )
-from sheeprl_tpu.obs.counters import add_seq_core, installed as counters_installed, set_seq_core_state_bytes
+from sheeprl_tpu.obs.counters import (
+    add_seq_core,
+    installed as counters_installed,
+    set_seq_core_gauges,
+    set_seq_core_state_bytes,
+)
 from sheeprl_tpu.obs.dist import pmean
 from sheeprl_tpu.utils.optim import clip_norm_of
 from sheeprl_tpu.parallel.shard import measured_bytes_per_device
@@ -1118,6 +1123,9 @@ def main(fabric, cfg: Dict[str, Any]):
                             steps=n_samples,
                             **{k: float(metrics[f"Core/{k}"]) * n_samples
                                for k in seq_agent.CORE_COUNTERS if f"Core/{k}" in metrics},
+                        )
+                        set_seq_core_gauges(
+                            **{k: float(metrics[f"Core/{k}"]) for k in seq_agent.CORE_GAUGES if f"Core/{k}" in metrics}
                         )
                     # the cached fresh player state (episode resets) belongs
                     # to the previous params version

@@ -8,8 +8,10 @@ configured model; nothing below knows which. A core module answers ``Config``
 (with ``from_mapping``, ``hidden_size``, ``vocab_size``, ``chunk``,
 ``experts_held``, ``moe_layers``, ``balance_loss``), ``init_params``,
 ``window``, ``decode``, ``boundary_state``, ``init_state``, ``reset_state``,
-``embed``, ``head_logits``, and names the statistics it counts
-(``DECODE_COUNTS``, ``WINDOW_COUNTS``).
+``embed``, ``head_logits``, ``balance_step`` (what training moves outside the
+gradient, after the optimiser's step, from the window pass's load of every
+expert: a router's selection bias, or nothing), and names the statistics it
+counts (``DECODE_COUNTS``, ``WINDOW_COUNTS``).
 
 - The stochastic state is *one* categorical over ``discrete_size`` observation
   codes, its posterior from the encoder alone. Token ids below
@@ -61,13 +63,17 @@ from sheeprl_tpu.distributions import (
     OneHotCategorical,
     TwoHotEncodingDistribution,
 )
-from sheeprl_tpu.obs.dist import pmean
+from sheeprl_tpu.obs.dist import pmean, psum
 
 sg = jax.lax.stop_gradient
 f32 = jnp.float32
 
 #: ``algo.world_model.sequence_model`` -> the module that is the core
-CORES = {"qwen3_next": "sheeprl_tpu.models.qwen3_next", "deepseek_v2": "sheeprl_tpu.models.deepseek_v2"}
+CORES = {
+    "qwen3_next": "sheeprl_tpu.models.qwen3_next",
+    "deepseek_v2": "sheeprl_tpu.models.deepseek_v2",
+    "lfm2_moe": "sheeprl_tpu.models.lfm2_moe",
+}
 SEQUENCE_MODELS = ("gru", *CORES)
 #: the scope the core's parts are named under, beside ``dv3/encoder``, ``dv3/heads``, ...
 CORE_SCOPE = "dv3/core"
@@ -315,7 +321,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
                 **{f"Core/{name}": stats[name] for name in core_module.WINDOW_COUNTS},
             }
         carry = {"states": sg(states), "tokens": tokens, "reset": reset}
-        return loss, (metrics, carry)
+        return loss, (metrics, carry, sg(stats["load"]))
 
     def imagine(p, actor_params, carry, key):
         """``horizon`` imagined steps of two tokens from every ``chunk``-th
@@ -413,7 +419,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
                 lambda cr, t: tau * cr + (1.0 - tau) * t, params["critic"], params["target_critic"]
             )
         k_wm, k_img = jax.random.split(key)
-        (wm_loss, (wm_metrics, carry)), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
+        (wm_loss, (wm_metrics, carry, load)), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
             params["world_model"], data, k_wm
         )
         with jax.named_scope("dv3/optimizer"):
@@ -421,6 +427,9 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
             wm_updates, wm_opt = world_tx.update(wm_grads, opt["world_model"], params["world_model"])
             wm_params = optax.apply_updates(params["world_model"], wm_updates)
             grad_norm_wm = optax.global_norm(wm_grads)
+            # what the core trains outside the gradient, from the whole step's load of every expert
+            balanced, balance_report = core_module.balance_step(wm_params["core"], psum(load, axis), c)
+            wm_params = {**wm_params, "core": balanced}
         if not learn_on:  # 2.7 GB each at the recipe's size: kept only for the learn probes
             del wm_grads, wm_updates
         (actor_loss, aux), actor_grads = jax.value_and_grad(actor_loss_fn, has_aux=True)(
@@ -441,6 +450,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
         metrics = dict(wm_metrics)
         metrics.update({k: v for k, v in aux.items() if k not in ("trajectories", "lambda_values", "discount", "moments")})
         metrics["Loss/value_loss"] = critic_loss
+        metrics.update({f"Core/{name}": value for name, value in balance_report.items()})
         with jax.named_scope("dv3/optimizer"):
             metrics["Grads/world_model"] = grad_norm_wm
             metrics["Grads/actor"] = optax.global_norm(actor_grads)
@@ -475,12 +485,18 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
 #: to) and ``decode_cache_tokens`` (those that had to be read: a row's shared cache once);
 #: the delta-rule core adds ``delta_rule_fused_tiles`` (tiles of the chunk-local WY form
 #: that the fused kernels built, the three passes of a gradient step; 0 in the XLA form);
-#: a counter the configured core does not report is not in the step's metrics
+#: the convolution-attention core (``lfm2_moe``) reports ``attended_pairs`` of its attention
+#: layers and adds ``router_max_load`` (the largest load among *all* the router's outputs,
+#: held here or not, the routing layers' maximum, a step's window pass: summed, divide by
+#: the steps); a counter the configured core does not report is not in the step's metrics
 CORE_COUNTERS = (
     "held_pairs", "experts_hit", "max_load", "dropped_pairs", "episode_ends", "imagination_starts", "decode_steps",
     "imagination_pairs", "imagination_experts_hit", "attended_pairs", "decode_context_tokens", "decode_cache_tokens",
-    "delta_rule_fused_tiles",
+    "delta_rule_fused_tiles", "router_max_load",
 )
+#: train-step metrics that are levels, not counts: the newest burst's value stands.
+#: ``expert_bias_abs_max`` (``lfm2_moe``): the largest selection bias in any routing layer
+CORE_GAUGES = ("expert_bias_abs_max",)
 
 
 # ---------------------------------------------------------------------------

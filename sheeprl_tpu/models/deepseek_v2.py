@@ -407,6 +407,13 @@ def decode(params, state, tokens, c: DeepseekV2Config, dtype=f32, context=None, 
     return h, new_state, {**(total or {}), **counts}
 
 
+def balance_step(params, load, c: DeepseekV2Config):
+    """Nothing moves outside the gradient: this model balances its experts by
+    the loss's load-balancing term (:meth:`DeepseekV2Config.balance_loss`).
+    Returns ``(params, what the step reports)``."""
+    return params, {}
+
+
 def init_state(c: DeepseekV2Config, R: int, S: int, cache_len: Optional[int] = None, dtype=f32):
     """Per-stream state at an episode's start: an empty latent ring a layer."""
     Lo = c.cache_len if cache_len is None else int(cache_len)

@@ -1,5 +1,5 @@
 """A sparse expert layer that is told which experts it holds, for every
-sequence core (``qwen3_next.py``, ``deepseek_v2.py``).
+sequence core (``qwen3_next.py``, ``deepseek_v2.py``, ``lfm2_moe.py``).
 
 ``held = (index, of)``: ``of`` chips share a layer's experts and this one is
 ``index`` of them. The router keeps all its outputs and its experts per token;
@@ -9,12 +9,17 @@ a Mosaic kernel on the TPU, XLA elsewhere), plus the shared expert, which every
 chip computes alike. What absent experts would add is left out; no pair is
 dropped (:func:`held_experts` walks the sorted pairs in windows).
 
-What differs between the models is in :class:`MoESpec`: whether the chosen
-experts' weights are renormalised, the factor on the routed sum, whether the
-shared expert sits behind a ``sigmoid`` gate of its own (a ``shared_router``
-leaf) or is always on. The load-balancing term is the same for both (Switch's
-``E * sum_e f_e P_e`` taken row by row); a model that normalises it otherwise
-does so itself (:attr:`MoESpec.aux_per_choice`).
+What differs between the models is in :class:`MoESpec`: how the router scores
+(a softmax over all experts, or a ``sigmoid`` of each alone), whether the ``k``
+experts are chosen by the score plus a per-expert bias the weights never see
+(an ``expert_bias`` leaf no gradient reaches: :func:`balance_step` moves it),
+whether the chosen experts' weights are renormalised, the factor on the routed
+sum, whether there is a shared expert and whether it sits behind a ``sigmoid``
+gate of its own (a ``shared_router`` leaf) or is always on. The load-balancing
+term is the same for all (Switch's ``E * sum_e f_e P_e`` taken row by row, over
+the scores as shares of their sum); a model that normalises it otherwise does
+so itself (:attr:`MoESpec.aux_per_choice`), and one that balances without a
+loss leaves it out of its loss.
 """
 
 from __future__ import annotations
@@ -39,6 +44,10 @@ class MoESpec:
     scale: float = 1.0  # the factor on the routed sum
     shared_gate: bool = True  # ``sigmoid(x @ shared_router)`` on the shared expert
     aux_per_choice: bool = False  # f_e counted per choice (divided by k), as DeepSeek's
+    score: str = "softmax"  # or "sigmoid": every expert scored alone
+    select_bias: bool = False  # the k are chosen by score + ``expert_bias``; the weights are the scores'
+    normalize_eps: float = 0.0  # added to the chosen weights' sum before the division
+    shared: bool = True  # a shared expert beside the routed ones
 
     @property
     def experts_held(self) -> int:
@@ -124,24 +133,37 @@ held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def route(p, x, spec: MoESpec, dtype):
-    """Softmax over all experts and the ``k`` largest (renormalised and scaled
-    as ``spec`` says). Returns ``(probs [N, E], top_p [N, k], top_i [N, k])``."""
-    probs = jax.nn.softmax(mm(x, p["router"], dtype), -1)
-    top_p, top_i = jax.lax.top_k(probs, spec.num_experts_per_tok)
+    """Every expert's score (a softmax over all, or each one's ``sigmoid``) and
+    the ``k`` chosen: the largest scores, or with ``spec.select_bias`` the
+    largest of ``score + expert_bias`` — the bias decides who is chosen and is
+    no part of a weight. Weights renormalised and scaled as ``spec`` says.
+    Returns ``(scores [N, E], top_p [N, k], top_i [N, k])``."""
+    logits = mm(x, p["router"], dtype)
+    scores = jax.nn.sigmoid(logits) if spec.score == "sigmoid" else jax.nn.softmax(logits, -1)
+    if spec.select_bias:
+        _, top_i = jax.lax.top_k(scores + p["expert_bias"], spec.num_experts_per_tok)
+        top_p = jnp.take_along_axis(scores, top_i, -1)
+    else:
+        top_p, top_i = jax.lax.top_k(scores, spec.num_experts_per_tok)
     if spec.normalize:
-        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+        total = jnp.sum(top_p, -1, keepdims=True)
+        if spec.normalize_eps:
+            total = total + spec.normalize_eps
+        top_p = top_p / total
     if spec.scale != 1.0:
         top_p = top_p * spec.scale
-    return probs, top_p, top_i
+    return scores, top_p, top_i
 
 
 def moe(p, x, spec: MoESpec, dtype, rows: int = 1) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``x`` ``[N, D]`` (``rows`` window rows of ``N / rows`` tokens) -> the
-    held experts' part plus the shared expert, and the layer's routing
-    statistics. The load-balancing term is taken row by row and averaged."""
+    held experts' part plus the shared expert (where the model has one), and
+    the layer's routing statistics. The load-balancing term is taken row by
+    row and averaged; ``load`` ``[1, E]`` is how many tokens chose each of
+    *all* the router's outputs, held here or not."""
     N, D = x.shape
     k, Eh = spec.num_experts_per_tok, spec.experts_held
-    probs, top_p, top_i = route(p, x, spec, dtype)
+    scores, top_p, top_i = route(p, x, spec, dtype)
     local = top_i - spec.held_index * Eh
     held = (local >= 0) & (local < Eh)
     group = jnp.where(held, local, Eh).reshape(-1)  # pairs of absent experts sort last
@@ -154,14 +176,17 @@ def moe(p, x, spec: MoESpec, dtype, rows: int = 1) -> Tuple[jnp.ndarray, Dict[st
     tok = jnp.pad(order // k, (0, pad)).astype(jnp.int32)
     weight = jnp.pad(jnp.where(held, top_p, 0.0).reshape(-1)[order], (0, pad))
     out = held_experts(x.astype(dtype), p["gate"], p["up"], p["down"], weight, tok, sizes, W, dtype)
-    shared = mm(jax.nn.silu(mm(x, p["shared_gate"], dtype)) * mm(x, p["shared_up"], dtype), p["shared_down"], dtype)
-    if spec.shared_gate:
-        shared = jax.nn.sigmoid(mm(x, p["shared_router"], dtype)) * shared
-    out = out + shared
+    if spec.shared:
+        shared = mm(jax.nn.silu(mm(x, p["shared_gate"], dtype)) * mm(x, p["shared_up"], dtype), p["shared_down"], dtype)
+        if spec.shared_gate:
+            shared = jax.nn.sigmoid(mm(x, p["shared_router"], dtype)) * shared
+        out = out + shared
     # load balancing over all of the router's outputs (Switch): E * sum_e f_e P_e,
     # f_e the share of a row's tokens that chose e, P_e their mean probability
     row = jnp.repeat(jnp.arange(rows), N // rows)
-    chosen = jnp.zeros((rows, spec.num_experts), f32).at[row[:, None], top_i].add(1.0) * (rows / N)
+    counts = jnp.zeros((rows, spec.num_experts), f32).at[row[:, None], top_i].add(1.0)
+    chosen = counts * (rows / N)
+    probs = scores / jnp.sum(scores, -1, keepdims=True) if spec.score == "sigmoid" else scores
     mean_p = jnp.mean(probs.reshape(rows, N // rows, -1), 1)
     aux = jnp.mean(spec.num_experts * jnp.sum(jax.lax.stop_gradient(chosen) * mean_p, -1))
     if spec.aux_per_choice:
@@ -172,13 +197,24 @@ def moe(p, x, spec: MoESpec, dtype, rows: int = 1) -> Tuple[jnp.ndarray, Dict[st
         "max_load": jnp.max(sizes).astype(f32),
         "experts_hit": jnp.sum(sizes > 0).astype(f32),
         "dropped_pairs": (jnp.sum(held) - n_held).astype(f32),
+        "load": jnp.sum(counts, 0)[None],
     }
     return out, stats
 
 
 def add_stats(total, stats):
-    """Routing statistics summed over layers (the largest load: its maximum)."""
+    """Routing statistics summed over layers (the largest load: its maximum;
+    ``load``: a row a layer)."""
     if total is None:
         return stats
-    return {name: jnp.maximum(total[name], value) if name == "max_load" else total[name] + value
-            for name, value in stats.items()}
+    join = {"max_load": jnp.maximum, "load": lambda a, b: jnp.concatenate([a, b])}
+    return {name: join.get(name, jnp.add)(total[name], value) for name, value in stats.items()}
+
+
+def balance_step(bias, load, rate: float):
+    """Loss-free balancing (arXiv:2408.15664): an expert under the mean load
+    is made likelier to be chosen, one over it less, by ``rate`` a step
+    whatever the distance: ``b_e <- b_e + rate * sign(mean(load) - load_e)``.
+    ``load`` is over every token of the step (summed over the data axis by the
+    caller) and over all the router's outputs."""
+    return bias + rate * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)

@@ -438,6 +438,13 @@ def decode(params, state, tokens, c: Qwen3NextConfig, dtype=f32, context=None, s
     return h, new_state, total
 
 
+def balance_step(params, load, c: Qwen3NextConfig):
+    """Nothing moves outside the gradient: this model balances its experts by
+    the loss's load-balancing term (:meth:`Qwen3NextConfig.balance_loss`).
+    Returns ``(params, what the step reports)``."""
+    return params, {}
+
+
 def init_state(c: Qwen3NextConfig, R: int, S: int, cache_len: Optional[int] = None, dtype=f32):
     """Per-stream state at an episode's start: all zero."""
     Lo = c.cache_len if cache_len is None else int(cache_len)

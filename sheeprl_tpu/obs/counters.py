@@ -57,6 +57,7 @@ __all__ = [
     "add_slo_alert",
     "add_train_burst",
     "set_replay_shard_fill",
+    "set_seq_core_gauges",
     "set_seq_core_state_bytes",
     "note_plane_policy_version",
     "device_memory_stats",
@@ -140,8 +141,8 @@ class Counters:
         # the window pass and of imagination's one-token steps, the per-step
         # largest load of a held expert (summed: divide by the steps), pairs
         # dropped (has to stay 0), episode ends inside sampled windows,
-        # imagination starts, one-token decode steps; and a gauge, the acting
-        # state's bytes an env
+        # imagination starts, one-token decode steps; and gauges: the acting
+        # state's bytes an env, and what `seq_agent.CORE_GAUGES` names
         self.seq_core: Dict[str, float] = {}
         # publication (utils/host.py::HostParamMirror): refreshes of a host
         # parameter mirror, the bytes of the leaves they moved device→host
@@ -485,6 +486,14 @@ def add_seq_core(steps: int = 0, **amounts: float) -> None:
             c.seq_core["steps"] = c.seq_core.get("steps", 0) + int(steps)
             for name, amount in amounts.items():
                 c.seq_core[name] = c.seq_core.get(name, 0.0) + float(amount)
+
+
+def set_seq_core_gauges(**levels: float) -> None:
+    """Gauges of the sequence core (``seq_agent.CORE_GAUGES``): the newest burst's value stands."""
+    c = _COUNTERS
+    if c is not None and levels:
+        with c._lock:
+            c.seq_core.update({name: float(level) for name, level in levels.items()})
 
 
 def set_seq_core_state_bytes(nbytes: int) -> None:

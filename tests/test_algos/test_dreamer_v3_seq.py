@@ -17,8 +17,10 @@ TINY_AGENT = [
     "algo.world_model.encoder.cnn_channels_multiplier=2", "algo.world_model.representation_model.hidden_size=16",
     "algo.world_model.discrete_size=254",
 ]
-#: each sequence core's recipe and its ``core`` block at widths a test can hold (four layers each, 16 experts, 4 held)
-RECIPES = {"qwen3_next": "dreamer_v3_qwen3next_ep16", "deepseek_v2": "dreamer_v3_dsv2lite_ep8"}
+#: each sequence core's recipe and its ``core`` block at widths a test can hold (four layers each, or the
+#: convolution-attention core's six with its two dense ones; 16 experts, 4 held)
+RECIPES = {"qwen3_next": "dreamer_v3_qwen3next_ep16", "deepseek_v2": "dreamer_v3_dsv2lite_ep8",
+           "lfm2_moe": "dreamer_v3_lfm2_ep4"}
 TINY_CORES = {
     "qwen3_next": dict(
         hidden_size=64, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16,
@@ -30,10 +32,16 @@ TINY_CORES = {
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=16, num_experts_per_tok=3,
         moe_intermediate_size=32, vocab_size=256, chunk=16, cache_len=32,
     ),
+    "lfm2_moe": dict(
+        hidden_size=64, intermediate_size=96, num_attention_heads=4, num_key_value_heads=2, num_experts=16,
+        num_experts_per_tok=3, moe_intermediate_size=32, vocab_size=256, chunk=16, cache_len=32,
+    ),
 }
 #: acting state of one env, bytes: three delta-rule states of 4 x 16 x 16 floats with their tails and a 32-token
-#: cache of 2 x 32 values, or four rings of 32 latents of 32 + 8 floats; two counters each
-STATE_BYTES = {"qwen3_next": 3 * (4 * 256 + 3 * 128) * 4 + 2 * 32 * 64 * 4 + 8, "deepseek_v2": 4 * 32 * 40 * 4 + 8}
+#: cache of 2 x 32 values, or four rings of 32 latents of 32 + 8 floats, or five convolution layers' two rows of 64
+#: floats and one 32-token cache of 2 x 2 heads of 16; two counters each
+STATE_BYTES = {"qwen3_next": 3 * (4 * 256 + 3 * 128) * 4 + 2 * 32 * 64 * 4 + 8, "deepseek_v2": 4 * 32 * 40 * 4 + 8,
+               "lfm2_moe": 5 * 2 * 64 * 4 + 2 * 32 * 32 * 4 + 8}
 CORES = sorted(RECIPES)
 OBS_SPACE = gym.spaces.Dict({"rgb": gym.spaces.Box(0, 255, (3, 64, 64), np.uint8)})
 
@@ -81,8 +89,16 @@ def test_the_recipe_trains_through_cli_run_and_counts_what_its_core_did(tmp_path
         assert 13 * 2 * 64 * 4 <= counts["attended_pairs"] <= 13 * 2 * 64 * 4 * 10
         # a one-token step reads at least its stream's own ring, and no more positions than it attends to
         assert 13 * 8 * 4 * 7 <= counts["decode_cache_tokens"] <= counts["decode_context_tokens"]
+    elif core == "lfm2_moe":
+        # one attention layer among the six; the largest load of any of the 16 router outputs in a step of
+        # 128 tokens x 3 lies between the mean and every token; the bias moved by 0.001 a step at the most
+        assert 13 * 2 * 64 <= counts["attended_pairs"] <= 13 * 2 * 64 * 10 and "decode_context_tokens" not in counts
+        assert 13 * 128 * 3 / 16 <= counts["router_max_load"] <= 13 * 128
+        assert 0.0 < counts["expert_bias_abs_max"] <= 13 * 0.001 + 1e-6
     else:
         assert "attended_pairs" not in counts and "decode_context_tokens" not in counts
+    if core != "lfm2_moe":
+        assert "router_max_load" not in counts and "expert_bias_abs_max" not in counts
     spans = [json.loads(line) for line in open(tmp_path / "spans.jsonl")]
     decode = [s for s in spans if s.get("name") == "Time/act_decode_time"]
     assert decode and all(s.get("args", {}).get("parent") == "Time/rollout_time" for s in decode)
@@ -91,6 +107,7 @@ def test_the_recipe_trains_through_cli_run_and_counts_what_its_core_did(tmp_path
 @pytest.mark.parametrize("exp,core,why", [
     ("p2e_dv3_exploration", "qwen3_next", "not supported by this entrypoint"),
     ("p2e_dv3_exploration", "deepseek_v2", "not supported by this entrypoint"),
+    ("p2e_dv3_exploration", "lfm2_moe", "not supported by this entrypoint"),
     ("dreamer_v3", "no_such_core", "no_such_core"),
 ])
 def test_a_core_the_entrypoint_cannot_train_is_refused_when_the_agent_is_built(exp, core, why):
@@ -100,6 +117,8 @@ def test_a_core_the_entrypoint_cannot_train_is_refused_when_the_agent_is_built(e
     with pytest.raises(ValueError, match=why) as refused:
         build_agent(cfg, (4,), False, OBS_SPACE, jax.random.PRNGKey(0))
     assert "sequence_model" in str(refused.value)
+    if exp == "dreamer_v3":  # the entrypoint that trains them names every core
+        assert all(name in str(refused.value) for name in ("gru", "qwen3_next", "deepseek_v2", "lfm2_moe"))
 
 
 @pytest.mark.parametrize("core", CORES)
