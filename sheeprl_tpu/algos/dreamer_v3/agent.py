@@ -1334,7 +1334,8 @@ def build_player_fns(
         )
 
     return {
-        "init_states": init_states,
+        # one dispatch: acting on the device makes it after every burst (PERF.md, PR 35)
+        "init_states": jax.jit(init_states, static_argnums=1),
         "reset_states": jax.jit(reset_states),
         "greedy_action": greedy_action,
         "exploration_action": exploration_action,

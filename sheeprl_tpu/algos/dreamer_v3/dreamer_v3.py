@@ -681,10 +681,10 @@ def main(fabric, cfg: Dict[str, Any]):
     )
 
     # Acting is handed parameter trees: the leaves it reads
-    # (agent.acting_params and the actor) as CPU snapshots refreshed per burst
-    # where the host mirror is on (player_on_host=True on an accelerator mesh,
-    # utils/host.py), the trained leaves themselves where it is off (a
-    # disabled mirror is the identity).
+    # (agent.acting_params and the actor), the trained leaves themselves where
+    # the host mirror is off (the recipe's default, and every CPU run: a
+    # disabled mirror is the identity), CPU snapshots refreshed per burst where
+    # it is on (player_on_host=True on an accelerator mesh, utils/host.py).
     wm_mirror = HostParamMirror.from_cfg(
         acting_params(agent_state["params"]["world_model"]), fabric, cfg
     )

@@ -919,6 +919,11 @@ def main(fabric, cfg: Dict[str, Any]):
                 )
             _host_step_core(actions, real_actions, state_box["carry"]["player"])
         else:
+            if not wm_mirror.enabled:
+                # acting runs on the device that holds the trained leaves, and its
+                # program waits for the host callback: the callback has to find the
+                # fresh player state made, not ask it of the device it holds
+                _fresh_player()
             with span("Time/rollout_time", SumMetric(sync_on_compute=False), phase="rollout"):
                 _, root_key = burst_actor.rollout(
                     {

@@ -431,6 +431,11 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
         if update >= learning_starts and player_actor_type == "exploration":
             player_actor_type = "task"
 
+        if not wm_mirror.enabled:
+            # acting runs on the device that holds the trained leaves, and its
+            # program waits for the host callback: the callback has to find the
+            # fresh player state made, not ask it of the device it holds
+            _fresh_player()
         with span("Time/rollout_time", SumMetric(sync_on_compute=False), phase="rollout"):
             _, root_key = burst_actor.rollout(
                 {

@@ -35,7 +35,7 @@ from typing import Any, Callable, Tuple
 import numpy as np
 
 from sheeprl_tpu.obs import span
-from sheeprl_tpu.obs.counters import add_rollout_burst
+from sheeprl_tpu.obs.counters import add_rollout_burst, add_rollout_device_burst
 
 __all__ = ["BurstActor", "DeviceActor"]
 
@@ -130,8 +130,10 @@ class BurstActor:
         # multi-device (mesh-replicated) inputs. Pin to wherever the acting
         # params already live — the CPU host mirror when player_on_host is
         # on, the accelerator otherwise (algo.player_on_host=False keeps
-        # its meaning) — so the put is a no-op except for mesh-replicated
-        # params, which collapse to their first device's local shard.
+        # its meaning). The put moves no parameter: a leaf already on that
+        # device is handed on as it is, and of a mesh-replicated leaf jax
+        # takes the first device's own shard in place (the same buffer, no
+        # copy: tests/test_envs/test_rollout.py holds it to that).
         if self._device is None:
             self._device = self._params_device(params)
         params, obs, key = jax.device_put((params, obs, key), self._device)
@@ -142,6 +144,8 @@ class BurstActor:
         # here proves every host_step of the burst has run.
         jax.block_until_ready(obs)
         add_rollout_burst(act_dispatches=1)
+        if self._device.platform != "cpu":
+            add_rollout_device_burst()
         return obs, key
 
 
@@ -167,15 +171,20 @@ class DeviceActor:
         self._host_step = host_step
         self.state = state
         self.last: Any = None
+        self._on_device: Any = None
 
     def rollout(self, params: Any, obs: Any, key: Any, burst_len: int) -> Tuple[Any, Any]:
         """``burst_len`` policy steps; returns ``(next_obs, key)``."""
         import jax
 
+        if self._on_device is None:
+            self._on_device = BurstActor._params_device(params).platform != "cpu"
         for _ in range(int(burst_len)):
             with span("Time/act_decode_time", phase="rollout"):
                 to_host, self.last, self.state, key = self._step(params, self.state, obs, key)
                 to_host = jax.device_get(to_host)
             obs = self._host_step(to_host)
             add_rollout_burst(act_dispatches=1)
+            if self._on_device:
+                add_rollout_device_burst()
         return obs, key

@@ -48,6 +48,7 @@ __all__ = [
     "add_replay_priority_updates",
     "add_ring_gather",
     "add_rollout_burst",
+    "add_rollout_device_burst",
     "add_seq_core",
     "add_serve_batch",
     "add_serve_failed",
@@ -121,8 +122,13 @@ class Counters:
         # inference dispatches — per-step acting pays one per env step,
         # burst acting one per K steps, the jitted-scan jax backend one per
         # whole burst — and `env_steps_jax` counts env steps taken entirely
-        # inside jit (pure-JAX envs, zero host involvement)
+        # inside jit (pure-JAX envs, zero host involvement);
+        # `rollout_device_bursts` counts the bursts of Python envs whose acting
+        # program ran on a device that is not the host's CPU (envs/rollout/burst.py:
+        # it equals `rollout_bursts` where acting reads the trained leaves on the
+        # chip, and stays 0 on a host mirror and on every CPU run)
         self.rollout_bursts = 0
+        self.rollout_device_bursts = 0
         self.act_dispatches = 0
         self.env_steps_jax = 0
         # train-burst engine (sheeprl_tpu/train): `train_bursts` counts
@@ -272,6 +278,7 @@ class Counters:
                 "env_worker_restarts": self.env_worker_restarts,
                 "env_degraded_to_sync": self.env_degraded_to_sync,
                 "rollout_bursts": self.rollout_bursts,
+                "rollout_device_bursts": self.rollout_device_bursts,
                 "act_dispatches": self.act_dispatches,
                 "env_steps_jax": self.env_steps_jax,
                 "train_bursts": self.train_bursts,
@@ -452,6 +459,15 @@ def add_rollout_burst(act_dispatches: int = 1, jax_steps: int = 0) -> None:
             c.rollout_bursts += 1
             c.act_dispatches += int(act_dispatches)
             c.env_steps_jax += int(jax_steps)
+
+
+def add_rollout_device_burst() -> None:
+    """Record that a collection burst's acting program ran on a device other
+    than the host's CPU (beside :func:`add_rollout_burst`, which counts it)."""
+    c = _COUNTERS
+    if c is not None:
+        with c._lock:
+            c.rollout_device_bursts += 1
 
 
 def add_act_dispatches(n: int = 1) -> None:

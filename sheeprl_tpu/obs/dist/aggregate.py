@@ -74,6 +74,7 @@ SUMMED_RANK_COUNTERS = (
     "env_worker_restarts",
     "env_degraded_to_sync",
     "rollout_bursts",
+    "rollout_device_bursts",
     "act_dispatches",
     "env_steps_jax",
     "plane_traj_slabs",

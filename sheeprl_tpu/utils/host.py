@@ -1,12 +1,14 @@
 """Host-side parameter mirroring for the acting path.
 
 Environment interaction is one jitted policy call per env step. With
-``algo.player_on_host`` (default on) and a mesh on an accelerator, that call
+``algo.player_on_host`` (the global default; the DreamerV3 family's recipe
+turns it off) and a mesh on an accelerator, that call
 runs on the host CPU instead of the mesh (SURVEY §5.8 — players live on CPU
 hosts feeding the trainer mesh): :class:`HostParamMirror` keeps a CPU copy
 of the acting parameters, and every call of it refreshes that copy (the
-entrypoints call it once per update). Whether acting on the mirror beats
-acting on the device is ROADMAP S2/D4's measurement, not settled here.
+entrypoints call it once per update). For DreamerV3-XL acting on the device
+won in every cell (PERF.md §6, PR 35); for the other families neither side
+has a chip run (ROADMAP D1/D4).
 
 **The route of a refresh.** The leaves are fetched from the device with all
 their copies in flight together (``jax.device_get``: a leaf sharded over the

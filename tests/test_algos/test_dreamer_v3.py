@@ -576,21 +576,28 @@ def test_the_train_burst_returns_the_state_and_the_metrics_and_nothing_of_the_pa
     assert all(x.size < smallest_matrix for x in jax.tree_util.tree_leaves(metrics))
 
 
-def test_the_acting_callback_asks_nothing_of_the_device_that_runs_it(tmp_path, monkeypatch):
-    """With the mirror off, acting's program runs on the device that holds the
-    trained leaves and waits there for its host callback: a callback that asked
-    that device for the fresh player state would wait for the program that
-    waits for it (on the chip a hang at the first episode's end after a burst;
-    the CPU backend lets it through). So that state is made before a rollout,
-    never inside one."""
-    import sheeprl_tpu.algos.dreamer_v3.dreamer_v3 as dv3
+@pytest.mark.parametrize("entrypoint", ["dreamer_v3", "p2e_dv3_exploration", "p2e_dv3_finetuning"])
+def test_the_acting_callback_asks_nothing_of_the_device_that_runs_it(entrypoint, tmp_path, monkeypatch):
+    """With the mirror off (the DreamerV3 family's default), acting's program runs
+    on the device that holds the trained leaves and waits there for its host
+    callback: a callback that asked that device for the fresh player state would
+    wait for the program that waits for it (on the chip a hang at the first
+    episode's end after a burst; the CPU backend lets it through). So that state
+    is made before a rollout, never inside one."""
+    import glob
+    import importlib
+
     from sheeprl_tpu.envs.rollout import BurstActor
 
-    seen = {"in_rollout": False, "made": 0, "made_in_rollout": 0}
-    rollout, build_player_fns = BurstActor.rollout, dv3.build_player_fns
+    module = importlib.import_module(
+        "sheeprl_tpu.algos.dreamer_v3.dreamer_v3" if entrypoint == "dreamer_v3" else f"sheeprl_tpu.algos.p2e_dv3.{entrypoint}"
+    )
+    seen = {"in_rollout": False, "rollouts": 0, "made": 0, "made_in_rollout": 0}
+    rollout, build_player_fns = BurstActor.rollout, module.build_player_fns
 
     def flagged_rollout(actor, *args, **kwargs):
         seen["in_rollout"] = True
+        seen["rollouts"] += 1
         try:
             return rollout(actor, *args, **kwargs)
         finally:
@@ -606,12 +613,29 @@ def test_the_acting_callback_asks_nothing_of_the_device_that_runs_it(tmp_path, m
 
         return {**fns, "init_states": init_states}
 
+    def args(exp, extra):
+        ensembles = ["algo.ensembles.n=3"] if exp.startswith("p2e") else []
+        base = [a for a in dv3_args(tmp_path, extra) if a != "exp=dreamer_v3"]
+        return base + [f"exp={exp}", "fabric.devices=1", "env.id=discrete_dummy", *ensembles]
+
     monkeypatch.chdir(tmp_path)
-    monkeypatch.setenv("SHEEPRL_ACT_DUMP", str(tmp_path / "actions.pkl"))
+    extra = []
+    if entrypoint == "p2e_dv3_finetuning":
+        cli.run(args("p2e_dv3_exploration", ["checkpoint.every=1", "checkpoint.save_last=True"]))
+        ckpts = glob.glob(f"{tmp_path}/logs/**/checkpoint/ckpt_*", recursive=True)
+        extra = [f"checkpoint.exploration_ckpt_path={os.path.abspath(ckpts[-1])}"]
     monkeypatch.setattr(BurstActor, "rollout", flagged_rollout)
-    monkeypatch.setattr(dv3, "build_player_fns", watched_player_fns)
-    actions, _ = _seeded_run(tmp_path, "fresh")
+    monkeypatch.setattr(module, "build_player_fns", watched_player_fns)
+    cli.run(
+        args(
+            entrypoint,
+            [
+                "dry_run=False", "total_steps=40", "per_rank_sequence_length=4", "buffer.size=128",
+                "algo.learning_starts=8", "algo.train_every=8", "algo.run_test=False", "run_name=fresh", *extra,
+            ],
+        )
+    )
     # the dummy env's episodes last five steps, so episodes end inside these rollouts;
     # one fresh state at start-up and one for each burst's parameters
-    assert len(actions) >= 16 and seen["made"] >= 4
+    assert seen["rollouts"] >= 8 and seen["made"] >= 4
     assert seen["made_in_rollout"] == 0

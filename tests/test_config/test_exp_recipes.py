@@ -17,14 +17,26 @@ _EXPS = sorted(
 )
 
 
-@pytest.mark.parametrize("exp", _EXPS)
-def test_exp_recipe_composes(exp):
+def _composed(exp):
     overrides = [f"exp={exp}"]
     if "finetuning" in exp or "fntn" in exp:
         overrides.append("checkpoint.exploration_ckpt_path=/tmp/dummy")
-    cfg = compose("config", overrides=overrides)
+    return compose("config", overrides=overrides)
+
+
+@pytest.mark.parametrize("exp", _EXPS)
+def test_exp_recipe_composes(exp):
+    cfg = _composed(exp)
     assert cfg.algo.name
     assert cfg.env.wrapper._target_
+
+
+@pytest.mark.parametrize("exp", _EXPS)
+def test_only_the_dreamer_v3_family_acts_on_the_device_that_trains(exp):
+    """The DreamerV3 and P2E-DV3 recipes act on the device that holds the trained
+    leaves (no host mirror; PERF.md, PR 35); every other family keeps the global
+    default, acting on a CPU mirror, which no chip run has measured against."""
+    assert _composed(exp).algo.player_on_host is not exp.startswith(("dreamer_v3", "p2e_dv3"))
 
 
 def test_headline_recipes_carry_reference_presets():
