@@ -832,8 +832,9 @@ def main(fabric, cfg: Dict[str, Any]):
         if state_box["fresh"] is None and seq_core:
             state_box["fresh"] = {"reset": np.ones((n_envs, 1), np.float32)}
         if state_box["fresh"] is None:
-            fresh = player_fns["init_states"](play_wm, n_envs)
-            state_box["fresh"] = {k: np.asarray(v) for k, v in fresh.items()}
+            with span("Time/act_fresh_state_time", phase="rollout"):
+                fresh = player_fns["init_states"](play_wm, n_envs)
+                state_box["fresh"] = {k: np.asarray(v) for k, v in fresh.items()}
         return state_box["fresh"]
 
     def _host_step_core(actions, real_actions, player_np, key_data=None):
@@ -1047,7 +1048,9 @@ def main(fabric, cfg: Dict[str, Any]):
                 )
             _host_step_core(actions, real_actions, state_box["carry"]["player"])
         else:
-            burst_params = {"wm": play_wm, "actor": play_actor, "expl": jnp.float32(expl_amount)}
+            with span("Time/act_prepare_time", phase="rollout"):
+                # the exploration amount's upload: an eager dispatch of its own
+                burst_params = {"wm": play_wm, "actor": play_actor, "expl": jnp.float32(expl_amount)}
             if not wm_mirror.enabled:
                 # acting runs on the device that holds the trained leaves, and its
                 # program waits for the host callback: the callback has to find the
@@ -1060,7 +1063,8 @@ def main(fabric, cfg: Dict[str, Any]):
             # the burst program commits its inputs to the player's device;
             # pull the carried key back to host numpy (uncommitted) so the
             # possibly multi-device train program keeps accepting it
-            root_key = np.asarray(root_key)
+            with span("Time/act_key_fetch_time", phase="rollout"):
+                root_key = np.asarray(root_key)
         policy_step = state_box["policy_step"]
 
         update += n_act
@@ -1102,14 +1106,16 @@ def main(fabric, cfg: Dict[str, Any]):
                     first_hard=True,
                 )
                 with span("Time/train_time", SumMetric(sync_on_compute=cfg.metric.sync_on_compute), phase="train"):
-                    root_key, train_key = jax.random.split(root_key)
+                    with span("Time/train_prepare_time", phase="train"):
+                        root_key, train_key = jax.random.split(root_key)
+                        scanned = (jax.random.split(train_key, n_samples), jnp.asarray(taus))
                     # two values; the `*_` is for the benchmark's sequence adapter, which
                     # returns a triple in this call's place (benchmarks/dv3_seq_adapter.py:169)
                     agent_state, metrics, *_ = run_train_burst(
                         train_fn,
                         agent_state,
                         local_data,
-                        (jax.random.split(train_key, n_samples), jnp.asarray(taus)),
+                        scanned,
                         world_size=world_size,
                         # the sequence core's counters ride the step's metrics
                         fetch_metrics=fetch_metrics or (seq_core and counters_installed() is not None),

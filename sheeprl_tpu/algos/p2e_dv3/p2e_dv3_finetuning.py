@@ -294,8 +294,9 @@ def main(fabric, cfg: Dict[str, Any], exploration_cfg: Dict[str, Any]):
 
     def _fresh_player():
         if state_box["fresh"] is None:
-            fresh = player_fns["init_states"](play_wm, n_envs)
-            state_box["fresh"] = {k: np.asarray(v) for k, v in fresh.items()}
+            with span("Time/act_fresh_state_time", phase="rollout"):
+                fresh = player_fns["init_states"](play_wm, n_envs)
+                state_box["fresh"] = {k: np.asarray(v) for k, v in fresh.items()}
         return state_box["fresh"]
 
     def _host_step_core(actions, real_actions, player_np):

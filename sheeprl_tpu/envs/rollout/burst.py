@@ -34,8 +34,8 @@ from typing import Any, Callable, Tuple
 
 import numpy as np
 
-from sheeprl_tpu.obs import span
 from sheeprl_tpu.obs.counters import add_rollout_burst, add_rollout_device_burst
+from sheeprl_tpu.obs.spans import current_span, span
 
 __all__ = ["BurstActor", "DeviceActor"]
 
@@ -50,6 +50,11 @@ class BurstActor:
     episode logging, info stashing), and returns the prepared obs pytree
     for the next act. ``obs_example`` fixes the obs spec (shapes/dtypes the
     callback must return exactly).
+
+    Each ``host_step`` runs under the span ``Time/act_host_step_time``, on
+    whichever thread the runtime calls it back on (one of its own on a
+    chip); its parent is the span that was open where :meth:`rollout` was
+    called (the caller's ``Time/rollout_time``).
     """
 
     def __init__(
@@ -68,6 +73,7 @@ class BurstActor:
         )
         self._rollout_fn: Any = None
         self._device: Any = None
+        self._caller: Any = None  # the span open where rollout was called
 
     @staticmethod
     def _params_device(params):
@@ -92,8 +98,11 @@ class BurstActor:
         from jax.experimental import io_callback
 
         act_fn = self._act_fn
-        host_step = self._host_step
         obs_spec = self._obs_spec
+
+        def host_step(*args):
+            with span("Time/act_host_step_time", phase="rollout", parent=self._caller):
+                return self._host_step(*args)
 
         def rollout(params, obs, key, n):
             # n is traced: one compiled program serves every burst length,
@@ -137,6 +146,7 @@ class BurstActor:
         if self._device is None:
             self._device = self._params_device(params)
         params, obs, key = jax.device_put((params, obs, key), self._device)
+        self._caller = current_span()
         obs, key = fn(params, obs, key, np.int32(burst_len))
         # FENCE: dispatch is async — the caller is about to read host state
         # the callbacks mutate (replay buffer, episode stats). The returned
@@ -161,7 +171,8 @@ class DeviceActor:
     same Python loop body a :class:`BurstActor` calls; ``on_device`` (what the
     step computed besides: tokens, logits) is kept as ``self.last`` and never
     waited for. The dispatch and the fetch run under the span
-    ``Time/act_decode_time``, a child of the caller's ``Time/rollout_time``.
+    ``Time/act_decode_time``, ``host_step`` under ``Time/act_host_step_time``
+    after it: both children of the caller's ``Time/rollout_time``.
     """
 
     def __init__(self, step: Callable, host_step: Callable[[Any], Any], state: Any):
@@ -183,7 +194,8 @@ class DeviceActor:
             with span("Time/act_decode_time", phase="rollout"):
                 to_host, self.last, self.state, key = self._step(params, self.state, obs, key)
                 to_host = jax.device_get(to_host)
-            obs = self._host_step(to_host)
+            with span("Time/act_host_step_time", phase="rollout"):
+                obs = self._host_step(to_host)
             add_rollout_burst(act_dispatches=1)
             if self._on_device:
                 add_rollout_device_burst()

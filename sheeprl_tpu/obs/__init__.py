@@ -35,7 +35,6 @@ defaults to off; disabled, the instrumented code paths reduce to the plain
 from sheeprl_tpu.obs.counters import (
     Counters,
     DevicePoller,
-    add_act_dispatches,
     add_ckpt_blocked_ms,
     add_ckpt_write,
     add_env_async_steps,
@@ -102,7 +101,6 @@ __all__ = [
     "StreamingHist",
     "Telemetry",
     "TraceWriter",
-    "add_act_dispatches",
     "add_ckpt_blocked_ms",
     "add_ckpt_write",
     "add_env_async_steps",

@@ -32,7 +32,6 @@ import numpy as np
 
 __all__ = [
     "Counters",
-    "add_act_dispatches",
     "add_ckpt_blocked_ms",
     "add_ckpt_write",
     "add_env_async_steps",
@@ -468,15 +467,6 @@ def add_rollout_device_burst() -> None:
     if c is not None:
         with c._lock:
             c.rollout_device_bursts += 1
-
-
-def add_act_dispatches(n: int = 1) -> None:
-    """Record ``n`` standalone policy inference dispatches (per-step acting
-    paths not yet routed through a rollout burst)."""
-    c = _COUNTERS
-    if c is not None:
-        with c._lock:
-            c.act_dispatches += int(n)
 
 
 # -- train-burst engine accounting --------------------------------------------

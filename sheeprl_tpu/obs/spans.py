@@ -26,7 +26,9 @@ the Chrome trace-event format):
 
 (``parent`` says which span caused this one, ``burst`` which train cycle it
 belongs to; the per-thread stack of open spans behind ``parent`` exists only
-while a tracer is installed)
+while a tracer is installed. Work that runs on another thread on a caller's
+behalf — a host callback of a dispatched program — names the caller's span
+itself: ``span(..., parent=current_span())`` taken on the caller's thread)
 
 plus ``{"ph": "M", ...}`` thread-name metadata and ``{"ph": "C", ...}``
 counter samples from the device poller. Load in Perfetto / chrome://tracing
@@ -46,7 +48,7 @@ from sheeprl_tpu.obs import counters as _counters
 from sheeprl_tpu.obs import hist as _hist
 from sheeprl_tpu.utils.timer import timer
 
-__all__ = ["span", "TraceWriter", "get_tracer", "set_tracer", "scoped_compile_key"]
+__all__ = ["span", "TraceWriter", "current_span", "get_tracer", "set_tracer", "scoped_compile_key"]
 
 #: events buffered before a file flush (bounds write syscalls in hot loops)
 _FLUSH_EVERY = 128
@@ -65,6 +67,15 @@ def get_tracer() -> Optional["TraceWriter"]:
 def set_tracer(tracer: Optional["TraceWriter"]) -> None:
     global _TRACER
     _TRACER = tracer
+
+
+def current_span() -> Optional[str]:
+    """The innermost span open on this thread while a tracer is installed;
+    None with no tracer (and then no stack is looked at)."""
+    if _TRACER is None:
+        return None
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1] if stack else None
 
 
 @contextmanager
@@ -286,12 +297,15 @@ class span(ContextDecorator):
     Accumulates into the global :class:`timer` registry under ``name`` (same
     semantics, including the concurrent-reset re-register path) and, when a
     tracer is active, emits a trace event categorized under ``phase`` and
-    mirrors the scope into the XLA profiler.
+    mirrors the scope into the XLA profiler. ``parent`` names the span that
+    caused this one where that span is open on another thread; it is read
+    only while a tracer is installed.
     """
 
-    def __init__(self, name: str, metric: Any = None, phase: Optional[str] = None):
+    def __init__(self, name: str, metric: Any = None, phase: Optional[str] = None, parent: Optional[str] = None):
         self.name = name
         self.phase = phase
+        self._parent = parent
         self._timer = timer(name, metric)
         self._t0: Optional[float] = None
         self._annotation = None
@@ -306,7 +320,7 @@ class span(ContextDecorator):
             if stack is None:
                 stack = _OPEN.stack = []
             self._args = {
-                "parent": stack[-1] if stack else None,
+                "parent": self._parent or (stack[-1] if stack else None),
                 "burst": _counters.train_bursts(),
             }
             stack.append(self.name)

@@ -18,6 +18,7 @@
 import glob
 import json
 import os
+import threading
 
 import gymnasium as gym
 import jax
@@ -297,6 +298,100 @@ def _counting_actor(kind, n_envs=2):
             lambda params, state, a_obs, key: (a_obs * params, (), state + 1, key), host_step, jnp.int32(0)
         )
     return actor, jnp.float32(1.0), obs
+
+
+def _traced_rollout(tmp_path, kind, k):
+    """``k`` policy steps of a counting actor under a tracer, inside the
+    caller's ``Time/rollout_time``: the final obs and the X events."""
+    from sheeprl_tpu.obs.spans import TraceWriter, set_tracer, span
+
+    writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=False)
+    actor, params, obs = _counting_actor(kind)
+    if kind == "burst":
+        # on a chip the runtime calls the host back on a thread of its own; the
+        # CPU backend calls it on the dispatching thread, so dispatch elsewhere
+        program = actor._build()
+
+        def on_another_thread(*args):
+            out = {}
+            worker = threading.Thread(target=lambda: out.setdefault("out", program(*args)))
+            worker.start()
+            worker.join(timeout=60)
+            return out["out"]
+
+        actor._rollout_fn = on_another_thread
+    set_tracer(writer)
+    try:
+        with span("Time/rollout_time", phase="rollout"):
+            obs, _ = actor.rollout(params, obs, jax.random.PRNGKey(0), k)
+    finally:
+        set_tracer(None)
+        writer.close()
+    with open(writer.path) as f:
+        events = [e for e in map(json.loads, f) if e.get("ph") == "X"]
+    return np.asarray(obs), events
+
+
+def _named(events, name):
+    return sorted((e for e in events if e["name"] == name), key=lambda e: e["ts"])
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_each_host_step_of_a_burst_is_spanned_under_the_callers_rollout(tmp_path, k):
+    """The burst program's host callback runs on the runtime's thread; each of
+    its K host steps is a ``Time/act_host_step_time`` inside the caller's
+    rollout span that names that span as its parent."""
+    obs, events = _traced_rollout(tmp_path, "burst", k)
+    np.testing.assert_array_equal(obs, np.full((2, 1), float(k), np.float32))
+    (rollout,) = _named(events, "Time/rollout_time")
+    steps = _named(events, "Time/act_host_step_time")
+    assert len(steps) == k
+    for step in steps:
+        assert step["args"]["parent"] == "Time/rollout_time" and step["tid"] != rollout["tid"]
+        # ts and dur are rounded to a tenth of a microsecond
+        assert rollout["ts"] - 0.2 <= step["ts"] and step["ts"] + step["dur"] <= rollout["ts"] + rollout["dur"] + 0.2
+    for a, b in zip(steps, steps[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 0.2
+
+
+def test_a_device_actor_step_spans_its_host_step_after_its_decode(tmp_path):
+    obs, events = _traced_rollout(tmp_path, "device", 3)
+    np.testing.assert_array_equal(obs, np.full((2, 1), 3.0, np.float32))
+    decodes, steps = _named(events, "Time/act_decode_time"), _named(events, "Time/act_host_step_time")
+    assert len(decodes) == len(steps) == 3
+    for decode, step in zip(decodes, steps):
+        assert decode["args"]["parent"] == step["args"]["parent"] == "Time/rollout_time"
+        assert decode["ts"] + decode["dur"] <= step["ts"] + 0.2
+    for step, following in zip(steps, decodes[1:]):
+        assert step["ts"] + step["dur"] <= following["ts"] + 0.2
+
+
+@pytest.mark.parametrize("kind", ["burst", "device"])
+def test_with_no_tracer_the_host_step_span_is_a_timer_alone(kind, monkeypatch):
+    """Telemetry off: the actors' spans neither read a stack of open spans
+    (on either thread) nor call the profiler."""
+    from sheeprl_tpu.obs import spans as spans_mod
+    from sheeprl_tpu.utils.timer import timer
+
+    class Untouchable:
+        def __getattr__(self, name):
+            raise AssertionError(f"the stack of open spans was read ({name}) with no tracer installed")
+
+        def __setattr__(self, name, value):
+            raise AssertionError(f"a stack of open spans was made ({name}) with no tracer installed")
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a profiler annotation with no tracer installed")
+
+    assert spans_mod.get_tracer() is None
+    monkeypatch.setattr(timer, "disabled", False)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(spans_mod, "_OPEN", Untouchable())
+    timer.reset()
+    actor, params, obs = _counting_actor(kind)
+    obs, _ = actor.rollout(params, obs, jax.random.PRNGKey(0), 2)
+    np.testing.assert_array_equal(np.asarray(obs), np.full((2, 1), 2.0, np.float32))
+    assert "Time/act_host_step_time" in timer.compute()
 
 
 @pytest.mark.parametrize("kind", ["burst", "device"])
@@ -632,6 +727,55 @@ def test_dreamer_v3_burst_acting_k4_bitwise_k1_e2e(tmp_path, monkeypatch):
     cli.run(_dreamer_burst_args(tmp_path, "dreamer_v3", "vk1", extras))
     cli.run(_dreamer_burst_args(tmp_path, "dreamer_v3", "vk4", extras + ["env.act_burst=4"]))
     _assert_ckpt_bitwise(tmp_path, "vk1", "vk4", written=8)
+
+
+def test_dreamer_v3_spans_the_host_work_that_holds_its_device(tmp_path, monkeypatch):
+    """A traced DreamerV3 run: the fresh player state is spanned where a train
+    burst made the cached one stale, once a cycle and before the cycle's first
+    rollout, never on the cached path; the train block's preparation once a
+    burst, inside ``Time/train_time``; the acting call's preparation before
+    and the carried key's fetch after every rollout; each rollout's one policy
+    step as a host step inside it."""
+    monkeypatch.chdir(tmp_path)
+    from sheeprl_tpu import cli
+
+    trace = tmp_path / "spans.jsonl"
+    cli.run(_dreamer_burst_args(tmp_path, "dreamer_v3", "traced", [
+        "algo.world_model.discrete_size=4", "metric.telemetry.enabled=true", f"metric.telemetry.trace_file={trace}",
+        f"metric.telemetry.summary_path={tmp_path / 'telemetry.json'}", "metric.telemetry.learn.enabled=false",
+        "metric.telemetry.flight.enabled=false", "metric.telemetry.live_interval_s=0",
+        "metric.telemetry.poll_interval_s=0",
+    ]))
+    with open(trace) as f:
+        events = sorted((e for e in map(json.loads, f) if e.get("ph") == "X"), key=lambda e: e["ts"])
+
+    def named(name):
+        return [e for e in events if e["name"] == name]
+
+    def inside(inner, outer):  # ts and dur are rounded to a tenth of a microsecond
+        return outer["ts"] - 0.2 <= inner["ts"] and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 0.2
+
+    trains, rollouts, fresh = named("Time/train_time"), named("Time/rollout_time"), named("Time/act_fresh_state_time")
+    assert len(trains) >= 2 and len(rollouts) > len(trains) + 1
+    prepares = named("Time/train_prepare_time")
+    assert len(prepares) == len(trains)
+    assert all(inside(p, t) and p["args"]["parent"] == "Time/train_time" for p, t in zip(prepares, trains))
+    fetches, uploads = named("Time/act_key_fetch_time"), named("Time/act_prepare_time")
+    assert len(fetches) == len(uploads) == len(rollouts)
+    assert all(r["ts"] + r["dur"] <= k["ts"] + 0.2 for r, k in zip(rollouts, fetches))
+    assert all(u["ts"] + u["dur"] <= r["ts"] + 0.2 for u, r in zip(uploads, rollouts))
+    steps = named("Time/act_host_step_time")
+    for rollout in rollouts:
+        mine = [s for s in steps if inside(s, rollout)]
+        assert len(mine) == 1 and mine[0]["args"]["parent"] == "Time/rollout_time"
+    # the cycles after each burst: one fresh state, made before the cycle's first rollout
+    ends = [t["ts"] + t["dur"] for t in trains] + [float("inf")]
+    for after, before in zip(ends, ends[1:]):
+        cycle = [r for r in rollouts if after <= r["ts"] < before]
+        made = [f for f in fresh if after <= f["ts"] < before]
+        assert len(made) == (1 if cycle else 0)
+        assert not cycle or made[0]["ts"] + made[0]["dur"] <= cycle[0]["ts"] + 0.2
+    assert len(fresh) < len(rollouts)
 
 
 @pytest.mark.slow

@@ -8,7 +8,7 @@ import time
 
 import pytest
 
-from sheeprl_tpu.obs.spans import TraceWriter, set_tracer, span
+from sheeprl_tpu.obs.spans import TraceWriter, current_span, set_tracer, span
 from sheeprl_tpu.utils.metric import SumMetric
 from sheeprl_tpu.utils.timer import timer
 
@@ -196,6 +196,61 @@ def test_span_without_tracer_keeps_no_stack_and_makes_no_jax_call(monkeypatch):
             pass
     assert not hasattr(spans_mod._OPEN, "stack")
     assert set(timer.compute()) == {"Time/train_time", "Time/train_dispatch_time"}
+
+
+def test_a_span_run_on_another_thread_names_the_span_that_caused_it(tmp_path):
+    """Work a caller hands to another thread (a dispatched program's host
+    callback) carries the caller's open span as its parent; the spans it
+    opens itself are its children, on its own thread's stack."""
+    writer = TraceWriter(str(tmp_path / "t.jsonl"), xla_annotations=False)
+    set_tracer(writer)
+    try:
+        assert current_span() is None
+        with span("Time/rollout_time", phase="rollout"):
+            caller = current_span()
+
+            def host_step():
+                with span("Time/act_host_step_time", phase="rollout", parent=caller):
+                    with span("Time/env_interaction_time", phase="env"):
+                        pass
+
+            worker = threading.Thread(target=host_step)
+            worker.start()
+            worker.join(timeout=5)
+        assert current_span() is None
+    finally:
+        set_tracer(None)
+        writer.close()
+    events = {e["name"]: e for e in _read_events(writer.path) if e["ph"] == "X"}
+    assert caller == "Time/rollout_time"
+    step, env = events["Time/act_host_step_time"], events["Time/env_interaction_time"]
+    assert step["args"]["parent"] == "Time/rollout_time" and step["tid"] != events["Time/rollout_time"]["tid"]
+    assert env["args"]["parent"] == "Time/act_host_step_time" and env["tid"] == step["tid"]
+
+
+def test_a_span_with_a_parent_and_no_tracer_looks_up_no_span(monkeypatch):
+    """Telemetry off, the host step's span and the look for its parent touch
+    neither the stacks of open spans nor jax."""
+    import jax
+
+    from sheeprl_tpu.obs import spans as spans_mod
+
+    class Untouchable:
+        def __getattr__(self, name):
+            raise AssertionError(f"the stack of open spans was read ({name}) with no tracer installed")
+
+        def __setattr__(self, name, value):
+            raise AssertionError(f"a stack of open spans was made ({name}) with no tracer installed")
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a jax call with no tracer installed")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(spans_mod, "_OPEN", Untouchable())
+    with span("Time/rollout_time", phase="rollout"):
+        with span("Time/act_host_step_time", phase="rollout", parent=current_span()):
+            pass
+    assert set(timer.compute()) == {"Time/rollout_time", "Time/act_host_step_time"}
 
 
 @pytest.mark.parametrize("annotations", [True, False])
