@@ -484,7 +484,9 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
 #: the layers), ``decode_context_tokens`` (latent positions the one-token steps attended
 #: to) and ``decode_cache_tokens`` (those that had to be read: a row's shared cache once);
 #: the delta-rule core adds ``delta_rule_fused_tiles`` (tiles of the chunk-local WY form
-#: that the fused kernels built, the three passes of a gradient step; 0 in the XLA form);
+#: that the fused kernels built, the three passes of a gradient step; 0 in the XLA form)
+#: and ``delta_rule_scan_fused_tiles`` (a head's chunks that the inter-chunk kernels
+#: carried, likewise);
 #: the convolution-attention core (``lfm2_moe``) reports ``attended_pairs`` of its attention
 #: layers and adds ``router_max_load`` (the largest load among *all* the router's outputs,
 #: held here or not, the routing layers' maximum, a step's window pass: summed, divide by
@@ -492,7 +494,7 @@ def build_seq_train_fn(world_model: SeqWorldModel, actor, critic, world_tx, acto
 CORE_COUNTERS = (
     "held_pairs", "experts_hit", "max_load", "dropped_pairs", "episode_ends", "imagination_starts", "decode_steps",
     "imagination_pairs", "imagination_experts_hit", "attended_pairs", "decode_context_tokens", "decode_cache_tokens",
-    "delta_rule_fused_tiles", "router_max_load",
+    "delta_rule_fused_tiles", "delta_rule_scan_fused_tiles", "router_max_load",
 )
 #: train-step metrics that are levels, not counts: the newest burst's value stands.
 #: ``expert_bias_abs_max`` (``lfm2_moe``): the largest selection bias in any routing layer

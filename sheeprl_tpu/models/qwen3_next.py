@@ -45,7 +45,7 @@ SCOPE = "core"
 #: one-token statistics ``seq_agent`` sums over imagination's steps -> the run counter each feeds
 DECODE_COUNTS = {"held_pairs": "imagination_pairs", "experts_hit": "imagination_experts_hit"}
 #: window-pass statistics reported as run counters beside the expert layer's
-WINDOW_COUNTS = ("delta_rule_fused_tiles",)
+WINDOW_COUNTS = ("delta_rule_fused_tiles", "delta_rule_scan_fused_tiles")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -398,12 +398,16 @@ def window(params, tokens, reset, c: Qwen3NextConfig, dtype=f32, scope: str = SC
         total = _moe.add_stats(total, stats)
     with jax.named_scope(f"{scope}/head"):
         h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    # tiles of the delta rule's WY form that the fused kernels build: every delta-rule
-    # layer's forward pass, its rematerialisation and its transpose (0 in the XLA form)
+    # tiles (a head's chunk) of the delta rule that its kernels take, the WY build's and the
+    # inter-chunk pass's: every delta-rule layer's forward pass, its rematerialisation and
+    # its transpose (0 in the XLA form)
     key_shape = (B, L, c.linear_num_value_heads, c.linear_key_head_dim)
-    layers = sum(not c.is_attention(l) for l in range(c.num_hidden_layers))
-    tiles = delta_rule.fused_tiles(key_shape, c.linear_value_head_dim, c.chunk)
-    return h, states, {**total, "delta_rule_fused_tiles": 3.0 * layers * tiles}
+    passes = 3.0 * sum(not c.is_attention(l) for l in range(c.num_hidden_layers))
+    return h, states, {
+        **total,
+        "delta_rule_fused_tiles": passes * delta_rule.fused_tiles(key_shape, c.linear_value_head_dim, c.chunk),
+        "delta_rule_scan_fused_tiles": passes * delta_rule.scan_fused_tiles(key_shape, c.linear_value_head_dim, c.chunk),
+    }
 
 
 def decode(params, state, tokens, c: Qwen3NextConfig, dtype=f32, context=None, scope: str = SCOPE):
